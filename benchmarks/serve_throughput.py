@@ -464,8 +464,11 @@ def _sharded_pool(smoke: bool):
     import subprocess
     code = _SHARDED_POOL_SCRIPT.format(n_req=4 if smoke else 12,
                                        max_new=8 if smoke else 32)
+    # the child runs on 8 host CPU devices: left to its default backend it
+    # would try to take an accelerator this process already holds.
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=1800, env=dict(os.environ))
+                       text=True, timeout=1800,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert r.returncode == 0, r.stderr[-3000:]
     rows = {}
     for line in r.stdout.splitlines():

@@ -32,18 +32,6 @@ from typing import Optional, Sequence, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax moved shard_map out of experimental (and renamed check_rep ->
-# check_vma) in newer releases; adapt so the same call sites run on the
-# baked-in toolchain.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - version-dependent import path
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
-
 _ctx = threading.local()
 
 

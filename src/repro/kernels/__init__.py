@@ -1,6 +1,5 @@
 """Pallas TPU kernels for the paper's compute hot-spots, with jit
-wrappers (ops), pure-jnp oracles (ref), and interpret-mode CPU
-fallbacks:
+wrappers (ops) and pure-jnp oracles (ref):
 
   * mixed-precision quantized matmul (``quantized_matmul`` over
     ``PackedWeight`` — the paper's sub-byte compute story),
@@ -13,8 +12,12 @@ fallbacks:
     behind ``ServeConfig.use_pallas_decode``; partials are
     bit-identical to the lax ``_page_partials`` path for f32 pools.
 
-Every kernel runs under ``interpret=True`` off-TPU, so CPU CI
-exercises the real kernel logic without a TPU plugin.
+``interpret=False`` compiles a kernel with Mosaic; ``interpret=True``
+runs the same grid in the Pallas interpreter, which is how the CPU test
+suite checks kernel logic.  tests/test_tpu_compile.py compiles the
+paged-decode and quantized-matmul kernels for a described v5e topology;
+``flash_attention`` is not yet accepted by Mosaic (its blocks are not
+(8, 128)-tiled) and no model path calls it.
 """
 from repro.kernels.ops import (  # noqa: F401
     PackedWeight, prepare_weight, quantized_matmul,

@@ -24,11 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:                # CPU-only envs (no TPU plugin) still import the package
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:                     # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -98,14 +94,6 @@ def flash_attention(q, k, v, *, bq: int = 256, bk: int = 256,
     kernel = functools.partial(
         _flash_kernel, bq=bq, bk=bk, scale=dh ** -0.5, causal=causal,
         kv_valid=kv_valid)
-    if interpret or pltpu is None:      # no TPU plugin: interpret-only
-        params = None
-    else:
-        # jax renamed TPUCompilerParams -> CompilerParams across releases.
-        cp = getattr(pltpu, "CompilerParams", None) or \
-            getattr(pltpu, "TPUCompilerParams")
-        params = cp(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -119,6 +107,7 @@ def flash_attention(q, k, v, *, bq: int = 256, bk: int = 256,
         out_specs=pl.BlockSpec((1, bq, 1, dh),
                                lambda ib, ih, iq: (ib, iq, ih, 0)),
         out_shape=jax.ShapeDtypeStruct((b, sq, h, dh), q.dtype),
-        compiler_params=params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

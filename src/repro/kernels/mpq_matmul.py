@@ -74,11 +74,10 @@ def _wo_kernel(x_ref, w_ref, ws_ref, out_ref, acc_ref, *,
         out_ref[...] = (acc_ref[...] * ws_ref[...]).astype(out_ref.dtype)
 
 
-def _compiler_params(interpret: bool):
-    if interpret:
-        return None
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+# grid (M/bm, N/bn, K/bk): output tiles are independent, the contraction
+# is sequential into the accumulator scratch.
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 @functools.partial(
@@ -111,7 +110,7 @@ def mpq_matmul_kernel(x_q, x_scale, w_packed, w_scale, *, a_bits: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=_compiler_params(interpret),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(x_q, w_packed, x_scale, w_scale)
 
@@ -141,6 +140,6 @@ def wo_matmul_kernel(x, w_packed, w_scale, *, w_bits: int, bm: int, bk: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_compiler_params(interpret),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(x, w_packed, w_scale)

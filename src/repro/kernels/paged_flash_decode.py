@@ -23,6 +23,25 @@ flash-decoding (split-KV) shape:
     combine (``attention._combine_page_partials``) are UNCHANGED, which
     is what keeps N-shard logits bit-identical to the lax path.
 
+TPU layout.  Mosaic tiles the last two dims of every block by (8, 128)
+unless they span the whole array, and its matmuls are 2-D.  So the
+kernel works on 2-D row tiles and the wrappers convert at the boundary:
+
+  * GQA queries enter transposed, one ``(dh, Sq*G)`` tile per KV head,
+    so the query rows run along lanes, and their positions ride beside
+    them as a ``(1, Sq*G)`` row; MLA queries enter as ``(Sq*H, r)`` and
+    ``(Sq*H, dr)`` tiles,
+  * the per-slot scalars the skip predicate needs (fill bound, last
+    query position) are scalar-prefetched into SMEM with the table,
+  * outputs put the page axis ahead of the tile, ``(B, P, KV, 1|dv,
+    Sq*G)`` (GQA) and ``(B, P, Sq*H, 1|r)`` (MLA), and the wrappers move
+    it back to the callers' ``(..., P)`` layout,
+  * per-row scale pools are read through a unit middle axis
+    ``(N, 1, ps)``.
+
+Reshapes and transposes move values without rounding, so the callers
+see exactly what the per-page math produced.
+
 Bit-exactness: per-page scores/weights are the same fp ops in the same
 order as ``attention._page_partials_chunk`` (masking with the same
 ``NEG_INF`` identities, f32 score/acc accumulation via
@@ -43,10 +62,11 @@ path's ``PageFormat.dequantize`` element for element, so the quantized
 kernel partials are bitwise equal to the quantized lax partials the same
 way the fp ones are; no fp window is materialized in HBM in either mode.
 
-Off-TPU the kernels run with ``interpret=True`` (auto-detected from
-``jax.default_backend()``), so CPU CI exercises the REAL kernel logic —
-grid walk, index-map table lookups, ``pl.when`` skips — through the
-Pallas interpreter.
+``interpret`` chooses how the kernel runs: ``False`` compiles it with
+Mosaic, ``True`` runs it in the Pallas interpreter (the same grid walk,
+index-map table lookups and ``pl.when`` skips, executed as XLA ops —
+how the CPU test suite checks the kernel logic), and ``None`` compiles
+on a TPU backend and interprets on any other.
 
 Serving wires this behind ``ServeConfig.use_pallas_decode``: the engine
 enters :func:`use_pallas_decode` around its jitted dispatches and the
@@ -62,13 +82,9 @@ import threading
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import pack_factor, unpack
-
-try:                                    # CPU-only envs lack the TPU plugin
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:                     # pragma: no cover
-    pltpu = None
 
 NEG_INF = -1e30
 
@@ -85,10 +101,13 @@ _state = threading.local()
 def use_pallas_decode(enabled: bool = True, interpret: bool | None = None):
     """Route page-striped paged decode/resume through the fused kernel.
 
-    ``interpret=None`` auto-selects: compiled on TPU backends, the
-    Pallas interpreter everywhere else (the CPU fallback).  Nesting
-    restores the previous state on exit."""
+    ``interpret=None`` keeps the choice of an enclosing context, and
+    with none resolves from the backend at trace time (compiled on TPU,
+    the Pallas interpreter elsewhere).  Nesting restores the previous
+    state on exit."""
     prev = getattr(_state, "cfg", None)
+    if interpret is None and prev is not None:
+        interpret = prev[1]
     _state.cfg = (enabled, interpret)
     try:
         yield
@@ -101,114 +120,95 @@ def decode_kernel_config():
     cfg = getattr(_state, "cfg", None)
     if cfg is None or not cfg[0]:
         return None
-    interpret = cfg[1]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return interpret
+    return _resolve_interpret(cfg[1])
 
 
-def _compiler_params(*semantics):
-    # jax renamed TPUCompilerParams -> CompilerParams across releases.
-    cp = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    return None if cp is None else cp(dimension_semantics=semantics)
+def _resolve_interpret(interpret: bool | None) -> bool:
+    return jax.default_backend() != "tpu" if interpret is None else interpret
 
 
-def _require_pltpu():
-    if pltpu is None:                   # pragma: no cover
-        raise RuntimeError(
-            "kernels.paged_flash_decode needs jax.experimental.pallas.tpu "
-            "(scalar-prefetch grid specs); this jax build does not provide "
-            "it — run with ServeConfig.use_pallas_decode=False")
+def _call(kernel, grid_spec, out_shape, operands, interpret):
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret)(*operands)
+
+
+def _write_identities(m_ref, l_ref, acc_ref):
+    """A skipped page's partial: the exact flash identities."""
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, m_ref.dtype)
+    l_ref[...] = jnp.zeros(l_ref.shape, l_ref.dtype)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
+
+
+def _dequant(packed, s_ref, bits, dtype):
+    """(ps, w) packed page rows -> (ps, w * 8 // bits) rows of ``dtype``:
+    unpack, then one f32 multiply by the row scale — the op sequence of
+    ``PageFormat.dequantize``.  ``s_ref`` holds the page's (1, ps) row
+    scales; the select-and-sum turns them into a (ps, 1) column exactly
+    (every sum adds one scale to zeros)."""
+    ps = s_ref.shape[-1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (ps, ps), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (ps, ps), 1))
+    col = jnp.sum(jnp.where(eye, s_ref[0], 0.0), axis=1, keepdims=True)
+    return (unpack(packed, bits, axis=-1).astype(jnp.float32)
+            * col).astype(dtype)
+
+
+def _scale_spec(ps):
+    """BlockSpec of a per-row scale pool viewed as (N, 1, ps)."""
+    return pl.BlockSpec((1, 1, ps), lambda b_, j, t, *_: (
+        jnp.maximum(t[b_, j], 0), 0, 0))
 
 
 # ---------------------------------------------------------------------------
 # GQA: per-logical-page partials of q against the (N, ps, KV, dh) pool.
 # ---------------------------------------------------------------------------
 
-def _gqa_page_kernel(tbl_ref, q_ref, k_ref, v_ref, qp_ref, kvv_ref,
-                     m_ref, l_ref, acc_ref, *, sq, kv, g, ps, scale):
+def _gqa_page_kernel(tbl_ref, qmax_ref, kvv_ref, q_ref, k_ref, v_ref, *refs,
+                     kv, ps, scale, bits):
+    if bits is None:
+        qp_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        ks_ref, vs_ref, qp_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
     j = pl.program_id(1)
-    page = tbl_ref[b, j]                # this program's POOL page (or -1)
     k0 = j * ps                         # first logical row of the page
-    qp = qp_ref[0]                      # (Sq,) query positions of slot b
-    kvs = kvv_ref[0, 0]                 # filled-row bound of slot b
+    kvs = kvv_ref[b]                    # filled-row bound of slot b
     # A page participates iff it is resident on this shard AND at least
     # one of its rows passes the causal/fill predicates.  Skipped pages
     # write the exact flash identities the lax path computes for them.
-    active = (page >= 0) & (k0 <= jnp.max(qp)) & (k0 < kvs)
+    active = (tbl_ref[b, j] >= 0) & (k0 <= qmax_ref[b]) & (k0 < kvs)
 
     @pl.when(active)
     def _():
-        qx = q_ref[0].reshape(sq, kv, g, q_ref.shape[-1])
-        kb = k_ref[0]                   # (ps, KV, dh) — the mapped page
-        vb = v_ref[0]                   # (ps, KV, dv)
-        s = jnp.einsum("qkgd,skd->qkgs", (qx * scale).astype(qx.dtype), kb,
+        qt = q_ref[0]                   # (KV, dh, Sq*G) queries, transposed
+        kb, vt = [], []                 # the mapped page, per head
+        for h in range(kv):
+            kh, vh = k_ref[0, :, h, :], v_ref[0, :, h, :]   # (ps, dh|dv)
+            if bits is not None:
+                kh = _dequant(kh, ks_ref, bits, qt.dtype)
+                vh = _dequant(vh, vs_ref, bits, qt.dtype)
+            kb.append(kh)
+            vt.append(vh.T)
+        kb, vt = jnp.stack(kb), jnp.stack(vt)   # (KV, ps, dh), (KV, dv, ps)
+        # scores key-major, (KV, ps, Sq*G): the query rows run along lanes
+        s = jnp.einsum("ksd,kdq->ksq", kb, (qt * scale).astype(qt.dtype),
                        preferred_element_type=jnp.float32)
-        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (sq, ps), 1)
-        mask = (kpos <= qp[:, None]) & (kpos < kvs)
-        s = jnp.where(mask[:, None, None, :], s, NEG_INF)
-        m = jnp.max(s, axis=-1)         # (Sq, KV, G)
-        w = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m[..., None]))
-        l = jnp.sum(w, axis=-1)
-        acc = jnp.einsum("qkgs,skd->qkgd", w.astype(qx.dtype), vb,
-                         preferred_element_type=jnp.float32)
-        m_ref[0, :, :, :, 0] = m
-        l_ref[0, :, :, :, 0] = l
-        acc_ref[0, :, :, :, 0, :] = acc
+        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape[1:], 0)
+        mask = (kpos <= qp_ref[0]) & (kpos < kvs)   # qp_ref[0]: (1, rows)
+        s = jnp.where(mask, s, NEG_INF)
+        m = jnp.max(s, axis=1, keepdims=True)       # (KV, 1, Sq*G)
+        w = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m))
+        m_ref[0, 0] = m
+        l_ref[0, 0] = jnp.sum(w, axis=1, keepdims=True)
+        acc_ref[0, 0] = jnp.einsum("kds,ksq->kdq", vt, w.astype(qt.dtype),
+                                   preferred_element_type=jnp.float32)
 
     @pl.when(~active)
     def _():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-
-def _gqa_page_kernel_quant(tbl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                           qp_ref, kvv_ref, m_ref, l_ref, acc_ref, *,
-                           sq, kv, g, ps, scale, bits):
-    """The GQA body for QUANTIZED pools: identical flow to
-    :func:`_gqa_page_kernel`, with the page block dequantized in VMEM
-    (unpack -> f32 multiply by the row scale) before the score math —
-    the same op sequence ``PageFormat.dequantize`` runs on the lax path,
-    so the partials stay bitwise comparable between the two."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    page = tbl_ref[b, j]
-    k0 = j * ps
-    qp = qp_ref[0]
-    kvs = kvv_ref[0, 0]
-    active = (page >= 0) & (k0 <= jnp.max(qp)) & (k0 < kvs)
-
-    @pl.when(active)
-    def _():
-        qx = q_ref[0].reshape(sq, kv, g, q_ref.shape[-1])
-        ks = ks_ref[0][:, None, None]   # (ps, 1, 1) per-row scales
-        vs = vs_ref[0][:, None, None]
-        kb = (unpack(k_ref[0], bits, axis=-1).astype(jnp.float32)
-              * ks).astype(qx.dtype)    # (ps, KV, dh) dequantized page
-        vb = (unpack(v_ref[0], bits, axis=-1).astype(jnp.float32)
-              * vs).astype(qx.dtype)
-        s = jnp.einsum("qkgd,skd->qkgs", (qx * scale).astype(qx.dtype), kb,
-                       preferred_element_type=jnp.float32)
-        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (sq, ps), 1)
-        mask = (kpos <= qp[:, None]) & (kpos < kvs)
-        s = jnp.where(mask[:, None, None, :], s, NEG_INF)
-        m = jnp.max(s, axis=-1)
-        w = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m[..., None]))
-        l = jnp.sum(w, axis=-1)
-        acc = jnp.einsum("qkgs,skd->qkgd", w.astype(qx.dtype), vb,
-                         preferred_element_type=jnp.float32)
-        m_ref[0, :, :, :, 0] = m
-        l_ref[0, :, :, :, 0] = l
-        acc_ref[0, :, :, :, 0, :] = acc
-
-    @pl.when(~active)
-    def _():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _write_identities(m_ref, l_ref, acc_ref)
 
 
 def paged_flash_decode_partials(k_pool, v_pool, q, tbl, qpos, kv_valid, *,
@@ -233,7 +233,6 @@ def paged_flash_decode_partials(k_pool, v_pool, q, tbl, qpos, kv_valid, *,
     and the block is dequantized in VMEM; the softmax scale and the
     ``acc`` width use the FULL feature dims, matching the lax dequant
     path exactly."""
-    _require_pltpu()
     n, ps, kv, dh = k_pool.shape
     dv = v_pool.shape[-1]
     if bits is not None:
@@ -241,142 +240,90 @@ def paged_flash_decode_partials(k_pool, v_pool, q, tbl, qpos, kv_valid, *,
     b, sq, hq, _ = q.shape
     p = tbl.shape[1]
     g = hq // kv
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    # index maps receive the scalar-prefetched table last: the pool
-    # blocks are addressed THROUGH it (clamped; -1 pages are skipped by
-    # the kernel predicate, never read for values).
-    pool_idx = lambda b_, j, t: (jnp.maximum(t[b_, j], 0), 0, 0, 0)  # noqa: E731
+    rows = sq * g
+    # (B, Sq, KV*G, dh) -> one (Sq*G, dh) row tile per KV head; row
+    # i*G + gg is query i of group member gg, at position qpos[b, i].
+    qt = q.reshape(b, sq, kv, g, dh).transpose(0, 2, 4, 1, 3).reshape(
+        b, kv, dh, rows)
+    qrow = jnp.repeat(qpos, g, axis=1)[:, None, :]
+    pool_idx = lambda b_, j, t, *_: (jnp.maximum(t[b_, j], 0), 0, 0, 0)  # noqa: E731
     in_specs = [
-        pl.BlockSpec((1, sq, hq, dh), lambda b_, j, t: (b_, 0, 0, 0)),
+        pl.BlockSpec((1, kv, dh, rows), lambda b_, j, *_: (b_, 0, 0, 0)),
         pl.BlockSpec((1, ps, kv, k_pool.shape[-1]), pool_idx),
         pl.BlockSpec((1, ps, kv, v_pool.shape[-1]), pool_idx),
     ]
-    operands = [q, k_pool, v_pool]
-    if bits is None:
-        kernel = functools.partial(_gqa_page_kernel, sq=sq, kv=kv, g=g,
-                                   ps=ps, scale=dh ** -0.5)
-    else:
-        kernel = functools.partial(_gqa_page_kernel_quant, sq=sq, kv=kv,
-                                   g=g, ps=ps, scale=dh ** -0.5, bits=bits)
-        scale_idx = lambda b_, j, t: (jnp.maximum(t[b_, j], 0), 0)  # noqa: E731
-        in_specs += [pl.BlockSpec((1, ps), scale_idx),
-                     pl.BlockSpec((1, ps), scale_idx)]
-        operands += [k_scale, v_scale]
-    in_specs += [
-        pl.BlockSpec((1, sq), lambda b_, j, t: (b_, 0)),
-        pl.BlockSpec((1, 1), lambda b_, j, t: (b_, 0)),
-    ]
-    operands += [qpos, kv_valid.astype(jnp.int32).reshape(b, 1)]
+    operands = [qt, k_pool, v_pool]
+    if bits is not None:
+        in_specs += [_scale_spec(ps), _scale_spec(ps)]
+        operands += [k_scale.reshape(n, 1, ps), v_scale.reshape(n, 1, ps)]
+    in_specs.append(pl.BlockSpec((1, 1, rows), lambda b_, j, *_: (b_, 0, 0)))
+    operands.append(qrow)
+    row_spec = pl.BlockSpec((1, 1, kv, 1, rows),
+                            lambda b_, j, *_: (b_, j, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=3,
         grid=(b, p),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, sq, kv, g, 1), lambda b_, j, t: (b_, 0, 0, 0, j)),
-            pl.BlockSpec((1, sq, kv, g, 1), lambda b_, j, t: (b_, 0, 0, 0, j)),
-            pl.BlockSpec((1, sq, kv, g, 1, dv),
-                         lambda b_, j, t: (b_, 0, 0, 0, j, 0)),
-        ])
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, sq, kv, g, p), jnp.float32),
-            jax.ShapeDtypeStruct((b, sq, kv, g, p), jnp.float32),
-            jax.ShapeDtypeStruct((b, sq, kv, g, p, dv), jnp.float32),
-        ],
-        compiler_params=None if interpret else _compiler_params(
-            "parallel", "arbitrary"),
-        interpret=interpret,
-    )(tbl, *operands)
+        out_specs=[row_spec, row_spec,
+                   pl.BlockSpec((1, 1, kv, dv, rows),
+                                lambda b_, j, *_: (b_, j, 0, 0, 0))])
+    kernel = functools.partial(_gqa_page_kernel, kv=kv, ps=ps,
+                               scale=dh ** -0.5, bits=bits)
+    row_shape = jax.ShapeDtypeStruct((b, p, kv, 1, rows), jnp.float32)
+    m, l, acc = _call(
+        kernel, grid_spec,
+        [row_shape, row_shape,
+         jax.ShapeDtypeStruct((b, p, kv, dv, rows), jnp.float32)],
+        [tbl, jnp.max(qpos, axis=1), kv_valid.astype(jnp.int32), *operands],
+        _resolve_interpret(interpret))
+    # back to the callers' (B, Sq, KV, G, P[, dv]) layout
+    m, l = (x.reshape(b, p, kv, sq, g).transpose(0, 3, 2, 4, 1)
+            for x in (m, l))
+    acc = acc.reshape(b, p, kv, dv, sq, g).transpose(0, 4, 2, 5, 1, 3)
+    return m, l, acc
 
 
 # ---------------------------------------------------------------------------
 # MLA: compressed-space partials against the (N, ps, r+dr) latent pool.
 # ---------------------------------------------------------------------------
 
-def _mla_page_kernel(tbl_ref, pool_ref, qc_ref, qr_ref, pos_ref,
-                     m_ref, l_ref, acc_ref, *, ps, r, scale):
+def _mla_page_kernel(tbl_ref, pos_ref, pool_ref, *refs, ps, r, scale, bits):
+    if bits is None:
+        qc_ref, qr_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        s_ref, qc_ref, qr_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
     j = pl.program_id(1)
-    page = tbl_ref[b, j]
     k0 = j * ps
-    pb = pos_ref[0, 0]                  # slot position (-1 = inactive)
-    active = (page >= 0) & (k0 <= pb)
+    pb = pos_ref[b]                     # slot position (-1 = inactive)
+    active = (tbl_ref[b, j] >= 0) & (k0 <= pb)
 
     @pl.when(active)
     def _():
+        qc = qc_ref[0]                  # (Sq*H, r) absorbed queries
+        qr = qr_ref[0]                  # (Sq*H, dr)
         blk = pool_ref[0]               # (ps, r+dr) — the mapped page
+        if bits is not None:            # one scale spans c_kv and k_rope
+            blk = _dequant(blk, s_ref, bits, qc.dtype)
         c, kr = blk[:, :r], blk[:, r:]
-        qc = qc_ref[0]                  # (Sq, H, r) absorbed queries
-        qr = qr_ref[0]                  # (Sq, H, dr)
-        sc = jnp.einsum("qhr,sr->qhs", qc, c,
-                        preferred_element_type=jnp.float32)
-        sc += jnp.einsum("qhd,sd->qhs", qr, kr,
-                         preferred_element_type=jnp.float32)
+        nt = (((1,), (1,)), ((), ()))
+        sc = jax.lax.dot_general(qc, c, nt,
+                                 preferred_element_type=jnp.float32)
+        sc += jax.lax.dot_general(qr, kr, nt,
+                                  preferred_element_type=jnp.float32)
         sc = sc * scale
-        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)[0]
-        sc = jnp.where((kpos <= pb)[None, None, :], sc, NEG_INF)
-        m = jnp.max(sc, axis=-1)        # (Sq, H)
-        w = jnp.where(sc <= NEG_INF / 2, 0.0, jnp.exp(sc - m[..., None]))
-        l = jnp.sum(w, axis=-1)
-        acc = jnp.einsum("qhs,sr->qhr", w.astype(qc.dtype), c,
-                         preferred_element_type=jnp.float32)
-        m_ref[0, :, :, 0] = m
-        l_ref[0, :, :, 0] = l
-        acc_ref[0, :, :, 0, :] = acc
+        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        sc = jnp.where(kpos <= pb, sc, NEG_INF)
+        m = jnp.max(sc, axis=-1, keepdims=True)
+        w = jnp.where(sc <= NEG_INF / 2, 0.0, jnp.exp(sc - m))
+        m_ref[0, 0] = m
+        l_ref[0, 0] = jnp.sum(w, axis=-1, keepdims=True)
+        acc_ref[0, 0] = jnp.dot(w.astype(qc.dtype), c,
+                                preferred_element_type=jnp.float32)
 
     @pl.when(~active)
     def _():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-
-def _mla_page_kernel_quant(tbl_ref, pool_ref, sc_ref, qc_ref, qr_ref,
-                           pos_ref, m_ref, l_ref, acc_ref, *, ps, r, scale,
-                           bits):
-    """Compressed-space body for QUANTIZED latent pools: the whole
-    (ps, r+dr) page row is dequantized in VMEM with its per-row scale
-    (one scale spans the c_kv and k_rope halves, matching the write
-    side), then split at ``r`` and fed to the identical score math."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    page = tbl_ref[b, j]
-    k0 = j * ps
-    pb = pos_ref[0, 0]
-    active = (page >= 0) & (k0 <= pb)
-
-    @pl.when(active)
-    def _():
-        qc = qc_ref[0]                  # (Sq, H, r) absorbed queries
-        qr = qr_ref[0]                  # (Sq, H, dr)
-        s_row = sc_ref[0][:, None]      # (ps, 1) per-row scales
-        blk = (unpack(pool_ref[0], bits, axis=-1).astype(jnp.float32)
-               * s_row).astype(qc.dtype)   # (ps, r+dr) dequantized page
-        c, kr = blk[:, :r], blk[:, r:]
-        sc = jnp.einsum("qhr,sr->qhs", qc, c,
-                        preferred_element_type=jnp.float32)
-        sc += jnp.einsum("qhd,sd->qhs", qr, kr,
-                         preferred_element_type=jnp.float32)
-        sc = sc * scale
-        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)[0]
-        sc = jnp.where((kpos <= pb)[None, None, :], sc, NEG_INF)
-        m = jnp.max(sc, axis=-1)
-        w = jnp.where(sc <= NEG_INF / 2, 0.0, jnp.exp(sc - m[..., None]))
-        l = jnp.sum(w, axis=-1)
-        acc = jnp.einsum("qhs,sr->qhr", w.astype(qc.dtype), c,
-                         preferred_element_type=jnp.float32)
-        m_ref[0, :, :, 0] = m
-        l_ref[0, :, :, 0] = l
-        acc_ref[0, :, :, 0, :] = acc
-
-    @pl.when(~active)
-    def _():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _write_identities(m_ref, l_ref, acc_ref)
 
 
 def mla_paged_decode_partials(pool, q_c, q_rope, tbl, pos_b, r, scale_dim, *,
@@ -395,51 +342,42 @@ def mla_paged_decode_partials(pool, q_c, q_rope, tbl, pos_b, r, scale_dim, *,
     QUANTIZED pools: pass ``scale_pool`` ((N, ps) f32) and ``bits``; the
     pool then stores packed int8 rows of width ``(r+dr) * bits // 8``,
     dequantized in VMEM before the split at ``r``."""
-    _require_pltpu()
     n, ps, width = pool.shape
     if bits is not None:
         width = width * pack_factor(bits)
     b, sq, h, _ = q_c.shape
     dr = width - r
     p = tbl.shape[1]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    pool_idx = lambda b_, j, t: (jnp.maximum(t[b_, j], 0), 0, 0)  # noqa: E731
-    in_specs = [pl.BlockSpec((1, ps, pool.shape[-1]), pool_idx)]
+    rows = sq * h
+    in_specs = [pl.BlockSpec((1, ps, pool.shape[-1]), lambda b_, j, t, *_: (
+        jnp.maximum(t[b_, j], 0), 0, 0))]
     operands = [pool]
-    if bits is None:
-        kernel = functools.partial(_mla_page_kernel, ps=ps, r=r,
-                                   scale=scale_dim ** -0.5)
-    else:
-        kernel = functools.partial(_mla_page_kernel_quant, ps=ps, r=r,
-                                   scale=scale_dim ** -0.5, bits=bits)
-        in_specs += [pl.BlockSpec(
-            (1, ps), lambda b_, j, t: (jnp.maximum(t[b_, j], 0), 0))]
-        operands += [scale_pool]
+    if bits is not None:
+        in_specs.append(_scale_spec(ps))
+        operands.append(scale_pool.reshape(n, 1, ps))
     in_specs += [
-        pl.BlockSpec((1, sq, h, r), lambda b_, j, t: (b_, 0, 0, 0)),
-        pl.BlockSpec((1, sq, h, dr), lambda b_, j, t: (b_, 0, 0, 0)),
-        pl.BlockSpec((1, 1), lambda b_, j, t: (b_, 0)),
+        pl.BlockSpec((1, rows, r), lambda b_, j, *_: (b_, 0, 0)),
+        pl.BlockSpec((1, rows, dr), lambda b_, j, *_: (b_, 0, 0)),
     ]
-    operands += [q_c, q_rope, pos_b.astype(jnp.int32).reshape(b, 1)]
+    operands += [q_c.reshape(b, rows, r), q_rope.reshape(b, rows, dr)]
+    row_spec = pl.BlockSpec((1, 1, rows, 1), lambda b_, j, *_: (b_, j, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, p),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, sq, h, 1), lambda b_, j, t: (b_, 0, 0, j)),
-            pl.BlockSpec((1, sq, h, 1), lambda b_, j, t: (b_, 0, 0, j)),
-            pl.BlockSpec((1, sq, h, 1, r), lambda b_, j, t: (b_, 0, 0, j, 0)),
-        ])
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, sq, h, p), jnp.float32),
-            jax.ShapeDtypeStruct((b, sq, h, p), jnp.float32),
-            jax.ShapeDtypeStruct((b, sq, h, p, r), jnp.float32),
-        ],
-        compiler_params=None if interpret else _compiler_params(
-            "parallel", "arbitrary"),
-        interpret=interpret,
-    )(tbl, *operands)
+        out_specs=[row_spec, row_spec,
+                   pl.BlockSpec((1, 1, rows, r),
+                                lambda b_, j, *_: (b_, j, 0, 0))])
+    kernel = functools.partial(_mla_page_kernel, ps=ps, r=r,
+                               scale=scale_dim ** -0.5, bits=bits)
+    row_shape = jax.ShapeDtypeStruct((b, p, rows, 1), jnp.float32)
+    m, l, acc = _call(
+        kernel, grid_spec,
+        [row_shape, row_shape,
+         jax.ShapeDtypeStruct((b, p, rows, r), jnp.float32)],
+        [tbl, pos_b.astype(jnp.int32), *operands],
+        _resolve_interpret(interpret))
+    # back to the callers' (B, Sq, H, P[, r]) layout
+    m, l = (x.reshape(b, p, sq, h).transpose(0, 2, 3, 1) for x in (m, l))
+    acc = acc.reshape(b, p, sq, h, r).transpose(0, 2, 3, 1, 4)
+    return m, l, acc

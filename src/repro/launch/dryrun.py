@@ -1,12 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 DOC = """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 Proves the distribution config is coherent without hardware:
 
-  * 512 host CPU placeholder devices (the XLA_FLAGS line above MUST run
-    before any jax import — device count locks at first init),
+  * 512 host CPU placeholder devices (the XLA_FLAGS and JAX_PLATFORMS
+    lines above MUST run before any jax import — device count locks at
+    first init; pinned to the CPU, neither this process nor the one-cell
+    children it starts with ``--all`` ever takes an accelerator),
   * parameters / optimizer state / caches are jax.ShapeDtypeStruct with
     NamedShardings — a 34B-parameter train state is lowered with ZERO
     allocation,

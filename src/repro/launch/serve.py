@@ -21,6 +21,7 @@ import jax
 
 from repro.configs import all_archs, get_config, reduce_config
 from repro.core.quant import QuantConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.models.model import quantize_for_serving
 from repro.serve import (Request, Router, RouterConfig, ServeConfig,
@@ -63,6 +64,7 @@ def main():
                     choices=["affinity", "least_loaded", "random"],
                     help="router placement policy (--replicas > 1)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduce:

@@ -15,6 +15,7 @@ import jax
 from repro.configs import all_archs, get_config, reduce_config
 from repro.data.pipeline import DataConfig
 from repro.distributed.sharding import use_rules
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models import init_params, param_count
 from repro.train import StepOptions, init_train_state
@@ -38,6 +39,7 @@ def main():
                     choices=["none", "pod1", "pod2"])
     ap.add_argument("--rules", default="fsdp_sp")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduce:

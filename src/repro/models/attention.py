@@ -46,7 +46,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.pageformat import FP, format_for_packed
 from repro.distributed.sharding import (current_mesh, lshard, make_spec,
-                                        mesh_axes_for, shard_map)
+                                        mesh_axes_for)
 from repro.kernels.paged_flash_decode import (decode_kernel_config,
                                               paged_flash_decode_partials)
 from repro.models.common import (ParamSpec, broadcast_offset, chunk_lengths,
@@ -469,10 +469,10 @@ def sharded_paged_scatter(pool, pages, rows, t, valid):
                                pl.shape[0])
         return paged_scatter(pl, lt, rw, tt, ok)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(pspec, P(), P(), P(), P()),
-                     out_specs=pspec, check_vma=False)(
-                         pool, pages, rows, t, valid)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(pspec, P(), P(), P(), P()),
+                         out_specs=pspec, check_vma=False)(
+                             pool, pages, rows, t, valid)
 
 
 def _paged_flash_striped(cache, pages, k, v, q, t, ok, qpos, kvv, mesh,
@@ -514,7 +514,7 @@ def _paged_flash_striped(cache, pages, k, v, q, t, ok, qpos, kvv, mesh,
         b, sq = qq.shape[:2]
         return o.reshape(b, sq, -1, o.shape[-1]).astype(qq.dtype), pk, pv
 
-    o, pk, pv = shard_map(
+    o, pk, pv = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec, pspec, P(), P(), P(), P(), P(), P(), P(), P()),
         out_specs=(P(), pspec, pspec), check_vma=False)(
@@ -569,7 +569,7 @@ def _paged_flash_striped_quant(cache, pages, k, v, q, t, ok, qpos, kvv,
         return (o.reshape(b, sq, -1, o.shape[-1]).astype(qq.dtype),
                 pk, pv, pks, pvs)
 
-    o, pk, pv, pks, pvs = shard_map(
+    o, pk, pv, pks, pvs = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec, pspec, sspec, sspec,
                   P(), P(), P(), P(), P(), P(), P(), P(), P(), P()),
@@ -727,7 +727,7 @@ def sdpa(q, k, v, *, kv_valid) -> jax.Array:
         vf = jax.lax.all_gather(v_l, seq_axes, axis=1, tiled=True)
         return _chunked_attention_local(q_l, kf, vf, q0, kv_valid)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=(qkv_spec, qkv_spec, qkv_spec),
         out_specs=qkv_spec, check_vma=False)(q, k, v)
 
@@ -751,7 +751,7 @@ def decode_sdpa(q, k_cache, v_cache, *, kv_valid) -> jax.Array:
         k0 = (idx * k_l.shape[1]).astype(jnp.int32)
         return _decode_attention_local(q_l, k_l, v_l, k0, kv_valid, seq_axes)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=(q_spec, c_spec, c_spec),
         out_specs=q_spec, check_vma=False)(q, k_cache, v_cache)
 
@@ -813,7 +813,7 @@ def cache_update(cache: dict, k_new, v_new, index) -> dict:
         k0 = idx * kb.shape[1]
         return write_local(kb, kn, k0), write_local(vb, vn, k0)
 
-    k2, v2 = shard_map(
+    k2, v2 = jax.shard_map(
         local_fn, mesh=mesh, in_specs=(c_spec, c_spec, n_spec, n_spec),
         out_specs=(c_spec, c_spec), check_vma=False)(
             cache["k"], cache["v"], k_new, v_new)

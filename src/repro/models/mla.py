@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.pageformat import FP
-from repro.distributed.sharding import lshard, shard_map
+from repro.distributed.sharding import lshard
 from repro.kernels.paged_flash_decode import (decode_kernel_config,
                                               mla_paged_decode_partials)
 from repro.models.attention import (NEG_INF, _combine_page_partials,
@@ -167,10 +167,10 @@ def _mla_paged_decode(q_c, q_rope, entry, cache, pages, pos_b, r,
             acc = jax.lax.psum(acc, axes)
             return _combine_page_partials(m, l, acc), pl
 
-        ctx_c, pl = shard_map(body, mesh=mesh,
-                              in_specs=(pspec, P(), P(), P(), P(), P()),
-                              out_specs=(P(), pspec), check_vma=False)(
-                                  pool, entry, q_c, q_rope, pages, pos_b)
+        ctx_c, pl = jax.shard_map(
+            body, mesh=mesh, in_specs=(pspec, P(), P(), P(), P(), P()),
+            out_specs=(P(), pspec), check_vma=False)(
+                pool, entry, q_c, q_rope, pages, pos_b)
         return ctx_c, {"ckv": pl}
 
     sspec = _pool_spec(2)
@@ -195,7 +195,7 @@ def _mla_paged_decode(q_c, q_rope, entry, cache, pages, pos_b, r,
         acc = jax.lax.psum(acc, axes)
         return _combine_page_partials(m, l, acc), pl, pls
 
-    ctx_c, pl, pls = shard_map(
+    ctx_c, pl, pls = jax.shard_map(
         body_q, mesh=mesh,
         in_specs=(pspec, sspec, P(), P(), P(), P(), P(), P()),
         out_specs=(P(), pspec, sspec), check_vma=False)(
@@ -263,7 +263,7 @@ def _mla_paged_resume(p, qq, entry, cache, pages, t, ok, off_b, len_b, cfg,
             o = _combine_page_partials(m, l, acc)
             return o.reshape(b, q_.shape[1], h, dv).astype(q_.dtype), pl
 
-        o, pl = shard_map(
+        o, pl = jax.shard_map(
             body, mesh=mesh,
             in_specs=(pspec, P(), P(), P(), P(), P(), P(), P(), P(), P()),
             out_specs=(P(), pspec), check_vma=False)(
@@ -290,7 +290,7 @@ def _mla_paged_resume(p, qq, entry, cache, pages, t, ok, off_b, len_b, cfg,
         o = _combine_page_partials(m, l, acc)
         return o.reshape(b, q_.shape[1], h, dv).astype(q_.dtype), pl, pls
 
-    o, pl, pls = shard_map(
+    o, pl, pls = jax.shard_map(
         body_q, mesh=mesh,
         in_specs=(pspec, sspec, P(), P(), P(), P(), P(), P(), P(), P(),
                   P(), P()),

@@ -233,6 +233,11 @@ def forward(params: dict, inputs: jax.Array, cfg: ArchConfig, *,
 
     x = apply_norm(params["final_norm"], x, cfg)
     logits = dense(x, params["lm_head"], cfg.quant)
+    if cfg.padded_vocab != cfg.vocab_size:
+        # the padding columns only make the vocab axis shard evenly; they
+        # name no token, so no sampler or loss may pick them.
+        real = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
+        logits = jnp.where(real, logits, jnp.asarray(-1e30, logits.dtype))
     logits = lshard(logits, "batch", "seq", "vocab")
     return logits, (new_cache if cache is not None else None), aux_total
 

@@ -33,8 +33,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.quant import fake_quant
-from repro.distributed.sharding import (current_mesh, lshard, make_spec,
-                                        shard_map)
+from repro.distributed.sharding import current_mesh, lshard, make_spec
 from repro.models.common import ParamSpec, dense
 
 
@@ -199,7 +198,7 @@ def _moe_shardmap(p, x, expert_idx, gate_vals, cap, cfg, mesh,
         y = y_a.reshape(tl, k, d).sum(axis=1)
         return y.reshape(x_l.shape)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(x_spec, i_spec, i_spec) + wio_spec,
         out_specs=x_spec, check_vma=False)(

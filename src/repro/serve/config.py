@@ -71,9 +71,11 @@ class ServeConfig:
     # Pallas flash-decoding kernel (kernels/paged_flash_decode): page-
     # table translation + pool-page gather + per-logical-page flash
     # partials in ONE kernel instead of paged_gather materializing the
-    # window in HBM, with non-resident/future pages skipped.  Off-TPU
-    # the kernel runs through the Pallas interpreter (the CPU fallback),
-    # so the knob is honest everywhere.  The cross-shard combine is
+    # window in HBM, with non-resident/future pages skipped.  On a TPU
+    # backend the kernel is compiled with Mosaic; on any other backend
+    # (the CPU test suite) it runs the same grid through the Pallas
+    # interpreter, which checks its logic but says nothing of its
+    # speed.  The cross-shard combine is
     # unchanged: f32-pool logits are bit-identical to the lax path.
     # Inert when the pool is replicated (no 'pages' mesh striping in the
     # active rule table) — that path keeps its local gather.
