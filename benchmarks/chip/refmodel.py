@@ -1,0 +1,182 @@
+"""Plain float32 reference of a dense GQA decoder (Qwen2 / Qwen3), and the
+comparison that decides ``correct``.
+
+Written from the published architecture, not from the program: RMSNorm
+(eps from the config), q/k/v projections with optional biases, optional
+per-head RMSNorm of q and k (Qwen3), rotary embedding on the half-split
+layout with the config's theta, causal grouped-query attention scaled by
+1/sqrt(head_dim), output projection, SwiGLU MLP, final RMSNorm, head.
+It reads the weights the benchmark drew (``weightgen``) by name.
+
+Every matmul runs at ``precision=highest`` in float32; the weights are
+widened one layer at a time inside a scan, and the head one block of the
+vocabulary at a time, so the reference fits beside the weights on the
+chip.  The control (``quant="fp8"``) is the same forward with both
+operands of every projection and of the head rounded to float8_e4m3fn
+(weights per output column, activations per row, each scaled to the
+format's largest value): the step below bf16 a later change could be
+tempted by.
+
+The number compared is the widest gap, over the served positions of the
+sampled requests, by which the served token's reference logit lies
+below the reference's best logit at that position (true vocabulary
+only).  Greedy serving with no fault reads a gap of rounding size: it
+picks the reference's best, or a token tied with it to within bf16
+rounding.  For the control the gap is read at the token the lower
+precision puts first.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+HEAD_BLOCKS = 16
+F8_MAX = 448.0
+
+
+def _mm(x, w, quant):
+    w = w.astype(jnp.float32)
+    if quant == "fp8":
+        sx = jnp.max(jnp.abs(x), axis=-1, keepdims=True) / F8_MAX + 1e-30
+        sw = jnp.max(jnp.abs(w), axis=0, keepdims=True) / F8_MAX + 1e-30
+        x = (x / sx).astype(jnp.float8_e4m3fn).astype(jnp.float32) * sx
+        w = (w / sw).astype(jnp.float8_e4m3fn).astype(jnp.float32) * sw
+    return jnp.dot(x, w, precision=jax.lax.Precision.HIGHEST)
+
+
+def _rms(x, g, eps):
+    return x * (1.0 / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + eps)) \
+        * g.astype(jnp.float32)
+
+
+def _rope(x, theta):
+    """x: (S, heads, dh); rotate halves [0, dh/2) and [dh/2, dh)."""
+    s, _, dh = x.shape
+    half = dh // 2
+    inv = theta ** (-np.arange(half, dtype=np.float64) * 2.0 / dh)
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * \
+        jnp.asarray(inv, jnp.float32)[None, :]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+
+
+def _layer(m, quant, x, lw):
+    s = x.shape[0]
+    h, kv, dh = m["num_attention_heads"], m["num_key_value_heads"], m["head_dim"]
+    eps = m["rms_norm_eps"]
+    a = _rms(x, lw["ln1"]["w"], eps)
+    at = lw["attn"]
+    q, k, v = (_mm(a, at[n], quant) for n in ("wq", "wk", "wv"))
+    if m.get("attention_bias"):
+        q = q + at["bq"].astype(jnp.float32)
+        k = k + at["bk"].astype(jnp.float32)
+        v = v + at["bv"].astype(jnp.float32)
+    q, k, v = q.reshape(s, h, dh), k.reshape(s, kv, dh), v.reshape(s, kv, dh)
+    if m.get("qk_norm"):
+        q = _rms(q, at["q_norm"], eps)
+        k = _rms(k, at["k_norm"], eps)
+    q, k = _rope(q, m["rope_theta"]), _rope(k, m["rope_theta"])
+    g = h // kv
+    q = q.reshape(s, kv, g, dh)
+    sc = jnp.einsum("qkgd,skd->kgqs", q, k,
+                    precision=jax.lax.Precision.HIGHEST) * dh ** -0.5
+    causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+    sc = jnp.where(causal[None, None], sc, -jnp.inf)
+    p = jax.nn.softmax(sc, axis=-1)
+    o = jnp.einsum("kgqs,skd->qkgd", p, v,
+                   precision=jax.lax.Precision.HIGHEST).reshape(s, h * dh)
+    x = x + _mm(o, at["wo"], quant)
+    b = _rms(x, lw["ln2"]["w"], eps)
+    ff = lw["ffn"]
+    up = jax.nn.silu(_mm(b, ff["w_gate"], quant)) * _mm(b, ff["w_up"], quant)
+    return x + _mm(up, ff["w_down"], quant)
+
+
+def hidden(params, tokens, m, quant=None):
+    """Final-norm hidden states (S, d) of one sequence."""
+    x = params["embed"][tokens].astype(jnp.float32)
+    x, _ = jax.lax.scan(lambda c, lw: (_layer(m, quant, c, lw), None), x,
+                        params["stages"][0])
+    return _rms(x, params["final_norm"]["w"], m["rms_norm_eps"])
+
+
+def _scan_head(params, m, hid, quant, fn, init):
+    """Fold ``fn(carry, logits_block, col0)`` over the vocabulary blocks;
+    columns at or past vocab_size read -inf."""
+    w = params["lm_head"]
+    vocab, vp = m["vocab_size"], w.shape[1]
+    if vp % HEAD_BLOCKS:
+        raise ValueError(f"head width {vp} is no multiple of {HEAD_BLOCKS}")
+    vb = vp // HEAD_BLOCKS
+
+    def body(carry, i):
+        lg = _mm(hid, jax.lax.dynamic_slice_in_dim(w, i * vb, vb, 1), quant)
+        cols = i * vb + jnp.arange(vb)
+        lg = jnp.where(cols[None, :] < vocab, lg, -jnp.inf)
+        return fn(carry, lg, i * vb), None
+
+    carry, _ = jax.lax.scan(body, init, jnp.arange(HEAD_BLOCKS))
+    return carry
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def gaps(params, tokens, targets, valid, m_items, control: bool):
+    """Per-position gap best - logit[target] of the f32 reference over one
+    padded sequence.  ``targets[i]`` is the token served after position i
+    (ignored where not ``valid``).  With ``control``, the gap is read at
+    the token the fp8 forward puts first instead of at ``targets``.
+    Returns (gaps (S,), chosen tokens (S,))."""
+    m = dict(m_items)
+    s = tokens.shape[0]
+    if control:
+        hq = hidden(params, tokens, m, "fp8")
+
+        def amax(c, lg, col0):
+            best, arg = c
+            j = jnp.argmax(lg, -1)
+            v = jnp.max(lg, -1)
+            take = v > best
+            return jnp.where(take, v, best), jnp.where(take, col0 + j, arg)
+
+        _, targets = _scan_head(params, m, hq, "fp8", amax,
+                                (jnp.full((s,), -jnp.inf),
+                                 jnp.zeros((s,), jnp.int32)))
+    hid = hidden(params, tokens, m, None)
+
+    def best_and_pick(c, lg, col0):
+        best, pick = c
+        cols = col0 + jnp.arange(lg.shape[1])
+        hit = cols[None, :] == targets[:, None]
+        pick = jnp.where(hit.any(-1), jnp.sum(jnp.where(hit, lg, 0.0), -1),
+                         pick)
+        return jnp.maximum(best, jnp.max(lg, -1)), pick
+
+    best, pick = _scan_head(params, m, hid, None, best_and_pick,
+                            (jnp.full((s,), -jnp.inf), jnp.full((s,), -jnp.inf)))
+    return jnp.where(valid, best - pick, 0.0), targets
+
+
+def served_gaps(params, m: dict, prompt, served, length: int,
+                control: bool = False):
+    """Gaps at each served token of one request: the reference runs over
+    prompt + served[:-1], padded to ``length`` rows (one compiled shape
+    per cell), and position len(prompt) - 1 + i predicts served[i]."""
+    seq = list(prompt) + list(served[:-1])
+    if len(seq) > length:
+        raise ValueError(f"sequence of {len(seq)} rows over the reference's "
+                         f"{length}")
+    toks = np.zeros(length, np.int32)
+    toks[:len(seq)] = seq
+    tgt = np.zeros(length, np.int32)
+    valid = np.zeros(length, bool)
+    p = len(prompt)
+    tgt[p - 1:p - 1 + len(served)] = served
+    valid[p - 1:p - 1 + len(served)] = True
+    with jax.default_matmul_precision("highest"):
+        g, _ = gaps(params, jnp.asarray(toks), jnp.asarray(tgt),
+                    jnp.asarray(valid), tuple(sorted(m.items())), control)
+    return np.asarray(g)[valid]
