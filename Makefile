@@ -5,7 +5,7 @@
 PY ?= python
 export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
 
-.PHONY: test smoke test-sharded test-quant-pool test-tiered test-spec test-router bench-smoke bench-serve bench serve-demo
+.PHONY: test smoke test-sharded test-quant-pool test-tiered test-spec test-router bench serve-demo
 
 test:
 	$(PY) -m pytest -x -q
@@ -55,14 +55,6 @@ test-spec:
 # XLA_FLAGS set, so this also runs on a plain single-device host).
 test-router:
 	$(PY) -m pytest -x -q tests/test_router.py tests/test_wire_properties.py
-
-# tiny end-to-end pass of every serving-benchmark section (CI): asserts
-# the benchmark itself still runs, so it cannot silently rot.
-bench-smoke:
-	$(PY) benchmarks/serve_throughput.py --smoke
-
-bench-serve:
-	$(PY) benchmarks/serve_throughput.py
 
 # end-to-end launcher pass on a reduced arch (CI): exercises the session
 # serve API (submit/stream/drain, priorities + deadlines) through the

@@ -28,7 +28,8 @@ Within a fixed quantized format every serving transform is still pure
 addressing — COW/swap/resume/prefix-sharing copy quantized bytes and scales
 verbatim — so int8 runs are bitwise invariant across shard counts and
 preemption schedules; only the fp->int round-trip itself is lossy, and that
-error is budgeted in the benchmark (``benchmarks/serve_throughput.py``).
+error is budgeted in tests/test_quant_pool.py (the chip benchmark,
+``benchmarks/chip/run.py``, serves fp pages only).
 """
 from __future__ import annotations
 

@@ -85,9 +85,9 @@ above:
     integer rows plus one f32 absmax scale per cache row, quantized
     once at page-write time and dequantized inside the flash partial
     (lax and Pallas kernel alike — never an fp window in HBM).  The
-    fp-vs-quantized logit error is measured and reported by
-    ``benchmarks/serve_throughput.py`` (``kv_quant`` in
-    BENCH_serve.json); what stays EXACT is addressing-invariance —
+    fp-vs-quantized logit error is budgeted in tests/test_quant_pool.py
+    (the chip benchmark, ``benchmarks/chip/run.py``, serves fp pages
+    only); what stays EXACT is addressing-invariance —
     a row's stored bytes depend only on its own fp values, so
     quantized logits are bitwise identical across chunking schedules,
     sharing on/off, swap cycles, shard counts, and lax-vs-kernel

@@ -73,6 +73,10 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+# host spans named ``serve.*``: inert outside a profiler session; inside
+# one they land on the host plane beside the device's programs, their int
+# args as event stats (a request's spans share its ``rid``).
+from jax.profiler import TraceAnnotation as span
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.checkpoint import checkpoint as checkpointing
@@ -623,43 +627,56 @@ class ServingEngine:
         """One admission wave from ``queue`` (a _ListQueue or _SchedQueue):
         fill free slots in the queue's pop order, then one prefill
         dispatch covering new and resumed slots."""
+        with span("serve.admit"):
+            placed = self._place_wave(queue)
+        if placed:
+            self._prefill_tick()    # new slots' first chunk + resumed ones
+        return len(placed)
+
+    def _place_wave(self, queue) -> List[tuple]:
+        """Place requests from ``queue`` into free slots, in its pop
+        order; returns the (slot, request) pairs placed."""
         if self.sc.paged:
             self._swap_in_ready()
         placed: List[tuple] = []        # (slot, request) vetted this wave
         copies: List[Tuple[int, int]] = []
         try:
             for slot in self._free_slots():
-                got, share = None, (None, 0)
-                while queue and got is None:
-                    req = queue.pop()
-                    if req.done:        # already rejected/finished earlier
-                        continue
-                    verdict, share = self._admissible(slot, req)
-                    if verdict is _DEFER:
-                        queue.defer(req)
-                        break
-                    if verdict is _OVERSIZED:
-                        # streams from the host tier: consumes no slot —
-                        # keep popping for this one.
-                        self._admit_oversized(req)
-                        continue
-                    if verdict:
-                        got = req
-                if got is None:
-                    break               # out of requests, or deferred
-                start_row = 0
-                twin = None
-                if self.sc.paged:
-                    if self.sc.decode_sharing:
-                        # before place(): the ledger must not match the
-                        # request against its own fresh slot.
-                        twin = self.sched.find_twin(got.prompt)
-                    start_row, cps = self._claim_pages(slot, got, share)
-                    copies.extend(cps)
-                self.sched.place(slot, got, prefill_done=start_row)
-                if twin is not None:
-                    self.sched.link_twin(slot, twin)
-                placed.append((slot, got))
+                if not queue:
+                    break               # out of requests
+                with span("serve.place") as place:
+                    got, share = None, (None, 0)
+                    while queue and got is None:
+                        req = queue.pop()
+                        if req.done:    # already rejected/finished earlier
+                            continue
+                        verdict, share = self._admissible(slot, req)
+                        if verdict is _DEFER:
+                            queue.defer(req)
+                            break
+                        if verdict is _OVERSIZED:
+                            # streams from the host tier: consumes no
+                            # slot — keep popping for this one.
+                            self._admit_oversized(req)
+                            continue
+                        if verdict:
+                            got = req
+                    if got is None:
+                        break           # out of requests, or deferred
+                    place.set_metadata(rid=got.rid)
+                    start_row = 0
+                    twin = None
+                    if self.sc.paged:
+                        if self.sc.decode_sharing:
+                            # before place(): the ledger must not match
+                            # the request against its own fresh slot.
+                            twin = self.sched.find_twin(got.prompt)
+                        start_row, cps = self._claim_pages(slot, got, share)
+                        copies.extend(cps)
+                    self.sched.place(slot, got, prefill_done=start_row)
+                    if twin is not None:
+                        self.sched.link_twin(slot, twin)
+                    placed.append((slot, got))
         except IotlbFault:
             # strict fault mid-wave: no slot was mutated yet (the faulting
             # request is already marked failed + completed) — put the
@@ -677,8 +694,7 @@ class ServingEngine:
             self.peak_active = max(self.peak_active,
                                    len(self.sched.active()))
             self._apply_copies(copies)
-            self._prefill_tick()    # new slots' first chunk + resumed ones
-        return len(placed)
+        return placed
 
     def warmup(self) -> None:
         """Compile the jitted prefill (both traces: fresh and resumed)
@@ -755,69 +771,79 @@ class ServingEngine:
 
     def _prefill_dispatch(self, work) -> None:
         """Issue one batched prefill step for ``work`` (same-trace chunks)."""
-        self.sched.mark_dispatch([w[0] for w in work], self.tick_no)
         bsz, sp, ps = self.sc.max_batch, self.sc.max_prompt, self.sc.page_size
-        if self.sc.paged:
-            copies = []
+        with span("serve.prefill", rows=sum(len(w[2]) for w in work),
+                  padded_rows=bsz * sp,
+                  resumed=int(any(w[1] for w in work))):
+            self.sched.mark_dispatch([w[0] for w in work], self.tick_no)
+            if self.sc.paged:
+                with span("serve.iotlb"):
+                    copies = []
+                    for slot, off, toks in work:
+                        # COW barrier + page-granular write coverage for
+                        # the rows this chunk writes (TLB refills are
+                        # counted, true misses fault before any cache
+                        # mutation).
+                        for j in range(off // ps,
+                                       (off + len(toks) - 1) // ps + 1):
+                            cp = self.alloc.privatize(slot, j)
+                            if cp is not None:
+                                copies.append(cp)
+                            self.alloc.check_write(
+                                slot, j * ps, ps, strict=self.sc.strict_iotlb)
+                    self._apply_copies(copies)
+            toks_np = np.zeros((bsz, sp), np.int32)
+            lens_np = np.zeros((bsz,), np.int32)
+            offs_np = np.zeros((bsz,), np.int32)
             for slot, off, toks in work:
-                # COW barrier + page-granular write coverage for the rows
-                # this chunk writes (TLB refills are counted, true misses
-                # fault before any cache mutation).
-                for j in range(off // ps, (off + len(toks) - 1) // ps + 1):
-                    cp = self.alloc.privatize(slot, j)
-                    if cp is not None:
-                        copies.append(cp)
-                    self.alloc.check_write(slot, j * ps, ps,
-                                           strict=self.sc.strict_iotlb)
-            self._apply_copies(copies)
-        toks_np = np.zeros((bsz, sp), np.int32)
-        lens_np = np.zeros((bsz,), np.int32)
-        offs_np = np.zeros((bsz,), np.int32)
-        for slot, off, toks in work:
-            toks_np[slot, :len(toks)] = toks
-            lens_np[slot] = len(toks)
-            offs_np[slot] = off
-        toks, lens = jnp.asarray(toks_np), jnp.asarray(lens_np)
-        if self.sc.paged:
-            # all-fresh waves (the common case) pass offsets=None — a
-            # separate trace of the same jitted step that keeps the
-            # single-pass chunk kernel instead of the full-window gather.
-            offs = jnp.asarray(offs_np) if offs_np.any() else None
-            with self._kernel_ctx():
-                logits, self.cache = self._prefill(
-                    self.params, self.cache, toks, lens, self._pages_dev(),
-                    offs)
-        else:
-            logits, self.cache = self._prefill(self.params, self.cache,
-                                               toks, lens)
-        # sample only when some prompt completes this tick: intermediate
-        # chunks discard their logits, and at temperature > 0 sampling
-        # consumes PRNG key state, so ticks that emit nothing must not
-        # burn splits.  (The engine-wide key still makes sampled streams
-        # depend on co-admission order in mixed waves; fully
-        # schedule-independent sampling needs per-request keys — the
-        # greedy path, which every equivalence test uses, is exact.)
-        finishes = any(
-            off + len(toks) >= len(self.sched.slots[slot].req.prompt)
-            for slot, off, toks in work)
-        firsts = np.asarray(self._sample(logits)) if finishes else None
-        lg_np = np.asarray(logits) if self.sc.record_logits else None
-        for slot, off, chunk_toks in work:
-            meta = self.sched.slots[slot]
-            meta.prefill_done = off + len(chunk_toks)
-            if not meta.prefilled:
-                continue            # more chunks to come; logits discarded
-            req = meta.req
-            first = int(firsts[slot])
-            self.positions[slot] = len(req.prompt)
-            self.last_token[slot] = first
-            req.out_tokens.append(first)    # the post-prompt prediction
-            self.sched.note_first_token(req, self.tick_no)
-            if lg_np is not None:
-                req.logits.append(lg_np[slot].copy())
-            if first == self.sc.eos_id or \
-                    len(req.out_tokens) >= self.sc.max_new_tokens:
-                self._finish(slot)
+                toks_np[slot, :len(toks)] = toks
+                lens_np[slot] = len(toks)
+                offs_np[slot] = off
+            toks, lens = jnp.asarray(toks_np), jnp.asarray(lens_np)
+            if self.sc.paged:
+                # all-fresh waves (the common case) pass offsets=None — a
+                # separate trace of the same jitted step that keeps the
+                # single-pass chunk kernel instead of the full-window
+                # gather.
+                offs = jnp.asarray(offs_np) if offs_np.any() else None
+                with self._kernel_ctx():
+                    logits, self.cache = self._prefill(
+                        self.params, self.cache, toks, lens,
+                        self._pages_dev(), offs)
+            else:
+                logits, self.cache = self._prefill(self.params, self.cache,
+                                                   toks, lens)
+            # sample only when some prompt completes this tick: intermediate
+            # chunks discard their logits, and at temperature > 0 sampling
+            # consumes PRNG key state, so ticks that emit nothing must not
+            # burn splits.  (The engine-wide key still makes sampled
+            # streams depend on co-admission order in mixed waves; fully
+            # schedule-independent sampling needs per-request keys — the
+            # greedy path, which every equivalence test uses, is exact.)
+            finishes = any(
+                off + len(toks) >= len(self.sched.slots[slot].req.prompt)
+                for slot, off, toks in work)
+            with span("serve.sync"):
+                firsts = (np.asarray(self._sample(logits)) if finishes
+                          else None)
+                lg_np = (np.asarray(logits) if self.sc.record_logits
+                         else None)
+            for slot, off, chunk_toks in work:
+                meta = self.sched.slots[slot]
+                meta.prefill_done = off + len(chunk_toks)
+                if not meta.prefilled:
+                    continue        # more chunks to come; logits discarded
+                req = meta.req
+                first = int(firsts[slot])
+                self.positions[slot] = len(req.prompt)
+                self.last_token[slot] = first
+                req.out_tokens.append(first)    # the post-prompt prediction
+                self.sched.note_first_token(req, self.tick_no)
+                if lg_np is not None:
+                    req.logits.append(lg_np[slot].copy())
+                if first == self.sc.eos_id or \
+                        len(req.out_tokens) >= self.sc.max_new_tokens:
+                    self._finish(slot)
 
     def _sample(self, logits):
         logits = logits.astype(jnp.float32)
@@ -828,26 +854,27 @@ class ServingEngine:
 
     def _finish(self, slot: int):
         req = self.sched.slots[slot].req
-        req.done = True
-        self.sched.note_terminal(req)   # deadline miss if no first token
-        self.completed.append(req)
-        # twin links die with either party; an orphaned follower keeps
-        # its shared pages (release_slot below drops this side's refs,
-        # leaving the survivor sole owner) and the restored COW barrier
-        # covers any write that would still land in one.
-        self.sched.break_twins(slot)
-        self.sched.release(slot)    # release slot
-        if self._drafter is not None:
-            self._drafter.release(slot)
-        if self.sc.paged:
-            # drop this slot's pending restore transfers BEFORE the
-            # allocator cancels their bookkeeping — a stale entry here
-            # would try to finish_restore a key the allocator forgot.
-            for key in [k for k in self._inflight_data if k[0] == slot]:
-                self._inflight_data.pop(key)
-            if self.tiered:
-                self._drop_evictions(slot)
-            self.alloc.release_slot(slot)   # refs return to the pool
+        with span("serve.finish", rid=req.rid):
+            req.done = True
+            self.sched.note_terminal(req)   # deadline miss if no first token
+            self.completed.append(req)
+            # twin links die with either party; an orphaned follower keeps
+            # its shared pages (release_slot below drops this side's refs,
+            # leaving the survivor sole owner) and the restored COW barrier
+            # covers any write that would still land in one.
+            self.sched.break_twins(slot)
+            self.sched.release(slot)    # release slot
+            if self._drafter is not None:
+                self._drafter.release(slot)
+            if self.sc.paged:
+                # drop this slot's pending restore transfers BEFORE the
+                # allocator cancels their bookkeeping — a stale entry here
+                # would try to finish_restore a key the allocator forgot.
+                for key in [k for k in self._inflight_data if k[0] == slot]:
+                    self._inflight_data.pop(key)
+                if self.tiered:
+                    self._drop_evictions(slot)
+                self.alloc.release_slot(slot)   # refs return to the pool
 
     # -- device <-> host page movement --------------------------------------
     def _map_cache(self, fn_pool, fn_slot):
@@ -1653,8 +1680,9 @@ class ServingEngine:
                 self.params, self.cache, jnp.asarray(toks_np),
                 jnp.asarray(lens_np), self._pages_dev(),
                 jnp.asarray(offs_np))
-        greedy = np.asarray(verify_greedy_tokens(logits))
-        lg_np = np.asarray(logits) if self.sc.record_logits else None
+        with span("serve.sync"):
+            greedy = np.asarray(verify_greedy_tokens(logits))
+            lg_np = np.asarray(logits) if self.sc.record_logits else None
         for i in active:
             req = self.sched.slots[i].req
             d = proposals.get(i, [])
@@ -1716,7 +1744,8 @@ class ServingEngine:
         decode dispatch per tick."""
         t0 = time.perf_counter()
         if self.tiered:
-            self._tier_tick()
+            with span("serve.tier"):
+                self._tier_tick()
         if self.sc.paged and self.sched.has_prefill_work() \
                 and not self._prefilled_since_step:
             self._prefill_tick()
@@ -1736,7 +1765,8 @@ class ServingEngine:
                     blocked = self._blocked_decode(
                         self.sched.decode_slots())
                 runnable = [i for i in runnable if i not in blocked]
-            self._grow_pages(runnable)
+            with span("serve.grow"):
+                self._grow_pages(runnable)
             runnable = set(runnable)
             active = [i for i in self.sched.decode_slots()
                       if i in runnable]   # growth may have swapped slots
@@ -1748,9 +1778,17 @@ class ServingEngine:
             return
         self.sched.mark_dispatch(active, self.tick_no)
         if self._drafter is not None:
-            self._spec_round(active)
+            with span("serve.spec"):
+                self._spec_round(active)
             self._end_tick(t0)
             return
+        with span("serve.decode"):
+            self._decode_dispatch(active)
+        self._end_tick(t0)
+
+    def _decode_dispatch(self, active: List[int]) -> None:
+        """Stage, dispatch and read one decode step for ``active``, then
+        emit each slot's token."""
         # host-side staging: ONE mask/position build + one transfer per
         # tick, not one .at[i].set dispatch per active slot.
         mask_np = np.zeros((self.sc.max_batch,), bool)
@@ -1765,28 +1803,30 @@ class ServingEngine:
         else:
             logits, self.cache = self._decode(self.params, self.cache, toks,
                                               pos_v)
-        nxt = np.asarray(self._sample(logits))
-        lg_np = np.asarray(logits) if self.sc.record_logits else None
+        with span("serve.sync"):
+            nxt = np.asarray(self._sample(logits))
+            lg_np = np.asarray(logits) if self.sc.record_logits else None
         self.last_token = np.where(mask_np, nxt,
                                    self.last_token).astype(np.int32)
         self.positions = np.where(mask_np, self.positions + 1,
                                   self.positions).astype(np.int32)
-        for i in active:
-            req = self.sched.slots[i].req
-            tok = int(nxt[i])
-            req.out_tokens.append(tok)
-            if lg_np is not None:
-                req.logits.append(lg_np[i].copy())
-            if self.sc.decode_sharing and \
-                    not self.sched.check_twin_token(i):
-                # divergence (unreachable for greedy twins; ledger
-                # defense): break the link so the COW barrier privatizes
-                # any still-shared page before the next write.
-                self.sched.break_twins(i)
-            if tok == self.sc.eos_id or \
-                    len(req.out_tokens) >= self.sc.max_new_tokens:
-                self._finish(i)
-        self._end_tick(t0)
+        with span("serve.emit"):
+            for i in active:
+                req = self.sched.slots[i].req
+                tok = int(nxt[i])
+                req.out_tokens.append(tok)
+                if lg_np is not None:
+                    req.logits.append(lg_np[i].copy())
+                if self.sc.decode_sharing and \
+                        not self.sched.check_twin_token(i):
+                    # divergence (unreachable for greedy twins; ledger
+                    # defense): break the link so the COW barrier
+                    # privatizes any still-shared page before the next
+                    # write.
+                    self.sched.break_twins(i)
+                if tok == self.sc.eos_id or \
+                        len(req.out_tokens) >= self.sc.max_new_tokens:
+                    self._finish(i)
 
     def _end_tick(self, t0: float) -> None:
         """Per-tick epilogue: oversized streams advance (one dispatch
@@ -1797,7 +1837,8 @@ class ServingEngine:
         if self._oversized:
             self._oversized_tick()
         if self.tiered:
-            self._tier_prefetch()
+            with span("serve.tier"):
+                self._tier_prefetch()
             dt = time.perf_counter() - t0
             self._tick_ema = (dt if self._tick_ema is None
                               else 0.9 * self._tick_ema + 0.1 * dt)
@@ -1814,9 +1855,10 @@ class ServingEngine:
             raise RuntimeError(
                 "ServingEngine is closed: submit() after drain() — "
                 "construct a new engine (or use run() before draining)")
-        if req.submit_tick is None:
-            req.submit_tick = self.tick_no
-        self.sched.submit(req)
+        with span("serve.submit", rid=req.rid):
+            if req.submit_tick is None:
+                req.submit_tick = self.tick_no
+            self.sched.submit(req)
         return RequestHandle(self, req)
 
     def tick(self) -> None:
@@ -1826,8 +1868,9 @@ class ServingEngine:
         then one decode dispatch for the prompt-complete slots.  Safe to
         call when idle (no-op dispatches are skipped)."""
         self.tick_no += 1
-        self._admission_wave(_SchedQueue(self.sched))
-        self.step()
+        with span("serve.tick"):
+            self._admission_wave(_SchedQueue(self.sched))
+            self.step()
 
     def drain(self) -> List[Request]:
         """Serve every outstanding submission to completion, then CLOSE
