@@ -54,7 +54,7 @@ from repro.models.common import (ParamSpec, broadcast_offset, chunk_lengths,
                                  page_resident_rows, paged_gather,
                                  paged_gather_quant, paged_scatter,
                                  paged_scatter_quant, rms_norm, rope,
-                                 shard_local_pages)
+                                 shard_local_pages, stacked_as_pool)
 
 NEG_INF = -1e30
 # per-shard score-chunk budget (bytes) used to pick the query chunk size.
@@ -348,9 +348,10 @@ def paged_pool_axes(num_pages: int):
     The pool is sharded when a rule table maps the 'pages' logical axis
     onto present mesh axes AND the pool page count divides them (pages
     stripe page-aligned: shard ``i`` physically holds global pages
-    [i * num_pages/N, (i+1) * num_pages/N)).  A size-1 striping still
-    takes the shard_map path, so 1-shard and N-shard pools run the same
-    code and stay bit-comparable."""
+    [i * num_pages/N, (i+1) * num_pages/N) of every layer).  A size-1
+    striping still takes the shard_map path, so 1-shard and N-shard pools
+    run the same code and stay bit-comparable.  Callers pass the page
+    count of a stacked (L, num_pages, ...) pool leaf, ``shape[1]``."""
     mesh, axes = mesh_axes_for("pages")
     if mesh is None or not axes or num_pages % _axes_size(mesh, axes):
         return None, ()
@@ -366,9 +367,10 @@ def _pool_page0(mesh, axes, n_local: int):
 
 
 def _pool_spec(ndim: int) -> P:
-    """PartitionSpec striping a pool leaf's leading (page) axis."""
+    """PartitionSpec striping a stacked (L, num_pages, ...) pool leaf's
+    page axis (axis 1): each shard holds 1/N of every layer's pages."""
     ax = make_spec(("pages",))[0]
-    return P(ax, *([None] * (ndim - 1)))
+    return P(None, ax, *([None] * (ndim - 2)))
 
 
 def _page_partials(q, kw, vw, tbl, qpos, kv_valid):
@@ -451,41 +453,45 @@ def _combine_page_partials(m, l, acc):
     return accg / jnp.maximum(lg, 1e-30)[..., None]
 
 
-def sharded_paged_scatter(pool, pages, rows, t, valid):
-    """:func:`paged_scatter` against a (possibly page-striped) pool.
+def sharded_paged_scatter(pool, pages, rows, t, valid, layer):
+    """:func:`paged_scatter` into layer ``layer`` of a stacked (possibly
+    page-striped) pool leaf.
 
     Replicated pool: the plain scatter.  Striped pool: each shard
     translates the global table to its local indices and applies only
     the writes landing on pages it physically holds — the rest are
     dropped locally (they land on their owning shard instead), so no
     cross-shard traffic is issued for a pure cache write."""
-    mesh, axes = paged_pool_axes(pool.shape[0])
+    mesh, axes = paged_pool_axes(pool.shape[1])
     if mesh is None:
-        return paged_scatter(pool, pages, rows, t, valid)
+        return paged_scatter(pool, pages, rows, t, valid, layer)
     pspec = _pool_spec(pool.ndim)
 
-    def body(pl, tbl, rw, tt, ok):
-        lt = shard_local_pages(tbl, _pool_page0(mesh, axes, pl.shape[0]),
-                               pl.shape[0])
-        return paged_scatter(pl, lt, rw, tt, ok)
+    def body(pl, tbl, rw, tt, ok, li):
+        lt = shard_local_pages(tbl, _pool_page0(mesh, axes, pl.shape[1]),
+                               pl.shape[1])
+        return paged_scatter(pl, lt, rw, tt, ok, li)
 
     return jax.shard_map(body, mesh=mesh,
-                         in_specs=(pspec, P(), P(), P(), P()),
+                         in_specs=(pspec, P(), P(), P(), P(), P()),
                          out_specs=pspec, check_vma=False)(
-                             pool, pages, rows, t, valid)
+                             pool, pages, rows, t, valid, layer)
 
 
 def _paged_flash_striped(cache, pages, k, v, q, t, ok, qpos, kvv, mesh,
-                         axes):
+                         axes, layer):
     """The one shard_map body both striped GQA paths share: translate
-    the table shard-local, scatter the new rows that land here, gather
-    the slot windows out of the LOCAL pool slice (non-resident rows are
-    garbage and masked — pool reads/writes stay shard-local; the score
-    compute itself is still window-shaped per shard), take per-logical-
-    page flash partials, pmax/psum them across the stripe, and run the
-    canonical page-axis combine.  ``qpos`` (B, Sq) / ``kvv`` (B,) carry
-    the causal/fill predicates: decode passes (pos, pos+1), resume
-    passes (offset+i, offset+len).
+    the table shard-local, scatter the new rows that land here into
+    layer ``layer`` of the stacked pool, gather the slot windows out of
+    the LOCAL pool slice (non-resident rows are garbage and masked —
+    pool reads/writes stay shard-local; the score compute itself is
+    still window-shaped per shard), take per-logical-page flash
+    partials, pmax/psum them across the stripe, and run the canonical
+    page-axis combine.  ``qpos`` (B, Sq) / ``kvv`` (B,) carry the
+    causal/fill predicates: decode passes (pos, pos+1), resume passes
+    (offset+i, offset+len).  The pool leaves enter sharded on their page
+    axis (axis 1) and the layer offset applies to SHARD-LOCAL pages, so
+    each shard holds and writes exactly its 1/N of every layer.
 
     Under :func:`repro.kernels.paged_flash_decode.use_pallas_decode`
     (ServeConfig.use_pallas_decode) the gather + lax partials are
@@ -496,17 +502,19 @@ def _paged_flash_striped(cache, pages, k, v, q, t, ok, qpos, kvv, mesh,
     pspec = _pool_spec(cache["k"].ndim)
     kernel_interpret = decode_kernel_config()
 
-    def body(pk, pv, kn, vn, qq, tbl, tt, okk, qp, kv_):
-        n_loc = pk.shape[0]
+    def body(pk, pv, kn, vn, qq, tbl, tt, okk, qp, kv_, li):
+        n_loc = pk.shape[1]
         lt = shard_local_pages(tbl, _pool_page0(mesh, axes, n_loc), n_loc)
-        pk = paged_scatter(pk, lt, kn, tt, okk)
-        pv = paged_scatter(pv, lt, vn, tt, okk)
+        pk = paged_scatter(pk, lt, kn, tt, okk, li)
+        pv = paged_scatter(pv, lt, vn, tt, okk, li)
         if kernel_interpret is not None:
+            tl, fk, fv = stacked_as_pool(lt, li, pk, pv)
             m, l, acc = paged_flash_decode_partials(
-                pk, pv, qq, lt, qp, kv_, interpret=kernel_interpret)
+                fk, fv, qq, tl, qp, kv_, interpret=kernel_interpret)
         else:
-            m, l, acc = _page_partials(qq, paged_gather(pk, lt),
-                                       paged_gather(pv, lt), lt, qp, kv_)
+            m, l, acc = _page_partials(qq, paged_gather(pk, lt, li),
+                                       paged_gather(pv, lt, li), lt, qp,
+                                       kv_)
         m = jax.lax.pmax(m, axes)
         l = jax.lax.psum(l, axes)
         acc = jax.lax.psum(acc, axes)
@@ -516,14 +524,15 @@ def _paged_flash_striped(cache, pages, k, v, q, t, ok, qpos, kvv, mesh,
 
     o, pk, pv = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(pspec, pspec, P(), P(), P(), P(), P(), P(), P(), P()),
+        in_specs=(pspec, pspec, P(), P(), P(), P(), P(), P(), P(), P(),
+                  P()),
         out_specs=(P(), pspec, pspec), check_vma=False)(
-            cache["k"], cache["v"], k, v, q, pages, t, ok, qpos, kvv)
+            cache["k"], cache["v"], k, v, q, pages, t, ok, qpos, kvv, layer)
     return o, {"k": pk, "v": pv}
 
 
 def _paged_flash_striped_quant(cache, pages, k, v, q, t, ok, qpos, kvv,
-                               mesh, axes, fmt):
+                               mesh, axes, fmt, layer):
     """:func:`_paged_flash_striped` for QUANTIZED pools.
 
     The new rows are quantized ONCE, outside the shard_map (per-row
@@ -539,27 +548,27 @@ def _paged_flash_striped_quant(cache, pages, k, v, q, t, ok, qpos, kvv,
     separate from the fp body so ``kv_format='fp'`` traces are untouched.
     """
     pspec = _pool_spec(cache["k"].ndim)
-    sspec = _pool_spec(2)
+    sspec = _pool_spec(cache["k_scale"].ndim)
     kernel_interpret = decode_kernel_config()
     kq, ks = fmt.quantize_rows(k)
     vq, vs = fmt.quantize_rows(v)
 
-    def body(pk, pv, pks, pvs, kn, vn, kns, vns, qq, tbl, tt, okk, qp, kv_):
-        n_loc = pk.shape[0]
+    def body(pk, pv, pks, pvs, kn, vn, kns, vns, qq, tbl, tt, okk, qp, kv_,
+             li):
+        n_loc = pk.shape[1]
         lt = shard_local_pages(tbl, _pool_page0(mesh, axes, n_loc), n_loc)
-        pk = paged_scatter(pk, lt, kn, tt, okk)
-        pv = paged_scatter(pv, lt, vn, tt, okk)
-        pks = paged_scatter(pks, lt, kns, tt, okk)
-        pvs = paged_scatter(pvs, lt, vns, tt, okk)
+        pk = paged_scatter(pk, lt, kn, tt, okk, li)
+        pv = paged_scatter(pv, lt, vn, tt, okk, li)
+        pks = paged_scatter(pks, lt, kns, tt, okk, li)
+        pvs = paged_scatter(pvs, lt, vns, tt, okk, li)
         if kernel_interpret is not None:
+            tl, fk, fv, fks, fvs = stacked_as_pool(lt, li, pk, pv, pks, pvs)
             m, l, acc = paged_flash_decode_partials(
-                pk, pv, qq, lt, qp, kv_, k_scale=pks, v_scale=pvs,
+                fk, fv, qq, tl, qp, kv_, k_scale=fks, v_scale=fvs,
                 bits=fmt.bits, interpret=kernel_interpret)
         else:
-            kw = fmt.dequantize(paged_gather(pk, lt),
-                                paged_gather(pks, lt), qq.dtype)
-            vw = fmt.dequantize(paged_gather(pv, lt),
-                                paged_gather(pvs, lt), qq.dtype)
+            kw = paged_gather_quant(pk, pks, lt, fmt, qq.dtype, li)
+            vw = paged_gather_quant(pv, pvs, lt, fmt, qq.dtype, li)
             m, l, acc = _page_partials(qq, kw, vw, lt, qp, kv_)
         m = jax.lax.pmax(m, axes)
         l = jax.lax.psum(l, axes)
@@ -572,88 +581,70 @@ def _paged_flash_striped_quant(cache, pages, k, v, q, t, ok, qpos, kvv,
     o, pk, pv, pks, pvs = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec, pspec, sspec, sspec,
-                  P(), P(), P(), P(), P(), P(), P(), P(), P(), P()),
+                  P(), P(), P(), P(), P(), P(), P(), P(), P(), P(), P()),
         out_specs=(P(), pspec, pspec, sspec, sspec), check_vma=False)(
             cache["k"], cache["v"], cache["k_scale"], cache["v_scale"],
-            kq, vq, ks, vs, q, pages, t, ok, qpos, kvv)
+            kq, vq, ks, vs, q, pages, t, ok, qpos, kvv, layer)
     return o, {"k": pk, "v": pv, "k_scale": pks, "v_scale": pvs}
 
 
-def _paged_decode(q, k, v, cache, pages, pos_b):
-    """One decode step against the paged pool: scatter this token's K/V
-    through the table, then attend over the slot's logical window.
-
-    Replicated pool (no rules context / TP rules / indivisible pool):
-    the local gather path — bit-identical to the contiguous layout at
-    equal window lengths.  Page-striped pool: the shared shard_map body
-    (:func:`_paged_flash_striped`) with the same pmax/psum flash-
-    decoding reduction ``decode_sdpa`` uses."""
-    t = pos_b[:, None]
+def _paged_attend(local, q, k, v, cache, pages, t, ok, qpos, kvv, layer):
+    """Write the new rows into layer ``layer`` of the stacked paged pool,
+    then attend over each slot's window: on a replicated pool (no rules
+    context / TP rules / indivisible pool) gather the windows back and
+    run ``local(q, kw, vw, kv_ok)``; on a page-striped one, the shared
+    striped shard_map body (per-page flash partials + pmax/psum).
+    Returns (output, new cache)."""
     fmt = cache_page_format(cache, q.shape[-1])
-    mesh, axes = paged_pool_axes(cache["k"].shape[0])
-    if mesh is None:
+    mesh, axes = paged_pool_axes(cache["k"].shape[1])
+    if mesh is not None:
         if fmt is None:
-            new_cache = {"k": paged_scatter(cache["k"], pages, k, t, t >= 0),
-                         "v": paged_scatter(cache["v"], pages, v, t, t >= 0)}
-        else:
-            pk, pks = paged_scatter_quant(cache["k"], cache["k_scale"],
-                                          pages, k, t, t >= 0, fmt)
-            pv, pvs = paged_scatter_quant(cache["v"], cache["v_scale"],
-                                          pages, v, t, t >= 0, fmt)
-            new_cache = {"k": pk, "v": pv, "k_scale": pks, "v_scale": pvs}
-        if fmt is None:
-            kw = paged_gather(new_cache["k"], pages)
-            vw = paged_gather(new_cache["v"], pages)
-        else:
-            kw = paged_gather_quant(new_cache["k"], new_cache["k_scale"],
-                                    pages, fmt, q.dtype)
-            vw = paged_gather_quant(new_cache["v"], new_cache["v_scale"],
-                                    pages, fmt, q.dtype)
-        o = _decode_attention_local(
-            q, kw, vw, jnp.int32(0), pos_b + 1, (),
-            kv_ok=page_resident_rows(pages, cache["k"].shape[1]))
-        return o, new_cache
+            return _paged_flash_striped(cache, pages, k, v, q, t, ok, qpos,
+                                        kvv, mesh, axes, layer)
+        return _paged_flash_striped_quant(cache, pages, k, v, q, t, ok,
+                                          qpos, kvv, mesh, axes, fmt, layer)
     if fmt is None:
-        return _paged_flash_striped(cache, pages, k, v, q, t, t >= 0, t,
-                                    pos_b + 1, mesh, axes)
-    return _paged_flash_striped_quant(cache, pages, k, v, q, t, t >= 0, t,
-                                      pos_b + 1, mesh, axes, fmt)
+        new_cache = {"k": paged_scatter(cache["k"], pages, k, t, ok, layer),
+                     "v": paged_scatter(cache["v"], pages, v, t, ok, layer)}
+        kw = paged_gather(new_cache["k"], pages, layer)
+        vw = paged_gather(new_cache["v"], pages, layer)
+    else:
+        pk, pks = paged_scatter_quant(cache["k"], cache["k_scale"], pages, k,
+                                      t, ok, fmt, layer)
+        pv, pvs = paged_scatter_quant(cache["v"], cache["v_scale"], pages, v,
+                                      t, ok, fmt, layer)
+        new_cache = {"k": pk, "v": pv, "k_scale": pks, "v_scale": pvs}
+        kw = paged_gather_quant(pk, pks, pages, fmt, q.dtype, layer)
+        vw = paged_gather_quant(pv, pvs, pages, fmt, q.dtype, layer)
+    kv_ok = page_resident_rows(pages, cache["k"].shape[2])
+    return local(q, kw, vw, kv_ok), new_cache
 
 
-def _paged_resume(q, k, v, cache, pages, t, ok, off_b, len_b):
+def _paged_decode(q, k, v, cache, pages, pos_b, layer):
+    """One decode step against the paged pool: scatter this token's K/V
+    through the table, then attend over the slot's logical window —
+    bit-identical to the contiguous layout at equal window lengths on a
+    replicated pool; the same pmax/psum flash-decoding reduction
+    ``decode_sdpa`` uses on a striped one."""
+    t = pos_b[:, None]
+    return _paged_attend(
+        lambda q_, kw, vw, kv_ok: _decode_attention_local(
+            q_, kw, vw, jnp.int32(0), pos_b + 1, (), kv_ok=kv_ok),
+        q, k, v, cache, pages, t, t >= 0, t, pos_b + 1, layer)
+
+
+def _paged_resume(q, k, v, cache, pages, t, ok, off_b, len_b, layer):
     """Resumable-chunk attention against the paged pool: scatter the
     chunk's K/V at rows [offset, offset+len), then attend the chunk
-    queries over the slot's whole cached window.  Same replicated-vs-
-    striped split as :func:`_paged_decode`."""
-    fmt = cache_page_format(cache, q.shape[-1])
-    mesh, axes = paged_pool_axes(cache["k"].shape[0])
-    if mesh is None:
-        if fmt is None:
-            new_cache = {"k": paged_scatter(cache["k"], pages, k, t, ok),
-                         "v": paged_scatter(cache["v"], pages, v, t, ok)}
-            kw = paged_gather(new_cache["k"], pages)
-            vw = paged_gather(new_cache["v"], pages)
-        else:
-            pk, pks = paged_scatter_quant(cache["k"], cache["k_scale"],
-                                          pages, k, t, ok, fmt)
-            pv, pvs = paged_scatter_quant(cache["v"], cache["v_scale"],
-                                          pages, v, t, ok, fmt)
-            new_cache = {"k": pk, "v": pv, "k_scale": pks, "v_scale": pvs}
-            kw = paged_gather_quant(pk, pks, pages, fmt, q.dtype)
-            vw = paged_gather_quant(pv, pvs, pages, fmt, q.dtype)
-        o = _resume_attention_local(
-            q, kw, vw, off_b, off_b + len_b,
-            kv_ok=page_resident_rows(pages, cache["k"].shape[1]))
-        return o, new_cache
+    queries over the slot's whole cached window."""
     qpos = off_b[:, None] + jnp.arange(q.shape[1], dtype=jnp.int32)[None]
-    if fmt is None:
-        return _paged_flash_striped(cache, pages, k, v, q, t, ok, qpos,
-                                    off_b + len_b, mesh, axes)
-    return _paged_flash_striped_quant(cache, pages, k, v, q, t, ok, qpos,
-                                      off_b + len_b, mesh, axes, fmt)
+    return _paged_attend(
+        lambda q_, kw, vw, kv_ok: _resume_attention_local(
+            q_, kw, vw, off_b, off_b + len_b, kv_ok=kv_ok),
+        q, k, v, cache, pages, t, ok, qpos, off_b + len_b, layer)
 
 
-def _paged_verify(q, k, v, cache, pages, t, ok, off_b, len_b):
+def _paged_verify(q, k, v, cache, pages, t, ok, off_b, len_b, layer):
     """Speculative VERIFY against the paged pool: scatter the candidate
     rows at [offset, offset + len), then score every row with DECODE-
     order numerics.
@@ -666,32 +657,11 @@ def _paged_verify(q, k, v, cache, pages, t, ok, off_b, len_b):
     row.  Only the REPLICATED pool needs a dedicated scorer
     (:func:`_verify_attention_local`), because there resume uses the
     softmax-order local path while decode uses flash order."""
-    fmt = cache_page_format(cache, q.shape[-1])
-    mesh, axes = paged_pool_axes(cache["k"].shape[0])
-    if mesh is None:
-        if fmt is None:
-            new_cache = {"k": paged_scatter(cache["k"], pages, k, t, ok),
-                         "v": paged_scatter(cache["v"], pages, v, t, ok)}
-            kw = paged_gather(new_cache["k"], pages)
-            vw = paged_gather(new_cache["v"], pages)
-        else:
-            pk, pks = paged_scatter_quant(cache["k"], cache["k_scale"],
-                                          pages, k, t, ok, fmt)
-            pv, pvs = paged_scatter_quant(cache["v"], cache["v_scale"],
-                                          pages, v, t, ok, fmt)
-            new_cache = {"k": pk, "v": pv, "k_scale": pks, "v_scale": pvs}
-            kw = paged_gather_quant(pk, pks, pages, fmt, q.dtype)
-            vw = paged_gather_quant(pv, pvs, pages, fmt, q.dtype)
-        o = _verify_attention_local(
-            q, kw, vw, off_b, off_b + len_b,
-            kv_ok=page_resident_rows(pages, cache["k"].shape[1]))
-        return o, new_cache
     qpos = off_b[:, None] + jnp.arange(q.shape[1], dtype=jnp.int32)[None]
-    if fmt is None:
-        return _paged_flash_striped(cache, pages, k, v, q, t, ok, qpos,
-                                    off_b + len_b, mesh, axes)
-    return _paged_flash_striped_quant(cache, pages, k, v, q, t, ok, qpos,
-                                      off_b + len_b, mesh, axes, fmt)
+    return _paged_attend(
+        lambda q_, kw, vw, kv_ok: _verify_attention_local(
+            q_, kw, vw, off_b, off_b + len_b, kv_ok=kv_ok),
+        q, k, v, cache, pages, t, ok, qpos, off_b + len_b, layer)
 
 
 def _batch_spec(mesh, b: int):
@@ -824,6 +794,7 @@ def apply_attention(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
                     mode: str, pos: jax.Array,
                     pages: Optional[jax.Array] = None,
                     offset: Optional[jax.Array] = None,
+                    layer: Optional[jax.Array] = None,
                     ) -> Tuple[jax.Array, Optional[dict]]:
     """Full attention sublayer: QKV proj, RoPE, SDPA, out proj.
 
@@ -837,10 +808,11 @@ def apply_attention(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
     right-padded chunk whose tokens sit at positions [0, len); in 'decode'
     mode a (B,) vector of per-slot positions (-1 = inactive slot).
     pages: optional (B, P) int32 page table (paged KV cache, serving): the
-    cache is then a (num_pages, page_size, KV, dh) pool and chunk/decode
-    writes scatter through the table; decode gathers the slot's logical
-    window back before attention (bit-identical math to the contiguous
-    layout — only the storage addressing changes).
+    cache is then the layer scan's whole stacked (L, num_pages, page_size,
+    KV, dh) pool, ``layer`` names this layer's slice, and chunk/decode
+    writes scatter through the table into that slice in place; decode
+    gathers the slot's logical window back before attention (bit-identical
+    math to the contiguous layout — only the storage addressing changes).
     offset: optional (B,) int32 — RESUMABLE chunk mode: each slot's chunk
     tokens sit at positions [offset, offset + len) and attend over the
     already-cached history rows [0, offset) too, so a prompt longer than
@@ -899,7 +871,7 @@ def apply_attention(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
         t = off_b[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
         if pages is not None:
             o, new_cache = _paged_resume(q, k, v, cache, pages, t, ok,
-                                         off_b, len_b)
+                                         off_b, len_b, layer)
         else:
             new_cache = {"k": contig_scatter(cache["k"], k, t, ok),
                          "v": contig_scatter(cache["v"], v, t, ok)}
@@ -918,7 +890,8 @@ def apply_attention(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
         ok = chunk_valid_mask(len_b, s)
         t = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
         o, new_cache = _paged_resume(q, k, v, cache, pages, t, ok,
-                                     jnp.zeros((b,), jnp.int32), len_b)
+                                     jnp.zeros((b,), jnp.int32), len_b,
+                                     layer)
     elif mode == "chunk":
         # one causal pass over the whole padded chunk; padded queries sit
         # after every valid token so they never leak into valid outputs,
@@ -929,8 +902,10 @@ def apply_attention(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
                 jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
             ok = chunk_valid_mask(chunk_lengths(pos, b), s)
             new_cache = {
-                "k": sharded_paged_scatter(cache["k"], pages, k, t, ok),
-                "v": sharded_paged_scatter(cache["v"], pages, v, t, ok)}
+                "k": sharded_paged_scatter(cache["k"], pages, k, t, ok,
+                                           layer),
+                "v": sharded_paged_scatter(cache["v"], pages, v, t, ok,
+                                           layer)}
         else:
             new_cache = cache_fill(cache, k, v, pos)
     elif mode == "verify":
@@ -945,12 +920,13 @@ def apply_attention(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
         ok = chunk_valid_mask(len_b, s)
         t = off_b[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
         o, new_cache = _paged_verify(q, k, v, cache, pages, t, ok,
-                                     off_b, len_b)
+                                     off_b, len_b, layer)
     elif mode == "decode":
         assert s == 1
         if pages is not None:
             pos_b = jnp.broadcast_to(jnp.atleast_1d(pos), (b,))
-            o, new_cache = _paged_decode(q, k, v, cache, pages, pos_b)
+            o, new_cache = _paged_decode(q, k, v, cache, pages, pos_b,
+                                         layer)
         else:
             new_cache = cache_update(cache, k, v, pos)
             o = decode_sdpa(q, new_cache["k"], new_cache["v"],

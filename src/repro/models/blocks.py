@@ -3,7 +3,7 @@
 Every block has the same interface:
   specs(cfg)                                  -> ParamSpec tree
   apply(p, x, cfg, cache, mode, pos, pages,
-        offset)                               -> (x', new_cache, aux_loss)
+        offset, layer)                        -> (x', new_cache, aux_loss)
   cache_spec(cfg, batch, capacity)            -> ParamSpec tree or None
   paged_cache_spec(cfg, num_pages, page_size,
                    fmt=pageformat.FP)        -> ParamSpec tree or None
@@ -14,9 +14,14 @@ per-slot state and ignore it.  ``offset`` is the (B,) start row of a
 RESUMABLE chunk (mode='chunk'): attention families scatter/attend at
 absolute rows [offset, offset + len), recurrent families resume their
 cached state when offset > 0; None keeps the single-pass chunk path.
+``layer`` is the layer scan's index when ``cache`` is a paged pool: the
+cache is then the whole stacked (L, num_pages, ...) pool, and the block
+writes and reads only layer ``layer``'s pages, in place
+(models/model.py); recurrent families get None and ignore it.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -84,12 +89,13 @@ def _chunk_token_mask(x, mode, pos):
     return chunk_valid_mask(chunk_lengths(pos, b), s)
 
 
-def _apply_attn_block(p, x, cfg, cache, mode, pos, pages, offset,
+def _apply_attn_block(p, x, cfg, cache, mode, pos, pages, offset, layer,
                       ffn: str):
     x = lshard(x, "batch", "seq", None)
     a, new_cache = apply_attention(
         p["attn"], apply_norm(p["ln1"], x, cfg), cfg,
-        cache=cache, mode=mode, pos=pos, pages=pages, offset=offset)
+        cache=cache, mode=mode, pos=pos, pages=pages, offset=offset,
+        layer=layer)
     x = x + a
     h = apply_norm(p["ln2"], x, cfg)
     if ffn == "moe":
@@ -108,12 +114,13 @@ def _mla_block_specs(cfg, ffn: str) -> dict:
     return s
 
 
-def _apply_mla_block(p, x, cfg, cache, mode, pos, pages, offset,
+def _apply_mla_block(p, x, cfg, cache, mode, pos, pages, offset, layer,
                      ffn: str):
     x = lshard(x, "batch", "seq", None)
     a, new_cache = mla.apply_mla(
         p["attn"], apply_norm(p["ln1"], x, cfg), cfg,
-        cache=cache, mode=mode, pos=pos, pages=pages, offset=offset)
+        cache=cache, mode=mode, pos=pos, pages=pages, offset=offset,
+        layer=layer)
     x = x + a
     h = apply_norm(p["ln2"], x, cfg)
     if ffn == "moe":
@@ -129,23 +136,26 @@ def _mamba_block_specs(cfg) -> dict:
     return {"ln": norm_specs(cfg), "mamba": ssm.mamba_specs(cfg)}
 
 
-def _apply_mamba_block(p, x, cfg, cache, mode, pos, pages, offset):
-    del pages    # recurrent state is per-slot fixed size: paging bypassed
+def _apply_mamba_block(p, x, cfg, cache, mode, pos, pages, offset,
+                       layer):
+    del pages, layer    # per-slot fixed-size recurrent state: not paged
     y, new_cache = ssm.apply_mamba(
         p["mamba"], apply_norm(p["ln"], x, cfg), cfg,
         cache=cache, mode=mode, pos=pos, offset=offset)
     return x + y, new_cache, jnp.float32(0)
 
 
-def _apply_mlstm_block(p, x, cfg, cache, mode, pos, pages, offset):
-    del pages    # recurrent state is per-slot fixed size: paging bypassed
+def _apply_mlstm_block(p, x, cfg, cache, mode, pos, pages, offset,
+                       layer):
+    del pages, layer    # per-slot fixed-size recurrent state: not paged
     y, new_cache = xlstm.apply_mlstm(p, x, cfg, cache=cache, mode=mode,
                                      pos=pos, offset=offset)
     return y, new_cache, jnp.float32(0)
 
 
-def _apply_slstm_block(p, x, cfg, cache, mode, pos, pages, offset):
-    del pages    # recurrent state is per-slot fixed size: paging bypassed
+def _apply_slstm_block(p, x, cfg, cache, mode, pos, pages, offset,
+                       layer):
+    del pages, layer    # per-slot fixed-size recurrent state: not paged
     y, new_cache = xlstm.apply_slstm(p, x, cfg, cache=cache, mode=mode,
                                      pos=pos, offset=offset)
     return y, new_cache, jnp.float32(0)
@@ -164,30 +174,22 @@ class BlockDef:
 BLOCKS = {
     "attn_mlp": BlockDef(
         lambda cfg: _attn_block_specs(cfg, "mlp"),
-        lambda p, x, cfg, cache, mode, pos, pages, offset:
-            _apply_attn_block(p, x, cfg, cache, mode, pos, pages, offset,
-                              "mlp"),
+        functools.partial(_apply_attn_block, ffn="mlp"),
         lambda cfg, b, cap: kv_cache_spec(cfg, b, cap),
         paged_kv_cache_spec),
     "attn_moe": BlockDef(
         lambda cfg: _attn_block_specs(cfg, "moe"),
-        lambda p, x, cfg, cache, mode, pos, pages, offset:
-            _apply_attn_block(p, x, cfg, cache, mode, pos, pages, offset,
-                              "moe"),
+        functools.partial(_apply_attn_block, ffn="moe"),
         lambda cfg, b, cap: kv_cache_spec(cfg, b, cap),
         paged_kv_cache_spec),
     "mla_mlp": BlockDef(
         lambda cfg: _mla_block_specs(cfg, "mlp"),
-        lambda p, x, cfg, cache, mode, pos, pages, offset:
-            _apply_mla_block(p, x, cfg, cache, mode, pos, pages, offset,
-                             "mlp"),
+        functools.partial(_apply_mla_block, ffn="mlp"),
         lambda cfg, b, cap: mla.mla_cache_spec(cfg, b, cap),
         mla.paged_mla_cache_spec),
     "mla_moe": BlockDef(
         lambda cfg: _mla_block_specs(cfg, "moe"),
-        lambda p, x, cfg, cache, mode, pos, pages, offset:
-            _apply_mla_block(p, x, cfg, cache, mode, pos, pages, offset,
-                             "moe"),
+        functools.partial(_apply_mla_block, ffn="moe"),
         lambda cfg, b, cap: mla.mla_cache_spec(cfg, b, cap),
         mla.paged_mla_cache_spec),
     "mamba": BlockDef(

@@ -197,7 +197,8 @@ def page_resident_rows(pages: jax.Array, page_size: int) -> jax.Array:
     return jnp.repeat(pages >= 0, page_size, axis=1)
 
 
-def paged_gather(pool: jax.Array, pages: jax.Array) -> jax.Array:
+def paged_gather(pool: jax.Array, pages: jax.Array,
+                 layer=None) -> jax.Array:
     """Gather a slot's logical cache window out of a paged row pool.
 
     ``pool``: (num_pages, page_size, *rest) physical pages shared by every
@@ -209,6 +210,13 @@ def paged_gather(pool: jax.Array, pages: jax.Array) -> jax.Array:
     (``kv_valid`` / ``kpos <= pos``), exactly as rows past the fill level
     already are in the contiguous layout.
 
+    ``layer``: with a (traced) layer index, ``pool`` is the STACKED
+    (L, num_pages, page_size, *rest) leaf the layer scan carries, and the
+    window is read straight out of layer ``layer``'s pages (page
+    ``layer * num_pages + page`` of the leaf seen as one run of pages) —
+    the same rows ``paged_gather(pool[layer], pages)`` reads, without
+    slicing the layer out first.
+
     This materializes the whole (B, P*page_size, *rest) window in HBM
     before any score math runs.  On the page-striped decode/resume hot
     path, ``ServeConfig.use_pallas_decode`` replaces this gather + the
@@ -218,11 +226,31 @@ def paged_gather(pool: jax.Array, pages: jax.Array) -> jax.Array:
     window; this function remains the canonical layout definition (and
     the prefill/replicated-pool path).
     """
-    n, ps = pool.shape[:2]
-    flat = pool.reshape((n * ps,) + pool.shape[2:])
-    idx = jnp.maximum(pages, 0)[:, :, None] * ps + \
-        jnp.arange(ps, dtype=jnp.int32)[None, None, :]
-    return flat[idx.reshape(pages.shape[0], -1)]
+    lead = 0 if layer is None else 1
+    n, ps = pool.shape[lead:lead + 2]
+    flat = pool.reshape((-1,) + pool.shape[lead + 2:])
+    page = jnp.maximum(pages, 0)
+    if layer is not None:
+        page = page + layer * n
+    # whole pages of ps rows, not single rows: a page is one contiguous
+    # block of the pool, and a row of a few KV heads is too small to
+    # fetch alone at speed.  Slicing the row view keeps the pool's layout.
+    take = jax.vmap(jax.vmap(
+        lambda r: jax.lax.dynamic_slice_in_dim(flat, r, ps)))
+    win = take(page * ps)
+    return win.reshape((pages.shape[0], -1) + win.shape[3:])
+
+
+def stacked_as_pool(pages: jax.Array, layer, *pools: jax.Array):
+    """Layer ``layer`` of stacked (L, num_pages, page_size, *rest) pool
+    leaves, for the fused kernels, which take plain page pools: each leaf
+    as one (L * num_pages, page_size, *rest) pool (a free reshape), and
+    the page table that addresses layer ``layer`` in it (mapped entries
+    move by ``layer * num_pages``, -1 stays unmapped).  Returns
+    ``(table, *pools)``."""
+    n = pools[0].shape[1]
+    return (jnp.where(pages >= 0, pages + layer * n, -1),
+            *(p.reshape((-1,) + p.shape[2:]) for p in pools))
 
 
 def shard_local_pages(pages: jax.Array, page0, n_local: int) -> jax.Array:
@@ -241,7 +269,7 @@ def shard_local_pages(pages: jax.Array, page0, n_local: int) -> jax.Array:
 
 
 def paged_scatter(pool: jax.Array, pages: jax.Array, rows: jax.Array,
-                  t: jax.Array, valid: jax.Array) -> jax.Array:
+                  t: jax.Array, valid: jax.Array, layer=None) -> jax.Array:
     """Scatter per-slot rows into a paged pool at logical positions.
 
     ``pool``: (num_pages, page_size, *rest); ``pages``: (B, P) page table;
@@ -250,13 +278,22 @@ def paged_scatter(pool: jax.Array, pages: jax.Array, rows: jax.Array,
     logical window, or land on an unmapped (-1) page-table entry are
     DROPPED — the software analogue of the IOTLB sinking an out-of-window
     AXI write — so an inactive or padded slot never touches the pool.
+
+    ``layer``: ``pool`` is then the stacked (L, num_pages, page_size,
+    *rest) leaf and only layer ``layer``'s pages are written (see
+    :func:`paged_gather`).  Inside the layer scan the leaf rides in the
+    carry, so the scatter updates it in place: a step writes its new rows
+    and nothing else of the pool.
     """
-    n, ps = pool.shape[:2]
+    lead = 0 if layer is None else 1
+    n, ps = pool.shape[lead:lead + 2]
+    flat = pool.reshape((-1,) + pool.shape[lead + 2:])
     p = pages.shape[1]
-    flat = pool.reshape((n * ps,) + pool.shape[2:])
     page = jnp.take_along_axis(pages, jnp.clip(t // ps, 0, p - 1), axis=1)
     ok = valid & (page >= 0) & (t >= 0) & (t < p * ps)
-    dest = jnp.where(ok, page * ps + t % ps, n * ps)    # out of bounds = drop
+    if layer is not None:
+        page = page + layer * n
+    dest = jnp.where(ok, page * ps + t % ps, flat.shape[0])  # OOB = drop
     flat = flat.at[dest.reshape(-1)].set(
         rows.astype(pool.dtype).reshape((-1,) + rows.shape[2:]), mode="drop")
     return flat.reshape(pool.shape)
@@ -264,7 +301,7 @@ def paged_scatter(pool: jax.Array, pages: jax.Array, rows: jax.Array,
 
 def paged_scatter_quant(pool: jax.Array, scales: jax.Array,
                         pages: jax.Array, rows: jax.Array, t: jax.Array,
-                        valid: jax.Array, fmt):
+                        valid: jax.Array, fmt, layer=None):
     """:func:`paged_scatter` for a QUANTIZED pool: quantize ``rows`` at
     the write boundary (per-row absmax, packed per ``fmt`` — see
     :mod:`repro.core.pageformat`) and scatter the packed bytes into
@@ -272,22 +309,23 @@ def paged_scatter_quant(pool: jax.Array, scales: jax.Array,
     through the SAME page table.  A row's quantized bytes depend only on
     its own fp values, so re-writing identical rows (resume, swap-in,
     COW re-fill) reproduces identical pool bytes regardless of chunking.
-    Returns (new_pool, new_scales)."""
+    Returns (new_pool, new_scales); ``layer`` as in :func:`paged_scatter`."""
     q, s = fmt.quantize_rows(rows)
-    return (paged_scatter(pool, pages, q, t, valid),
-            paged_scatter(scales, pages, s, t, valid))
+    return (paged_scatter(pool, pages, q, t, valid, layer),
+            paged_scatter(scales, pages, s, t, valid, layer))
 
 
 def paged_gather_quant(pool: jax.Array, scales: jax.Array,
-                       pages: jax.Array, fmt, dtype) -> jax.Array:
+                       pages: jax.Array, fmt, dtype, layer=None) -> jax.Array:
     """Gather + dequantize a slot window out of a quantized pool.
 
     The fp analogue of :func:`paged_gather`: unpacks and rescales the
     gathered (B, W, *rest) rows with their per-row scales.  Rows under
     unmapped table entries are garbage exactly as in the fp layout and
-    MUST be masked by the caller's validity predicate."""
-    return fmt.dequantize(paged_gather(pool, pages),
-                          paged_gather(scales, pages), dtype)
+    MUST be masked by the caller's validity predicate.  ``layer`` as in
+    :func:`paged_gather`."""
+    return fmt.dequantize(paged_gather(pool, pages, layer),
+                          paged_gather(scales, pages, layer), dtype)
 
 
 def rms_norm(x: jax.Array, w: jax.Array, eps: float = 1e-6) -> jax.Array:

@@ -31,7 +31,7 @@ from repro.models.common import (ParamSpec, broadcast_offset, chunk_lengths,
                                  page_resident_rows, paged_gather,
                                  paged_gather_quant, paged_scatter,
                                  paged_scatter_quant, rms_norm, rope,
-                                 shard_local_pages)
+                                 shard_local_pages, stacked_as_pool)
 
 
 def mla_dims(cfg):
@@ -122,7 +122,7 @@ def _mla_window_partials(buf, qc, qr, lt, pb, r, scale_dim):
 
 
 def _mla_paged_decode(q_c, q_rope, entry, cache, pages, pos_b, r,
-                      scale_dim, fmt):
+                      scale_dim, fmt, layer):
     """Absorbed-form decode against a PAGE-STRIPED compressed pool.
 
     Each shard scatters/gathers only its resident pages and computes
@@ -141,25 +141,29 @@ def _mla_paged_decode(q_c, q_rope, entry, cache, pages, pos_b, r,
     shard_map, packed bytes + scale scatter through the same local
     table (the ckv_scale pool is striped by the same page axis), and
     the read side dequantizes the window (lax) / the VMEM page block
-    (kernel) with the identical op sequence.
+    (kernel) with the identical op sequence.  ``layer`` picks the layer
+    of the stacked pool, which enters sharded on its page axis (axis 1).
     """
     pool = cache["ckv"]
-    mesh, axes = paged_pool_axes(pool.shape[0])
+    mesh, axes = paged_pool_axes(pool.shape[1])
     pspec = _pool_spec(pool.ndim)
     kernel_interpret = decode_kernel_config()
 
     if fmt is None:
-        def body(pl, en, qc, qr, tbl, pb):
-            n_loc = pl.shape[0]
+        def body(pl, en, qc, qr, tbl, pb, li):
+            n_loc = pl.shape[1]
             lt = shard_local_pages(tbl, _pool_page0(mesh, axes, n_loc),
                                    n_loc)
-            pl = paged_scatter(pl, lt, en, pb[:, None], (pb >= 0)[:, None])
+            pl = paged_scatter(pl, lt, en, pb[:, None], (pb >= 0)[:, None],
+                               li)
             if kernel_interpret is not None:
+                tl, fp = stacked_as_pool(lt, li, pl)
                 m, l, acc = mla_paged_decode_partials(
-                    pl, qc, qr, lt, pb, r, scale_dim,
+                    fp, qc, qr, tl, pb, r, scale_dim,
                     interpret=kernel_interpret)
             else:
-                buf = paged_gather(pl, lt)  # slot window, local pages only
+                # slot window, local pages only
+                buf = paged_gather(pl, lt, li)
                 m, l, acc = _mla_window_partials(buf, qc, qr, lt, pb, r,
                                                  scale_dim)
             m = jax.lax.pmax(m, axes)
@@ -168,26 +172,27 @@ def _mla_paged_decode(q_c, q_rope, entry, cache, pages, pos_b, r,
             return _combine_page_partials(m, l, acc), pl
 
         ctx_c, pl = jax.shard_map(
-            body, mesh=mesh, in_specs=(pspec, P(), P(), P(), P(), P()),
+            body, mesh=mesh, in_specs=(pspec, P(), P(), P(), P(), P(), P()),
             out_specs=(P(), pspec), check_vma=False)(
-                pool, entry, q_c, q_rope, pages, pos_b)
+                pool, entry, q_c, q_rope, pages, pos_b, layer)
         return ctx_c, {"ckv": pl}
 
-    sspec = _pool_spec(2)
+    sspec = _pool_spec(cache["ckv_scale"].ndim)
     eq, es = fmt.quantize_rows(entry)
 
-    def body_q(pl, pls, en, ens, qc, qr, tbl, pb):
-        n_loc = pl.shape[0]
+    def body_q(pl, pls, en, ens, qc, qr, tbl, pb, li):
+        n_loc = pl.shape[1]
         lt = shard_local_pages(tbl, _pool_page0(mesh, axes, n_loc), n_loc)
-        pl = paged_scatter(pl, lt, en, pb[:, None], (pb >= 0)[:, None])
-        pls = paged_scatter(pls, lt, ens, pb[:, None], (pb >= 0)[:, None])
+        pl = paged_scatter(pl, lt, en, pb[:, None], (pb >= 0)[:, None], li)
+        pls = paged_scatter(pls, lt, ens, pb[:, None], (pb >= 0)[:, None],
+                            li)
         if kernel_interpret is not None:
+            tl, fp, fps = stacked_as_pool(lt, li, pl, pls)
             m, l, acc = mla_paged_decode_partials(
-                pl, qc, qr, lt, pb, r, scale_dim, scale_pool=pls,
+                fp, qc, qr, tl, pb, r, scale_dim, scale_pool=fps,
                 bits=fmt.bits, interpret=kernel_interpret)
         else:
-            buf = fmt.dequantize(paged_gather(pl, lt),
-                                 paged_gather(pls, lt), qc.dtype)
+            buf = paged_gather_quant(pl, pls, lt, fmt, qc.dtype, li)
             m, l, acc = _mla_window_partials(buf, qc, qr, lt, pb, r,
                                              scale_dim)
         m = jax.lax.pmax(m, axes)
@@ -197,14 +202,15 @@ def _mla_paged_decode(q_c, q_rope, entry, cache, pages, pos_b, r,
 
     ctx_c, pl, pls = jax.shard_map(
         body_q, mesh=mesh,
-        in_specs=(pspec, sspec, P(), P(), P(), P(), P(), P()),
+        in_specs=(pspec, sspec, P(), P(), P(), P(), P(), P(), P()),
         out_specs=(P(), pspec, sspec), check_vma=False)(
-            pool, cache["ckv_scale"], eq, es, q_c, q_rope, pages, pos_b)
+            pool, cache["ckv_scale"], eq, es, q_c, q_rope, pages, pos_b,
+            layer)
     return ctx_c, {"ckv": pl, "ckv_scale": pls}
 
 
 def _mla_paged_resume(p, qq, entry, cache, pages, t, ok, off_b, len_b, cfg,
-                      dims, fmt):
+                      dims, fmt, layer):
     """Resumable-chunk MLA against the paged compressed pool: scatter the
     chunk's compressed entries, expand the slot's cached window back
     through W_UK/W_UV, attend with absolute causal masking.  Replicated
@@ -214,10 +220,11 @@ def _mla_paged_resume(p, qq, entry, cache, pages, t, ok, off_b, len_b, cfg,
     with pmax/psum (see attention._page_partials).  Quantized ``fmt``:
     entries quantize once before the write and every read dequantizes
     from the pool (including this chunk's own rows), so the chunk
-    schedule cannot change which bytes a row contributes."""
+    schedule cannot change which bytes a row contributes.  ``layer``
+    picks the layer of the stacked pool, as in :func:`_mla_paged_decode`."""
     b, h, r, dn, dr, dv = dims
     pool = cache["ckv"]
-    mesh, axes = paged_pool_axes(pool.shape[0])
+    mesh, axes = paged_pool_axes(pool.shape[1])
 
     def expand_window(buf, w_uk, w_uv):
         w = buf.shape[1]
@@ -231,28 +238,30 @@ def _mla_paged_resume(p, qq, entry, cache, pages, t, ok, off_b, len_b, cfg,
 
     if mesh is None:
         if fmt is None:
-            new_cache = {"ckv": paged_scatter(pool, pages, entry, t, ok)}
-            buf = paged_gather(new_cache["ckv"], pages)
+            new_cache = {"ckv": paged_scatter(pool, pages, entry, t, ok,
+                                              layer)}
+            buf = paged_gather(new_cache["ckv"], pages, layer)
         else:
             pl, pls = paged_scatter_quant(pool, cache["ckv_scale"], pages,
-                                          entry, t, ok, fmt)
+                                          entry, t, ok, fmt, layer)
             new_cache = {"ckv": pl, "ckv_scale": pls}
-            buf = paged_gather_quant(pl, pls, pages, fmt, entry.dtype)
+            buf = paged_gather_quant(pl, pls, pages, fmt, entry.dtype,
+                                     layer)
         k_full, v_w = expand_window(buf, p["w_uk"], p["w_uv"])
         o = _resume_attention_local(
             qq, k_full, v_w, off_b, off_b + len_b,
-            kv_ok=page_resident_rows(pages, pool.shape[1]))
+            kv_ok=page_resident_rows(pages, pool.shape[2]))
         return o, new_cache
 
     pspec = _pool_spec(pool.ndim)
 
     if fmt is None:
-        def body(pl, en, q_, tbl, tt, okk, q0, kvv, w_uk, w_uv):
-            n_loc = pl.shape[0]
+        def body(pl, en, q_, tbl, tt, okk, q0, kvv, w_uk, w_uv, li):
+            n_loc = pl.shape[1]
             lt = shard_local_pages(tbl, _pool_page0(mesh, axes, n_loc),
                                    n_loc)
-            pl = paged_scatter(pl, lt, en, tt, okk)
-            buf = paged_gather(pl, lt)
+            pl = paged_scatter(pl, lt, en, tt, okk, li)
+            buf = paged_gather(pl, lt, li)
             k_full, v_w = expand_window(buf, w_uk, w_uv)
             qpos = q0[:, None] + \
                 jnp.arange(q_.shape[1], dtype=jnp.int32)[None]
@@ -265,22 +274,23 @@ def _mla_paged_resume(p, qq, entry, cache, pages, t, ok, off_b, len_b, cfg,
 
         o, pl = jax.shard_map(
             body, mesh=mesh,
-            in_specs=(pspec, P(), P(), P(), P(), P(), P(), P(), P(), P()),
+            in_specs=(pspec, P(), P(), P(), P(), P(), P(), P(), P(), P(),
+                      P()),
             out_specs=(P(), pspec), check_vma=False)(
                 pool, entry, qq, pages, t, ok, off_b, off_b + len_b,
-                p["w_uk"], p["w_uv"])
+                p["w_uk"], p["w_uv"], layer)
         return o, {"ckv": pl}
 
-    sspec = _pool_spec(2)
+    sspec = _pool_spec(cache["ckv_scale"].ndim)
     eq, es = fmt.quantize_rows(entry)
 
-    def body_q(pl, pls, en, ens, q_, tbl, tt, okk, q0, kvv, w_uk, w_uv):
-        n_loc = pl.shape[0]
+    def body_q(pl, pls, en, ens, q_, tbl, tt, okk, q0, kvv, w_uk, w_uv,
+               li):
+        n_loc = pl.shape[1]
         lt = shard_local_pages(tbl, _pool_page0(mesh, axes, n_loc), n_loc)
-        pl = paged_scatter(pl, lt, en, tt, okk)
-        pls = paged_scatter(pls, lt, ens, tt, okk)
-        buf = fmt.dequantize(paged_gather(pl, lt),
-                             paged_gather(pls, lt), entry.dtype)
+        pl = paged_scatter(pl, lt, en, tt, okk, li)
+        pls = paged_scatter(pls, lt, ens, tt, okk, li)
+        buf = paged_gather_quant(pl, pls, lt, fmt, entry.dtype, li)
         k_full, v_w = expand_window(buf, w_uk, w_uv)
         qpos = q0[:, None] + jnp.arange(q_.shape[1], dtype=jnp.int32)[None]
         m, l, acc = _page_partials(q_, k_full, v_w, lt, qpos, kvv)
@@ -293,10 +303,10 @@ def _mla_paged_resume(p, qq, entry, cache, pages, t, ok, off_b, len_b, cfg,
     o, pl, pls = jax.shard_map(
         body_q, mesh=mesh,
         in_specs=(pspec, sspec, P(), P(), P(), P(), P(), P(), P(), P(),
-                  P(), P()),
+                  P(), P(), P()),
         out_specs=(P(), pspec, sspec), check_vma=False)(
             pool, cache["ckv_scale"], eq, es, qq, pages, t, ok, off_b,
-            off_b + len_b, p["w_uk"], p["w_uv"])
+            off_b + len_b, p["w_uk"], p["w_uv"], layer)
     return o, {"ckv": pl, "ckv_scale": pls}
 
 
@@ -304,7 +314,11 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
               mode: str, pos,
               pages: Optional[jax.Array] = None,
               offset: Optional[jax.Array] = None,
+              layer: Optional[jax.Array] = None,
               ) -> Tuple[jax.Array, Optional[dict]]:
+    """MLA sublayer; the modes, ``pages``, ``offset`` and ``layer`` (the
+    stacked paged pool's layer index) are as in
+    :func:`repro.models.attention.apply_attention`."""
     b, s, d = x.shape
     h = cfg.n_heads
     r = cfg.kv_lora_rank
@@ -346,7 +360,8 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
         if pages is not None:
             o, new_cache = _mla_paged_resume(
                 p, qq, entry, cache, pages, t, ok, off_b, len_b, cfg,
-                (b, h, r, dn, dr, dv), cache_page_format(cache, r + dr))
+                (b, h, r, dn, dr, dv), cache_page_format(cache, r + dr),
+                layer)
         else:
             new_cache = {"ckv": contig_scatter(cache["ckv"], entry, t, ok)}
             buf = new_cache["ckv"]
@@ -374,7 +389,7 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
         o, new_cache = _mla_paged_resume(
             p, qq, entry, cache, pages, t, ok,
             jnp.zeros((b,), jnp.int32), len_b, cfg,
-            (b, h, r, dn, dr, dv), cache_page_format(cache, r + dr))
+            (b, h, r, dn, dr, dv), cache_page_format(cache, r + dr), layer)
     elif mode in ("train", "prefill", "chunk"):
         # naive (expanded) form + shared context-parallel SDPA.
         k_nope = dense(c_kv, p["w_uk"], cfg.quant).reshape(b, s, h, dn)
@@ -401,7 +416,7 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
                     jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
                 ok = chunk_valid_mask(chunk_lengths(pos, b), s)
                 new_cache = {"ckv": sharded_paged_scatter(
-                    cache["ckv"], pages, entry, t, ok)}
+                    cache["ckv"], pages, entry, t, ok, layer)}
             else:
                 buf = cache["ckv"]
                 cap = buf.shape[1]
@@ -417,7 +432,7 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
         # per-slot write at `pos` (negative = inactive slot, no write).
         pos_b = jnp.broadcast_to(jnp.atleast_1d(pos), (b,))
         if pages is not None and \
-                paged_pool_axes(cache["ckv"].shape[0])[0] is not None:
+                paged_pool_axes(cache["ckv"].shape[1])[0] is not None:
             # page-striped pool: shard-local scatter/gather + the
             # cross-shard flash-decoding combine, in compressed space.
             w_uk = p["w_uk"].reshape(r, h, dn)
@@ -425,7 +440,7 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
                              w_uk.astype(jnp.float32))
             ctx_c, new_cache = _mla_paged_decode(
                 q_c.astype(x.dtype), q_rope, entry, cache, pages,
-                pos_b, r, scale_dim, cache_page_format(cache, r + dr))
+                pos_b, r, scale_dim, cache_page_format(cache, r + dr), layer)
             w_uv = p["w_uv"].reshape(r, h, dv)
             o = jnp.einsum("bqhr,rhv->bqhv", ctx_c,
                            w_uv.astype(jnp.float32))
@@ -436,18 +451,19 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
             fmt = cache_page_format(cache, r + dr)
             if fmt is None:
                 pool = paged_scatter(cache["ckv"], pages, entry,
-                                     pos_b[:, None], (pos_b >= 0)[:, None])
+                                     pos_b[:, None], (pos_b >= 0)[:, None],
+                                     layer)
                 new_cache = {"ckv": pool}
                 # slot-ordered logical window; rows past `pos` are masked
                 # below.
-                buf = paged_gather(pool, pages)
+                buf = paged_gather(pool, pages, layer)
             else:
                 pool, scales = paged_scatter_quant(
                     cache["ckv"], cache["ckv_scale"], pages, entry,
-                    pos_b[:, None], (pos_b >= 0)[:, None], fmt)
+                    pos_b[:, None], (pos_b >= 0)[:, None], fmt, layer)
                 new_cache = {"ckv": pool, "ckv_scale": scales}
                 buf = paged_gather_quant(pool, scales, pages, fmt,
-                                         entry.dtype)
+                                         entry.dtype, layer)
         else:
             buf = cache["ckv"]
             inb = (pos_b >= 0) & (pos_b < buf.shape[1])
@@ -473,7 +489,7 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
             # residency, ANDed in (all-True on any legal dispatch —
             # see common.page_resident_rows): rows under a host-parked
             # page never reach the softmax.
-            mask = mask & page_resident_rows(pages, cache["ckv"].shape[1])
+            mask = mask & page_resident_rows(pages, cache["ckv"].shape[2])
         sc = jnp.where(mask[:, None, None, :], sc, -1e30)
         pr = jax.nn.softmax(sc, axis=-1)
         ctx_c = jnp.einsum("bqhs,bsr->bqhr", pr.astype(x.dtype), c_all,

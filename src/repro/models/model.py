@@ -3,8 +3,10 @@
 The block program (ArchConfig.pattern) is interpreted into lax.scan stages
 with stacked parameters, so compile time scales with the number of *distinct*
 block kinds, not the number of layers — mandatory for dry-running 34B/60L
-models on a 512-device host platform.  Caches thread through the scans as
-xs/ys.  One forward covers the four lowered entry points:
+models on a 512-device host platform.  Paged pools ride through the scans
+as carry and are written in place; every other cache threads through them
+as xs/ys (:func:`_apply_stage`).  One forward covers the four lowered
+entry points:
 
   mode='train'    — no cache, remat per scan body
   mode='prefill'  — emits a cache sized ``capacity``
@@ -27,15 +29,16 @@ it with a global (num_pages, page_size, ...) pool per attention/MLA layer
 plus a per-slot page table ``pages`` (B, P) passed to ``forward`` — logical
 cache row ``t`` of slot ``b`` lives at physical row ``pages[b, t //
 page_size] * page_size + t % page_size``.  The table is shared by every
-layer (each layer owns its own pool array), chunk/decode writes scatter
-through it, and decode gathers the slot's logical window back before
-attention, so paging changes storage addressing only — the math (and its
-outputs) is bit-identical to the contiguous layout.  Under a
-seq-sharding rule table the pool is additionally STRIPED page-aligned
-over the seq mesh axes (logical axis 'pages'): each shard scatters and
-gathers only the pages it physically holds and paged decode/resume
-combine per-logical-page flash partials across shards with pmax/psum —
-bit-identical at any shard count (models/attention.py docstring).
+layer (each layer owns its own slice of the stacked (L, num_pages, ...)
+pool), chunk/decode writes scatter through it, and decode gathers the
+slot's logical window back before attention, so paging changes storage
+addressing only — the math (and its outputs) is bit-identical to the
+contiguous layout.  Under a seq-sharding rule table the pool is
+additionally STRIPED page-aligned over the seq mesh axes (logical axis
+'pages'): each shard scatters and gathers only the pages it physically
+holds and paged decode/resume combine per-logical-page flash partials
+across shards with pmax/psum — bit-identical at any shard count
+(models/attention.py docstring).
 Recurrent families (SSM/xLSTM) keep fixed-size per-slot state and
 bypass paging.
 """
@@ -148,48 +151,52 @@ def _remat(fn, cfg, mode):
     return jax.checkpoint(fn)
 
 
-def _apply_scan_stage(kind, count, stage_p, x, cfg, stage_c, mode, pos,
-                      pages, offset, shared):
-    block = BLOCKS[kind]
-    if kind == "shared_attn":
-        stage_p = None   # body uses `shared`
+def _apply_stage(kinds, count, stage_p, x, cfg, stage_c, mode, pos, pages,
+                 offset, shared):
+    """One lax.scan over ``count`` repeats of the blocks ``kinds``, whose
+    stacked params and caches are keyed ``b{j}``.
+
+    Paged pools (blocks declaring ``paged_cache_spec``, with a page
+    table) ride in the CARRY as whole stacked (count, num_pages, ...)
+    leaves, and block ``j`` of repeat ``i`` writes and reads its rows
+    through layer index ``i``: the scatter updates the carried buffer in
+    place, so a donated pool comes back aliased with no pool-sized copy.
+    Through ``xs``/``ys`` the scan would slice out each layer's pool,
+    rewrite the whole slice to add a few rows, stack the slices into a
+    fresh buffer, and copy that back.  Everything else (recurrent state,
+    contiguous caches) is per-slot and small, and stays on ``xs``/``ys``.
+    """
+    pooled = {f"b{j}" for j, kind in enumerate(kinds)
+              if pages is not None and stage_c is not None
+              and BLOCKS[kind].paged_cache_spec is not None
+              and f"b{j}" in stage_c}
+    pools = {k: stage_c[k] for k in pooled}
+    rest = None if stage_c is None else \
+        {k: c for k, c in stage_c.items() if k not in pooled}
 
     def body(carry, xs):
-        h, aux = carry
-        p_i, c_i = xs
-        if kind == "shared_attn":
-            p_i = shared
-        h, c_new, a = block.apply(p_i, h, cfg, c_i, mode, pos, pages,
-                                  offset)
-        return (h, aux + a), c_new
-
-    (x, aux), c_out = jax.lax.scan(
-        _remat(body, cfg, mode), (x, jnp.float32(0)), (stage_p, stage_c),
-        length=count)
-    return x, c_out, aux
-
-
-def _apply_group_stage(group, stage_p, x, cfg, stage_c, mode, pos, pages,
-                       offset, shared):
-    kinds = _linear_inner(group)
-
-    def body(carry, xs):
-        h, aux = carry
-        p_map, c_map = xs
-        new_c = {}
+        h, aux, pools = carry
+        p_map, c_map, i = xs
+        pools, new_c = dict(pools), {}
         for j, kind in enumerate(kinds):
-            p_j = shared if kind == "shared_attn" else p_map[f"b{j}"]
-            c_j = None if c_map is None else c_map.get(f"b{j}")
-            h, c_new, a = BLOCKS[kind].apply(p_j, h, cfg, c_j, mode, pos,
-                                             pages, offset)
+            key = f"b{j}"
+            p_j = shared if kind == "shared_attn" else p_map[key]
+            if key in pools:
+                h, pools[key], a = BLOCKS[kind].apply(
+                    p_j, h, cfg, pools[key], mode, pos, pages, offset, i)
+            else:
+                c_j = None if c_map is None else c_map.get(key)
+                h, c_new, a = BLOCKS[kind].apply(
+                    p_j, h, cfg, c_j, mode, pos, pages, offset, None)
+                if c_new is not None:
+                    new_c[key] = c_new
             aux = aux + a
-            if c_new is not None:
-                new_c[f"b{j}"] = c_new
-        return (h, aux), new_c
+        return (h, aux, pools), new_c
 
-    (x, aux), c_out = jax.lax.scan(
-        _remat(body, cfg, mode), (x, jnp.float32(0)), (stage_p, stage_c))
-    return x, c_out, aux
+    (x, aux, pools), c_out = jax.lax.scan(
+        _remat(body, cfg, mode), (x, jnp.float32(0), pools),
+        (stage_p, rest, jnp.arange(count, dtype=jnp.int32)))
+    return x, {**c_out, **pools}, aux
 
 
 def forward(params: dict, inputs: jax.Array, cfg: ArchConfig, *,
@@ -221,13 +228,18 @@ def forward(params: dict, inputs: jax.Array, cfg: ArchConfig, *,
         stage_p = params["stages"][i]
         stage_c = None if cache is None else cache[i]
         if entry[0] == "scan":
-            x, c2, aux = _apply_scan_stage(
-                entry[1], entry[2], stage_p, x, cfg, stage_c, mode, pos,
+            # one block kind: the same scan, its trees keyed b0.
+            _, kind, count = entry
+            x, c2, aux = _apply_stage(
+                [kind], count, {"b0": stage_p}, x, cfg,
+                None if stage_c is None else {"b0": stage_c}, mode, pos,
                 pages, offset, shared)
+            c2 = c2.get("b0")
         else:
-            x, c2, aux = _apply_group_stage(
-                entry[1], stage_p, x, cfg, stage_c, mode, pos, pages,
-                offset, shared)
+            _, group, count = entry
+            x, c2, aux = _apply_stage(
+                _linear_inner(group), count, stage_p, x, cfg, stage_c, mode,
+                pos, pages, offset, shared)
         new_cache.append(c2)
         aux_total = aux_total + aux
 

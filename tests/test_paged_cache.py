@@ -307,3 +307,184 @@ def test_paged_iotlb_windows_map_exactly_allocated_pages():
     # neighbors' logical windows are not mapped either.
     assert eng.iotlb.translate(eng._slot_span, 1, write=True,
                                strict=False) is None
+
+
+# -- the stacked pool, written in place through the layer scan --------------
+
+def _ref_attn(cfg, a, h, pool, pages, pos, off, fmt):
+    """One layer's GQA sublayer the plain way: scatter into that layer's
+    own (N, ps, ...) pool slice, gather its window, attend.  Returns (y,
+    the layer's new pool)."""
+    from repro.models.attention import (_decode_attention_local,
+                                        _resume_attention_local)
+    from repro.models.common import (dense, page_resident_rows,
+                                     paged_gather, paged_gather_quant,
+                                     paged_scatter, paged_scatter_quant,
+                                     rope)
+    b, s, _ = h.shape
+    nh, kvh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    q = dense(h, a["wq"], None, a.get("bq")).reshape(b, s, nh, dh)
+    k = dense(h, a["wk"], None, a.get("bk")).reshape(b, s, kvh, dh)
+    v = dense(h, a["wv"], None, a.get("bv")).reshape(b, s, kvh, dh)
+    if off is None:                                  # decode
+        t, ok = pos[:, None], pos[:, None] >= 0
+        positions = jnp.maximum(t, 0)
+    else:                                            # resumed chunk
+        t = off[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
+        ok = jnp.arange(s)[None] < pos[:, None]
+        positions = t
+    q, k = rope(q, positions, cfg.rope_theta), rope(k, positions,
+                                                     cfg.rope_theta)
+    new = {}
+    if fmt is None:
+        for name, rows in (("k", k), ("v", v)):
+            new[name] = paged_scatter(pool[name], pages, rows, t, ok)
+        kw, vw = (paged_gather(new[n], pages) for n in ("k", "v"))
+    else:
+        for name, rows in (("k", k), ("v", v)):
+            new[name], new[name + "_scale"] = paged_scatter_quant(
+                pool[name], pool[name + "_scale"], pages, rows, t, ok, fmt)
+        kw, vw = (paged_gather_quant(new[n], new[n + "_scale"], pages, fmt,
+                                     q.dtype) for n in ("k", "v"))
+    kv_ok = page_resident_rows(pages, pool["k"].shape[1])
+    if off is None:
+        o = _decode_attention_local(q, kw, vw, jnp.int32(0), pos + 1, (),
+                                    kv_ok=kv_ok)
+    else:
+        o = _resume_attention_local(q, kw, vw, off, off + pos, kv_ok=kv_ok)
+    return dense(o.reshape(b, s, nh * dh), a["wo"]), new
+
+
+def _ref_mla(cfg, a, h, pool, pages, pos, off, fmt):
+    """One layer's MLA sublayer the plain way (absorbed decode, expanded
+    resume) against that layer's own pool slice."""
+    from repro.models.attention import _resume_attention_local
+    from repro.models.common import (dense, page_resident_rows,
+                                     paged_gather, paged_scatter, rms_norm,
+                                     rope)
+    assert fmt is None
+    b, s, _ = h.shape
+    nh, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    q = dense(h, a["w_q"]).reshape(b, s, nh, dn + dr)
+    if off is None:
+        t, ok = pos[:, None], pos[:, None] >= 0
+        positions = jnp.maximum(t, 0)
+    else:
+        t = off[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
+        ok = jnp.arange(s)[None] < pos[:, None]
+        positions = t
+    q_nope, q_rope = q[..., :dn], rope(q[..., dn:], positions,
+                                       cfg.rope_theta)
+    ckv = dense(h, a["w_dkv"])
+    c_kv = rms_norm(ckv[..., :r], a["kv_norm"])
+    k_rope = rope(ckv[..., r:][:, :, None, :], positions, cfg.rope_theta)
+    entry = jnp.concatenate([c_kv, k_rope[:, :, 0, :]], axis=-1)
+    new = {"ckv": paged_scatter(pool["ckv"], pages, entry, t, ok)}
+    buf = paged_gather(new["ckv"], pages)
+    c_all, kr_all = buf[..., :r], buf[..., r:]
+    kv_ok = page_resident_rows(pages, new["ckv"].shape[1])
+    if off is None:
+        q_c = jnp.einsum("bqhd,rhd->bqhr", q_nope.astype(jnp.float32),
+                         a["w_uk"].reshape(r, nh, dn).astype(jnp.float32))
+        sc = jnp.einsum("bqhr,bsr->bqhs", q_c.astype(h.dtype), c_all,
+                        preferred_element_type=jnp.float32)
+        sc += jnp.einsum("bqhd,bsd->bqhs", q_rope, kr_all,
+                         preferred_element_type=jnp.float32)
+        sc = sc * ((dn + dr) ** -0.5)
+        kpos = jnp.arange(buf.shape[1], dtype=jnp.int32)
+        mask = (kpos[None, :] <= pos[:, None]) & kv_ok
+        sc = jnp.where(mask[:, None, None, :], sc, -1e30)
+        ctx = jnp.einsum("bqhs,bsr->bqhr",
+                         jax.nn.softmax(sc, axis=-1).astype(h.dtype), c_all,
+                         preferred_element_type=jnp.float32)
+        o = jnp.einsum("bqhr,rhv->bqhv", ctx, a["w_uv"].reshape(
+            r, nh, dv).astype(jnp.float32)).astype(h.dtype)
+    else:
+        w = buf.shape[1]
+        k_full = jnp.concatenate(
+            [dense(c_all, a["w_uk"]).reshape(b, w, nh, dn),
+             jnp.broadcast_to(kr_all[:, :, None, :], (b, w, nh, dr))],
+            axis=-1)
+        v_w = dense(c_all, a["w_uv"]).reshape(b, w, nh, dv)
+        o = _resume_attention_local(jnp.concatenate([q_nope, q_rope], -1),
+                                    k_full, v_w, off, off + pos,
+                                    kv_ok=kv_ok)
+    return dense(o.reshape(b, s, nh * dv), a["w_o"]), new
+
+
+def _ref_forward(cfg, params, toks, pools, pages, pos, off, fmt):
+    """The model step layer by layer: a scan that hands each layer its
+    own pool slice and stacks the slices it returns."""
+    from repro.models.blocks import apply_mlp, apply_norm
+    from repro.models.common import dense, embed_lookup
+    attn = _ref_mla if cfg.kv_lora_rank else _ref_attn
+
+    def layer(x, xs):
+        p, pool = xs
+        y, pool = attn(cfg, p["attn"], apply_norm(p["ln1"], x, cfg), pool,
+                       pages, pos, off, fmt)
+        x = x + y
+        return x + apply_mlp(p["ffn"], apply_norm(p["ln2"], x, cfg),
+                             cfg), pool
+
+    x, pools = jax.lax.scan(layer, embed_lookup(params["embed"], toks),
+                            (params["stages"][0], pools))
+    x = apply_norm(params["final_norm"], x, cfg)
+    logits = dense(x, params["lm_head"])
+    real = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
+    return jnp.where(real, logits, jnp.asarray(-1e30, logits.dtype)), pools
+
+
+@pytest.mark.parametrize("step", ["decode", "resume"])
+@pytest.mark.parametrize("cfg,kv_format", [(GQA, "fp"), (GQA, "int8"),
+                                           (MLA, "fp")],
+                         ids=["gqa-fp", "gqa-int8", "mla-fp"])
+def test_stacked_pool_step_bit_exact_per_layer(cfg, kv_format, step):
+    """One decode step or resumed chunk through ``forward``, which carries
+    each stacked (L, N, ps, ...) pool leaf through the layer scan and
+    writes it in place, returns pools and logits bitwise equal to a
+    layer-by-layer reference that scatters into and gathers from each
+    layer's own pool slice.  Slot 1's write lands under an unmapped (-1)
+    page-table entry and must be dropped; slot 2 is inactive."""
+    from repro.core.pageformat import get_format
+    b, ps, n_pages = 3, 4, 12
+    fmt = None if kv_format == "fp" else get_format(kv_format)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    pools = init_paged_cache(cfg, b, n_pages, ps, kv_format=kv_format)[0]
+    # a pool full of earlier rows, so every read and every kept row counts
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), len(pools)))
+    pools = {n: (jax.random.randint(next(keys), l.shape, -100, 100,
+                                    jnp.int32).astype(l.dtype)
+                 if l.dtype == jnp.int8 else
+                 jax.random.uniform(next(keys), l.shape, l.dtype, 0.5, 1.5)
+                 if n.endswith("_scale") else
+                 jax.random.normal(next(keys), l.shape, l.dtype))
+             for n, l in pools.items()}
+    pages = jnp.asarray([[3, 7, 1, 10], [5, -1, 8, 0], [2, 4, 6, 9]],
+                        jnp.int32)
+    if step == "decode":
+        pos, off, s = jnp.asarray([6, 5, -1], jnp.int32), None, 1
+    else:
+        pos, s = jnp.asarray([3, 4, 0], jnp.int32), 4   # chunk lengths
+        off = jnp.asarray([4, 4, 0], jnp.int32)
+    toks = jax.random.randint(jax.random.PRNGKey(2), (b, s), 0,
+                              cfg.vocab_size)
+    mode = "decode" if step == "decode" else "chunk"
+    want_logits, want = jax.jit(
+        lambda c: _ref_forward(cfg, params, toks, c, pages, pos, off,
+                               fmt))(pools)
+    # donated, as the engine jits its steps
+    logits, cache, _ = jax.jit(
+        lambda c: forward(params, toks, cfg, cache=c, mode=mode, pos=pos,
+                          pages=pages, offset=off), donate_argnums=0)(
+        [jax.tree.map(jnp.copy, pools)])
+    assert set(cache[0]) == set(want)
+    for name in want:
+        np.testing.assert_array_equal(np.asarray(cache[0][name]),
+                                      np.asarray(want[name]), err_msg=name)
+        # slot 1's write under the unmapped entry left layer pages alone
+        np.testing.assert_array_equal(np.asarray(cache[0][name])[:, 0],
+                                      np.asarray(pools[name])[:, 0])
+    np.testing.assert_array_equal(np.asarray(logits),
+                                  np.asarray(want_logits))

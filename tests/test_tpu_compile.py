@@ -4,7 +4,9 @@ The TPU compiler is installed beside the CPU backend, so a described
 ``v5e:2x2`` topology lets the chip's own compiler see the main serving
 path at its real widths: the fused paged-decode kernel (qwen2.5-3b GQA
 and deepseek-v2-lite MLA pools), the weight-only w4 matmul kernel, and
-the whole 36-layer qwen2.5-3b paged decode step from abstract shapes.
+the whole 36-layer qwen2.5-3b paged decode step from abstract shapes, and
+that its decode and resumed-prefill steps write a donated paged pool in
+place (no pool-sized copy, update or allocation).
 Mosaic refuses here what the Pallas interpreter accepts (block shapes not
 tiled by (8, 128), dots it cannot lower, too much VMEM), so these tests
 catch a kernel that would fail on the chip at no chip time.
@@ -13,7 +15,10 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and the test workers all
 import this file.
 """
+import contextlib
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +32,8 @@ from repro.kernels import paged_flash_decode as K
 from repro.kernels.mpq_matmul import wo_matmul_kernel
 from repro.models import abstract_params
 from repro.models.model import abstract_paged_cache
-from repro.train.step import make_paged_decode_step
+from repro.train.step import (make_paged_chunked_prefill_step,
+                              make_paged_decode_step)
 
 # serving shapes: 8 slots, 64 logical pages of 16 rows (1024-row window),
 # a 1024-page pool.
@@ -156,3 +162,61 @@ def test_qwen_paged_decode_step_compiles(topo, one_chip, kernel):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES, mem
+
+
+# one HLO instruction: name = dtype[dims]{layout} opcode(
+_HLO_OP = re.compile(r"%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+def _ops_of_size(hlo: str, n_elements: int):
+    """(opcode, line) of every instruction of ``hlo`` whose array result
+    holds ``n_elements`` elements, in any shape; a custom call is named
+    by its target."""
+    ops = []
+    for line in hlo.splitlines():
+        m = _HLO_OP.search(line)
+        if not m or math.prod(
+                int(d) for d in m.group(2).split(",") if d) != n_elements:
+            continue
+        op = m.group(3)
+        target = re.search(r'custom_call_target="([^"]+)"', line)
+        ops.append((target.group(1) if target else op, line.strip()))
+    return ops
+
+
+@pytest.mark.parametrize("program", ["decode", "resumed_prefill"])
+@pytest.mark.parametrize("striped", [False, True],
+                         ids=["replicated", "fsdp_sp"])
+def test_qwen_paged_pool_written_in_place(topo, one_chip, program, striped):
+    """The layer scan carries the stacked paged pool and each layer
+    scatters only its new rows into it, so with the cache donated (as
+    the engine jits its steps) the full-width qwen2.5-3b decode and
+    resumed-prefill steps hold no copy, dynamic-update-slice or
+    allocated buffer the size of a pool leaf, and decode needs
+    (almost) no temporaries beside it."""
+    cfg = get_config("qwen2.5-3b")
+    params = _shapes(abstract_params(cfg), one_chip)
+    cache = _shapes(abstract_paged_cache(cfg, B, N, PS), one_chip)
+    i32 = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
+        s, jnp.int32, sharding=one_chip)
+    if program == "decode":
+        step = make_paged_decode_step(cfg)
+        args = (params, cache, i32(B, 1), i32(B), i32(B, P))
+    else:
+        step = make_paged_chunked_prefill_step(cfg)
+        args = (params, cache, i32(B, 128), i32(B), i32(B, P), i32(B))
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    with use_rules(mesh, "fsdp_sp") if striped else contextlib.nullcontext():
+        compiled = jax.jit(step, donate_argnums=1).lower(*args).compile()
+    leaf = jax.tree.leaves(cache)[0]
+    ops = _ops_of_size(compiled.as_text(), math.prod(leaf.shape))
+    assert any(op == "parameter" for op, _ in ops), \
+        "the pool leaves should be found among the parameters"
+    bad = [line for op, line in ops
+           if op in ("copy", "dynamic-update-slice", "AllocateBuffer")]
+    assert not bad, bad
+    if program == "decode":
+        pool_bytes = sum(x.size * x.dtype.itemsize
+                         for x in jax.tree.leaves(cache))
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 0.05 * pool_bytes, (temp, pool_bytes)
