@@ -31,7 +31,6 @@ import registry  # noqa: E402
 
 
 def readings(cell_name: str, seed: int, seconds: float, bench=None) -> dict:
-    import refmodel
     import run
     import serveloop
     import weightgen
@@ -40,8 +39,9 @@ def readings(cell_name: str, seed: int, seconds: float, bench=None) -> dict:
     cell = bench.cell(cell_name)
     conf, mix = bench.config(cell["config"]), bench.traffic(cell["traffic"])
     m = conf["model"]
-    arch, sc = run.build(conf, mix)
-    params = weightgen.draw(m, seed)
+    code = bench.code(cell["config"])
+    arch, sc = run.build(code, conf, mix)
+    params = weightgen.draw(code.leaf_table(m), seed)
     eng = ServingEngine(arch, params, sc)
     eng.warmup()
     loop = serveloop.drive(eng, loadgen.schedule(mix, seed, seconds,
@@ -51,8 +51,8 @@ def readings(cell_name: str, seed: int, seconds: float, bench=None) -> dict:
     out = {"seed": seed, "program": 0.0, "control": 0.0, "tokens": 0}
     for r in run.sample(loop, mix, seed):
         for key, ctl in (("program", False), ("control", True)):
-            g = refmodel.served_gaps(params, m, r.prompt, r.tokens,
-                                     sc.slot_rows, control=ctl)
+            g = code.served_gaps(params, m, r.prompt, r.tokens,
+                                 sc.slot_rows, control=ctl)
             out[key] = max(out[key], float(g.max()))
         out["tokens"] += len(r.tokens)
     return out

@@ -39,7 +39,8 @@ def main(cell_name: str) -> int:
     jax.config.update("jax_enable_compilation_cache", False)
     bench = registry.Bench()
     cell = bench.cell(cell_name)
-    arch, sc = build(bench.config(cell["config"]), bench.traffic(cell["traffic"]))
+    arch, sc = build(bench.code(cell["config"]), bench.config(cell["config"]),
+                     bench.traffic(cell["traffic"]))
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])

@@ -2,9 +2,10 @@
 
 Each decode step's least time is the larger of its FLOPs over the bf16
 peak and its least bytes (the weights once, the KV rows its requests
-attend) over HBM bandwidth (``counts.decode_step``, ``peaks``).  The mean
-least time of the host-recorded decode ticks inside the traced window,
-times the decode dispatches the trace counts there, over the window."""
+attend) over HBM bandwidth (the model code's ``decode_step``, ``peaks``).
+The mean least time of the host-recorded decode ticks inside the traced
+window, times the decode dispatches the trace counts there, over the
+window."""
 import counts
 from peaks import peaks_for
 from readings import ticks_between
@@ -19,7 +20,7 @@ def read(run):
     if not n or not steps:
         return None
     pk = peaks_for(run.device_kind)
-    least = [counts.roofline_s(*counts.decode_step(run.model, t.decode_rows,
-                                                   t.decode_ctx), pk)
-             for t in steps]
+    step = run.code.decode_step
+    least = [counts.roofline_s(*step(run.model, t.decode_rows, t.decode_ctx),
+                               pk) for t in steps]
     return 100.0 * n * (sum(least) / len(least)) / run.trace.window_s

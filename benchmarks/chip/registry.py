@@ -7,9 +7,23 @@
     checks/<cell>.json        the limits that decide ``correct``
     metrics/<metric>.py       one reader per metric: ``read(run)`` returns a
                               number, or None where it finds nothing to read
+    code/<code>.py            everything that depends on the model's
+                              architecture, named by a configuration's
+                              ``"code"`` key:
+        arch(conf)            the program's ArchConfig, checked against the
+                              file's ``model`` block
+        leaf_table(m)         weight name -> (shape, dtype, kind, scale) in
+                              the engine's layout (``weightgen``)
+        served_gaps(params, m, prompt, served, length, control=False)
+                              the float32 reference's gap at each served
+                              token, or at the control's pick
+        decode_step(m, rows, ctx_rows)
+                              (FLOPs, least bytes) of one decode step
 
-Adding a configuration, a mix or a metric is adding files here and
-entries in ``BENCHMARK.json``; no file that exists needs an edit.
+Adding a configuration, a mix, a metric or a model's code is adding files
+here and entries in ``BENCHMARK.json``; no file that exists needs an edit.
+Outside ``code/`` no file reads a key of the ``model`` block but
+``vocab_size``, which the traffic generator needs.
 """
 from __future__ import annotations
 
@@ -25,6 +39,7 @@ class Bench:
     def __init__(self):
         self.root, self.here = ROOT, HERE
         self.spec = json.loads((self.root / "BENCHMARK.json").read_text())
+        self.loaded = {}        # path -> module: each file is loaded once
 
     def cell(self, name: str) -> dict:
         for w in self.spec["workloads"]:
@@ -59,10 +74,20 @@ class Bench:
                 if (cell in m["workloads"] if "workloads" in m
                     else m["moves"] in mine)]
 
+    def _load(self, path: pathlib.Path, prefix: str):
+        if path not in self.loaded:
+            spec = importlib.util.spec_from_file_location(
+                prefix + path.stem.replace(".", "_").replace("-", "_"), path)
+            mod = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(mod)
+            self.loaded[path] = mod
+        return self.loaded[path]
+
     def reader(self, metric: str):
-        path = self.here / "metrics" / f"{metric}.py"
-        spec = importlib.util.spec_from_file_location(
-            "metric_" + metric.replace(".", "_").replace("-", "_"), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return self._load(self.here / "metrics" / f"{metric}.py",
+                          "metric_").read
+
+    def code(self, config: str):
+        """The model code a configuration names (``code/<code>.py``)."""
+        name = self.config(config)["code"]
+        return self._load(self.here / "code" / f"{name}.py", "code_")

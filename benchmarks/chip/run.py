@@ -18,10 +18,11 @@ Nothing should compile in the window; the count is printed.  With
 per-layer metrics reported instead of the end-to-end ones.
 
 Once the loop has ended and the peak memory is read, the engine is freed
-and a plain float32 reference (``refmodel``) checks a sample of the
-served requests drawn from the seed.  The last stdout line is the result
-JSON; the numbers compared, each beside its limit, are the last stderr
-lines and the result's last key.  Without a TPU, or with fewer chips than
+and the plain float32 reference of the configuration's model code
+(``code/<code>.py``) checks a sample of the served requests drawn from
+the seed.  The last stdout line is the result JSON; the numbers
+compared, each beside its limit, are the last stderr lines and the
+result's last key.  Without a TPU, or with fewer chips than
 the cell asks for, the run exits non-zero and prints no result.
 """
 from __future__ import annotations
@@ -50,13 +51,6 @@ import registry  # noqa: E402
 
 TRACE_SECONDS = 10.0
 TRACE_DIR = HERE / "runs" / "trace"
-# model-block keys -> ArchConfig fields; the program's config must agree.
-ARCH_KEYS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
-             "num_attention_heads": "n_heads",
-             "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
-             "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-             "rope_theta": "rope_theta", "qk_norm": "qk_norm",
-             "attention_bias": "qkv_bias"}
 
 
 def log(msg: str) -> None:
@@ -94,30 +88,15 @@ class Run:
     serve: object                   # the ServeConfig
     num_pages: int
     model: dict                     # the configuration's model block
+    code: object                    # its model code (code/<code>.py)
     device_kind: str
     trace: object = None            # trace_reduce.Trace
 
 
-def build(conf: dict, mix: dict):
+def build(code, conf: dict, mix: dict):
     """(ArchConfig, ServeConfig) of a configuration under a mix."""
-    from repro.configs import get_config
     from repro.serve import ServeConfig
-    # an empty pattern is derived again from the (overridden) layer count
-    arch = get_config(conf["arch"]).with_(pattern=(), **conf["overrides"])
-    m = conf["model"]
-    if arch.n_blocks() != m["num_hidden_layers"]:
-        raise ValueError(f"{conf['arch']}: program runs {arch.n_blocks()} "
-                         f"layers, config {m['num_hidden_layers']}")
-    for key, field in ARCH_KEYS.items():
-        if getattr(arch, field) != m[key]:
-            raise ValueError(f"{conf['arch']}: program has {field}="
-                             f"{getattr(arch, field)}, config {key}={m[key]}")
-    if m["tie_word_embeddings"] or m["hidden_act"] != "silu" or \
-            arch.mlp_act != "silu_glu" or arch.norm != "rms" or \
-            arch.family != "dense":
-        raise ValueError(f"{conf['arch']}: not a dense SwiGLU/RMSNorm model "
-                         "with an untied head, which is all the reference "
-                         "implements")
+    arch = code.arch(conf)
     out = int(mix["output_tokens"])
     sc = ServeConfig(**conf["serve"], max_new_tokens=out,
                      max_seq=loadgen.max_prompt_tokens(mix) + out)
@@ -139,18 +118,17 @@ def sample(loop, mix: dict, seed: int):
                                                          replace=False))]
 
 
-def verify(params, m: dict, loop, mix: dict, limits: dict, seed: int,
+def verify(code, params, m: dict, loop, mix: dict, limits: dict, seed: int,
            length: int) -> dict:
     """The numbers that decide ``correct``, each {"value", "limit"}.  A
     request that failed or never finished (``tally``) fails the run: its
     latency would otherwise count only until the run gave up on it."""
-    import refmodel
     out = int(mix["output_tokens"])
     finished = [r for r in loop.requests if r.done]
     chosen = sample(loop, mix, seed)
     worst = 0.0
     for r in chosen:
-        g = refmodel.served_gaps(params, m, r.prompt, r.tokens, length)
+        g = code.served_gaps(params, m, r.prompt, r.tokens, length)
         worst = max(worst, float(g.max()))
     if not math.isfinite(worst):
         worst = 1e30            # a served id outside the vocabulary
@@ -230,11 +208,12 @@ def main(argv=None) -> int:
     mix = bench.traffic(cell["traffic"])
     limits = bench.checks(cell["name"])
     m = conf["model"]
-    arch, sc = build(conf, mix)
+    code = bench.code(cell["config"])
+    arch, sc = build(code, conf, mix)
     sched = loadgen.schedule(mix, args.seed, args.seconds, m["vocab_size"])
 
     t = time.perf_counter()
-    params = weightgen.draw(m, args.seed)
+    params = weightgen.draw(code.leaf_table(m), args.seed)
     weightgen.check_layout(params, abstract_params(arch))
     t_weights = time.perf_counter() - t
     t = time.perf_counter()
@@ -260,13 +239,15 @@ def main(argv=None) -> int:
     gc.collect()
 
     t = time.perf_counter()
-    checks = verify(params, m, loop, mix, limits, args.seed, sc.slot_rows)
+    checks = verify(code, params, m, loop, mix, limits, args.seed,
+                    sc.slot_rows)
     t_ref = time.perf_counter() - t
     trace = None
     if args.trace:
         trace = trace_reduce.reduce(trace_reduce.find_xplane(trace_dir))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
-    run = Run(loop, setup_s, sc, num_pages, m, devs[0].device_kind, trace)
+    run = Run(loop, setup_s, sc, num_pages, m, code, devs[0].device_kind,
+              trace)
 
     metrics = {}
     for md in bench.metrics_for(cell["name"], bool(args.trace)):

@@ -51,8 +51,9 @@ def main(argv=None) -> int:
     from repro.serve import ServingEngine
     run.enable_cache()
     conf, mix = bench.config(cell["config"]), bench.traffic(cell["traffic"])
-    arch, sc = run.build(conf, mix)
-    params = weightgen.draw(conf["model"], args.seed)
+    code = bench.code(cell["config"])
+    arch, sc = run.build(code, conf, mix)
+    params = weightgen.draw(code.leaf_table(conf["model"]), args.seed)
     for rate in args.rates:
         eng = ServingEngine(arch, params, sc)     # a fresh, empty engine
         eng.warmup()

@@ -1,8 +1,10 @@
 """BENCHMARK.json against the contract it is written to, and the pieces
 the harness finds by name: configurations, mixes, limits, readers,
-the traffic generator, the weight layout and the operation counts."""
+the traffic generator, and each configuration's model code: the weight
+layout, the reference and the operation counts."""
 from __future__ import annotations
 
+import hashlib
 import json
 import math
 import re
@@ -195,33 +197,51 @@ def test_mix_the_generator_cannot_run_is_refused(bad):
         loadgen.check(dict(MIX, **bad))
 
 
-def test_weight_layout_matches_the_program():
+@pytest.fixture(scope="module")
+def checkout(tmp_path_factory):
+    return fx.make_checkout(tmp_path_factory.mktemp("checkout"))
+
+
+@pytest.fixture
+def fixture_bench(checkout, monkeypatch):
+    """The registry of the checkout: the real entries and the fixture's."""
+    monkeypatch.setattr(registry, "ROOT", checkout)
+    monkeypatch.setattr(registry, "HERE", checkout / "benchmarks" / "chip")
+    return registry.Bench()
+
+
+def test_weight_layout_matches_the_program(fixture_bench):
+    """Every configuration, the fixtures' included, through its own model
+    code: the drawn tree has the program's structure and shapes."""
+    import jax
     from repro.models import abstract_params
     import run
-    for c in SPEC["configs"]:
-        conf = json.loads((fx.REPO / c["file"]).read_text())
-        arch, _ = run.build(conf, {"output_tokens": 8, "prompt_tokens":
-                                   {"max": 64}})
+    names = [c["name"] for c in fixture_bench.spec["configs"]]
+    assert {"tiny-qwen3", "tiny-mla"} < set(names)
+    for name in names:
+        code = fixture_bench.code(name)
+        conf = fixture_bench.config(name)
+        arch, _ = run.build(code, conf, {"output_tokens": 8, "prompt_tokens":
+                                         {"max": 64}})
         want = abstract_params(arch)
-        got = weightgen._nest({k: np.zeros(0) for k in
-                               weightgen.leaf_table(conf["model"])})
-        import jax
-        assert jax.tree.structure(got) == jax.tree.structure(want)
-        shapes = {k: v[0] for k, v in weightgen.leaf_table(conf["model"]).items()}
-        got = weightgen._nest(shapes)
+        table = code.leaf_table(conf["model"])
+        got = weightgen._nest({k: np.zeros(0) for k in table})
+        assert jax.tree.structure(got) == jax.tree.structure(want), name
+        got = weightgen._nest({k: v[0] for k, v in table.items()})
         assert jax.tree.leaves(got, is_leaf=lambda x: isinstance(x, tuple)) \
-            == [tuple(x.shape) for x in jax.tree.leaves(want)]
+            == [tuple(x.shape) for x in jax.tree.leaves(want)], name
 
 
 def test_counts_for_qwen2_5_3b_match_a_hand_count():
+    code = registry.Bench().code("qwen2.5-3b")
     m = json.loads((fx.CHIP / "configs" / "qwen2.5-3b.json").read_text())["model"]
     # per layer: q 2048x2048, k and v 2048x256 each, o 2048x2048,
     # gate/up/down 3 x 2048x11008
     per_layer = 2048 * 2048 * 2 + 2048 * 256 * 2 + 3 * 2048 * 11008
-    assert counts.layer_params(m) == per_layer == 77_070_336
+    assert code.layer_params(m) == per_layer == 77_070_336
     head = 2048 * 151936
     # one decode step of 16 rows attending 16 x 1000 positions
-    flops, nbytes = counts.decode_step(m, 16, 16_000)
+    flops, nbytes = code.decode_step(m, 16, 16_000)
     assert flops == 2 * 16 * (36 * per_layer + head) + 4 * 16 * 128 * 36 * 16_000
     small = 2 * 2048 + (16 + 2 + 2) * 128           # norms, QKV biases
     kv_row = 36 * 2 * 2 * 128 * 2                  # 36,864 bytes
@@ -230,3 +250,122 @@ def test_counts_for_qwen2_5_3b_match_a_hand_count():
     assert math.isclose(weights / 1e9, 6.1719, rel_tol=1e-4)
     peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
     assert counts.roofline_s(flops, nbytes, peaks) == nbytes / 819e9
+
+
+def test_counts_for_mla_match_a_hand_count(fixture_bench):
+    """The fixture's MLA counts at DeepSeek-V2-Lite's dense-layer widths:
+    one layer, 16 heads, latent 512, nope/rope/v 128/64/128."""
+    code = fixture_bench.code("tiny-mla")
+    m = {"hidden_size": 2048, "intermediate_size": 10944,
+         "num_attention_heads": 16, "kv_lora_rank": 512,
+         "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+         "num_hidden_layers": 1, "vocab_size": 102400}
+    # w_q 2048 x 16*192, w_dkv 2048 x 576, w_uk and w_uv 512 x 16*128
+    # each, w_o 16*128 x 2048, gate/up/down 3 x 2048 x 10944
+    per_layer = 2048 * 3072 + 2048 * 576 + 2 * 512 * 2048 + 2048 * 2048 \
+        + 3 * 2048 * 10944
+    assert code.layer_params(m) == per_layer == 81_002_496
+    head = 2048 * 102400
+    flops, nbytes = code.decode_step(m, 4, 4_000)
+    # scores against 576 latent+rope columns, values in 512, per head
+    assert flops == 2 * 4 * (per_layer + head) + 2 * 16 * 1088 * 4_000
+    weights = (per_layer + 2 * 2048 + 512 + 2048 + head) * 2
+    assert nbytes == weights + 576 * 2 * (4_000 + 4) + 4 * 2048 * 2
+
+
+# Recorded from the tree before the model code moved into ``code/``: the
+# digest of the weights ``weightgen`` drew for tiny-qwen3, and the float32
+# gaps (program, fp8 control) the reference read over one fixed request.
+# The weights are integer draws and elementwise arithmetic: the same bits
+# on any host.  The moved reference gave the recorded gaps to the bit on
+# the host they were recorded on; XLA's CPU dots sum in an order that
+# depends on the host (1.8e-7 between two instruction-set caps), so they
+# are held to 1e-5, far below what a change of the reference's
+# mathematics moves (bf16 inputs to its projections: 1.1e-3).
+PROMPT = [3, 141, 59, 265, 358, 97, 93, 238, 462, 64, 338, 327, 95, 28]
+SERVED = [84, 197, 169, 399, 375, 105, 320, 97, 494, 459, 230]
+DENSE_RECORD = {
+    2**31 + 11: (
+        "2f62378d70dc11b7bf7290f85143ad5af19a8d264deb72c038af93446f88e619",
+        [0.8866314888000488, 0.5996506810188293, 0.2758978009223938,
+         0.6608873605728149, 0.6428309082984924, 0.6157194375991821,
+         0.448322057723999, 0.5733518600463867, 0.7107202410697937,
+         0.5665387511253357, 0.5221256613731384],
+        [0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0]),
+    7: (
+        "6b53abfd37abc41b438ee4f772b6df16a33d8950f4b1c86e2e02d2913808e161",
+        [0.35070866346359253, 0.37547537684440613, 0.421529084444046,
+         0.5926416516304016, 0.5397074818611145, 0.4140439033508301,
+         0.6405903697013855, 0.5941125750541687, 0.5300873517990112,
+         0.42788267135620117, 0.221110999584198],
+        [0.0, 0.0, 0.0, 0.0, 0.0, 0.019796103239059448, 0.0, 0.0, 0.0,
+         0.0, 0.0]),
+}
+
+
+def _digest(arrays) -> str:
+    h = hashlib.sha256()
+    for a in arrays:
+        a = np.asarray(a)
+        h.update(f"{a.dtype}{a.shape}".encode())
+        h.update(a.tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("seed", sorted(DENSE_RECORD))
+def test_dense_weights_and_reference_keep_their_numbers(fixture_bench, seed):
+    import jax
+    code = fixture_bench.code("tiny-qwen3")
+    m = fixture_bench.config("tiny-qwen3")["model"]
+    params = weightgen.draw(code.leaf_table(m), seed)
+    weights, gaps, control = DENSE_RECORD[seed]
+    assert _digest(jax.tree.leaves(params)) == weights
+    got = code.served_gaps(params, m, PROMPT, SERVED, 32)
+    np.testing.assert_allclose(got, np.float32(gaps), rtol=0, atol=1e-5)
+    got = code.served_gaps(params, m, PROMPT, SERVED, 32, control=True)
+    np.testing.assert_allclose(got, np.float32(control), rtol=0, atol=1e-5)
+
+
+# Recorded likewise: the digest of each cell configuration's leaf table
+# (names in order, shapes, dtypes, kinds, scales) and decode_step at
+# three (rows, context rows) points.
+DENSE_TABLES = {
+    "qwen2.5-3b": (
+        "96a2bac5a2624e795edf58345553a562c1d3fa2efc77bf336c27f21cfa4398ac",
+        [(6171688960, 6171955200), (76533989376, 6482026496),
+         (103460896768, 6762356736)]),
+    "qwen3-8b-18L": (
+        "6d4dec082165631661a94d8b83daa91a584e89fc18dc21feaecaa6f37270e4da",
+        [(8190722048, 8190895104), (100762386432, 8811037696),
+         (135765426176, 9371698176)]),
+}
+
+
+@pytest.mark.parametrize("config", sorted(DENSE_TABLES))
+def test_dense_tables_and_counts_keep_their_numbers(config):
+    bench = registry.Bench()
+    code, m = bench.code(config), bench.config(config)["model"]
+    table = code.leaf_table(m)
+    rows = [[k, list(v[0]), v[1], v[2], v[3]] for k, v in table.items()]
+    digest, steps = DENSE_TABLES[config]
+    assert hashlib.sha256(json.dumps(rows).encode()).hexdigest() == digest
+    assert [code.decode_step(m, r, c) for r, c in
+            ((1, 1), (12, 12 * 700), (16, 16_000))] == steps
+
+
+MODEL_KEYS = ("num_hidden_layers", "num_key_value_heads", "head_dim",
+              "intermediate_size", "qk_norm", "attention_bias",
+              "tie_word_embeddings")
+
+
+def test_only_model_code_reads_the_model_block():
+    """Outside ``code/`` no harness file names a ``model``-block key but
+    ``vocab_size``, nor reaches model code but through the registry."""
+    files = [f for f in fx.CHIP.rglob("*.py")
+             if "code" not in f.relative_to(fx.CHIP).parts[:-1]
+             and "tests" not in f.relative_to(fx.CHIP).parts]
+    assert fx.CHIP / "run.py" in files and len(files) > 20
+    for f in files:
+        text = f.read_text()
+        for word in MODEL_KEYS + ("refmodel", "counts.decode_step"):
+            assert word not in text, (f, word)

@@ -1,11 +1,12 @@
 """The harness end to end on the CPU at a tiny size.
 
 The look for a chip is steered here (``run.check_device``), not by a flag
-of the program.  The cell is a fixture added to a copy of the benchmark
-(``chipbench_fixture``).  Besides a sound run, each fault the cells can
-have is planted under the timed path and must turn ``correct`` false: a
-token altered where it is sampled, and a decode step that returns its KV
-state unchanged.  The fp8 control must read above the limit too.
+of the program.  The cells are fixtures added to a copy of the benchmark
+(``chipbench_fixture``), one of them on model code of its own (MLA in
+two stages).  Besides a sound run, each fault the cells can have is
+planted under the timed path and must turn ``correct`` false: a token
+altered where it is sampled, and a decode step that returns its KV state
+unchanged.  The fp8 control must read above the limit too.
 """
 from __future__ import annotations
 
@@ -119,7 +120,7 @@ def test_failed_request_is_a_miss_and_fails_the_run(harness, capsys,
     assert res["failed"] == res["checks"]["failed"]["value"] == 1
 
 
-def test_altered_token_is_caught(harness, capsys, monkeypatch):
+def shift_sampled_tokens(monkeypatch):
     from repro.serve import engine
     orig = engine.ServingEngine._sample
 
@@ -127,7 +128,30 @@ def test_altered_token_is_caught(harness, capsys, monkeypatch):
         return (orig(self, logits) + 1) % self.cfg.vocab_size
 
     monkeypatch.setattr(engine.ServingEngine, "_sample", shifted)
+
+
+def test_altered_token_is_caught(harness, capsys, monkeypatch):
+    shift_sampled_tokens(monkeypatch)
     assert not result(harness, capsys, 7)["correct"]
+
+
+def test_model_code_of_its_own_runs_correct(harness, capsys):
+    """The MLA cell, added with new files alone, serves through two stages
+    and its reference passes the program's tokens."""
+    res = result(harness, capsys, 2**31 + 12, cell=fx.MLA_CELL)
+    assert res["correct"], res["checks"]
+    assert set(res["metrics"]) == {"ttft_p90_ms", "tpot_p90_ms", "setup_s"}
+    assert res["attempted"] >= 4 and res["failed"] == 0
+    assert res["checks"]["max_logit_gap"]["value"] > 0
+
+
+def test_model_code_of_its_own_catches_an_altered_token(harness, capsys,
+                                                         monkeypatch):
+    shift_sampled_tokens(monkeypatch)
+    res = result(harness, capsys, 9, cell=fx.MLA_CELL)
+    assert not res["correct"]
+    assert res["checks"]["max_logit_gap"]["value"] > \
+        res["checks"]["max_logit_gap"]["limit"]
 
 
 def test_unchanged_decode_state_is_caught(harness, capsys, monkeypatch):
@@ -158,6 +182,19 @@ def test_fp8_control_reads_above_the_limit(checkout, monkeypatch):
     limit = bench.checks(fx.CELL)["max_logit_gap"]["limit"]
     for seed in (1, 2, 3):
         r = control.readings(fx.CELL, seed, 1.5, bench)
+        assert r["program"] <= limit < r["control"], r
+
+
+def test_mla_fp8_control_reads_above_the_limit(checkout, monkeypatch):
+    """Likewise for the MLA cell, through its own model code."""
+    import control
+    import registry
+    monkeypatch.setattr(registry, "ROOT", checkout)
+    monkeypatch.setattr(registry, "HERE", checkout / "benchmarks" / "chip")
+    bench = registry.Bench()
+    limit = bench.checks(fx.MLA_CELL)["max_logit_gap"]["limit"]
+    for seed in (13, 14):
+        r = control.readings(fx.MLA_CELL, seed, 1.5, bench)
         assert r["program"] <= limit < r["control"], r
 
 

@@ -2,60 +2,36 @@
 
 The benchmark makes the weights itself, in the layout the serving engine
 reads, so the plain reference can use them without taking anything the
-program made.  One jitted program draws every leaf with the hardware
-generator (``rbg``) in float32 and stores it in the type it is served in;
-the layout is checked against the program's own abstract parameters.
-
-Scales follow the program's initialiser: matmul weights 1/sqrt(fan_in),
-embedding 1, head 0.02.  Norm gains are 1 +- 0.1 and QKV biases +- 0.1,
-so that neither is a no-op the reference could get wrong unseen.
+program made.  The model's code (``code/<code>.py``) gives the layout and
+the scales as a table, ``leaf_table(m)``: name -> (shape, dtype, kind,
+scale).  One jitted program draws every leaf with the hardware generator
+(``rbg``) in float32, leaf i of the sorted names from ``fold_in(key, i)``,
+and stores it in the type it is served in; the layout is checked against
+the program's own abstract parameters.
 """
 from __future__ import annotations
 
 import math
+import re
 
 import numpy as np
 
 SQRT3 = math.sqrt(3.0)
-
-
-def leaf_table(m: dict) -> dict:
-    """name -> (shape, dtype, kind, scale) for the scanned decoder layout
-    of a dense GQA model (``stages[0]`` holds the layers stacked)."""
-    d, f = m["hidden_size"], m["intermediate_size"]
-    h, kv, dh = m["num_attention_heads"], m["num_key_value_heads"], m["head_dim"]
-    L, vp = m["num_hidden_layers"], -(-m["vocab_size"] // 256) * 256
-    t = {
-        "embed": ((vp, d), "bf16", "std", 1.0),
-        "final_norm/w": ((d,), "f32", "gain", 0.1),
-        "lm_head": ((d, vp), "bf16", "std", 0.02),
-        "L/ln1/w": ((L, d), "f32", "gain", 0.1),
-        "L/ln2/w": ((L, d), "f32", "gain", 0.1),
-        "L/attn/wq": ((L, d, h * dh), "bf16", "std", d ** -0.5),
-        "L/attn/wk": ((L, d, kv * dh), "bf16", "std", d ** -0.5),
-        "L/attn/wv": ((L, d, kv * dh), "bf16", "std", d ** -0.5),
-        "L/attn/wo": ((L, h * dh, d), "bf16", "std", (h * dh) ** -0.5),
-        "L/ffn/w_gate": ((L, d, f), "bf16", "std", d ** -0.5),
-        "L/ffn/w_up": ((L, d, f), "bf16", "std", d ** -0.5),
-        "L/ffn/w_down": ((L, f, d), "bf16", "std", f ** -0.5),
-    }
-    if m.get("attention_bias"):
-        t["L/attn/bq"] = ((L, h * dh), "bf16", "bias", 0.1)
-        t["L/attn/bk"] = ((L, kv * dh), "bf16", "bias", 0.1)
-        t["L/attn/bv"] = ((L, kv * dh), "bf16", "bias", 0.1)
-    if m.get("qk_norm"):
-        t["L/attn/q_norm"] = ((L, dh), "f32", "gain", 0.1)
-        t["L/attn/k_norm"] = ((L, dh), "f32", "gain", 0.1)
-    return t
+STAGE = re.compile(r"L(\d*)")
 
 
 def _nest(flat: dict) -> dict:
-    """Flat names -> the engine's tree: ``L/...`` goes to stages[0]."""
-    out: dict = {"stages": [{}]}
+    """Flat names -> the engine's tree: a name whose first part is ``L``
+    goes to stages[0], ``L<k>`` to stages[k]; any other to the top."""
+    out: dict = {"stages": []}
     for name, v in flat.items():
         parts = name.split("/")
-        node = out["stages"][0] if parts[0] == "L" else out
-        parts = parts[1:] if parts[0] == "L" else parts
+        node = out
+        stage = STAGE.fullmatch(parts[0])
+        if stage:
+            k = int(stage.group(1) or 0)
+            out["stages"] += [{} for _ in range(k + 1 - len(out["stages"]))]
+            node, parts = out["stages"][k], parts[1:]
         for p in parts[:-1]:
             node = node.setdefault(p, {})
         node[parts[-1]] = v
@@ -69,11 +45,11 @@ def seed_key(seed: int):
     return jax.random.key(word, impl="rbg")
 
 
-def draw(m: dict, seed: int):
-    """The weight tree, drawn in one jitted program on the default device."""
+def draw(table: dict, seed: int):
+    """The weight tree of ``table`` (a model code's ``leaf_table``), drawn
+    in one jitted program on the default device."""
     import jax
     import jax.numpy as jnp
-    table = leaf_table(m)
     dtypes = {"bf16": jnp.bfloat16, "f32": jnp.float32}
 
     def make(key):
