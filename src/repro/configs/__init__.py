@@ -1,6 +1,7 @@
 """Config registry: ``--arch <id>`` resolution + input shapes + reduction.
 
-ARCHS maps the 10 assigned architecture ids to their exact published
+ARCHS maps the 10 assigned architecture ids, and deepseek-v2-lite (the
+published model, where deepseek-v2-lite-16b keeps plain rope), to their
 configs; SHAPES maps the 4 assigned input shapes; ``reduce_config``
 shrinks any config to a CPU-smoke-testable size *preserving its block
 structure* (same pattern kinds, fewer repeats / smaller dims).
@@ -24,6 +25,7 @@ _MODULES = {
     "zamba2-7b": "zamba2_7b",
     "granite-moe-1b-a400m": "granite_moe_1b",
     "deepseek-v2-lite-16b": "deepseek_v2_lite",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 
@@ -31,7 +33,8 @@ def get_config(name: str) -> ArchConfig:
     if name not in _MODULES:
         raise KeyError(f"unknown arch {name!r}; known: {sorted(_MODULES)}")
     mod = importlib.import_module(f"repro.configs.{_MODULES[name]}")
-    return mod.CONFIG
+    # a module may hold several entries by name (``CONFIGS``)
+    return getattr(mod, "CONFIGS", {}).get(name, mod.CONFIG)
 
 
 def all_archs():
@@ -82,10 +85,15 @@ def reduce_config(cfg: ArchConfig) -> ArchConfig:
         ssm_chunk=8,
     )
     if cfg.n_experts:
-        kw.update(n_experts=min(cfg.n_experts, 8),
-                  top_k=min(cfg.top_k, 2),
+        n = min(cfg.n_experts, 8)
+        lo, hi = cfg.held_experts()
+        kw.update(n_experts=n, top_k=min(cfg.top_k, 2),
                   d_ff_expert=min(cfg.d_ff_expert, 64),
-                  capacity_factor=4.0)
+                  capacity_factor=4.0,
+                  # the same share of the fewer experts, at least one
+                  experts_held=None if cfg.experts_held is None else
+                  (lo * n // cfg.n_experts,
+                   max(hi * n // cfg.n_experts, lo * n // cfg.n_experts + 1)))
     if cfg.kv_lora_rank:
         kw.update(kv_lora_rank=64, qk_nope_dim=32, qk_rope_dim=16,
                   v_head_dim=32)

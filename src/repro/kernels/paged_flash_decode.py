@@ -287,7 +287,8 @@ def paged_flash_decode_partials(k_pool, v_pool, q, tbl, qpos, kv_valid, *,
 # MLA: compressed-space partials against the (N, ps, r+dr) latent pool.
 # ---------------------------------------------------------------------------
 
-def _mla_page_kernel(tbl_ref, pos_ref, pool_ref, *refs, ps, r, scale, bits):
+def _mla_page_kernel(tbl_ref, pos_ref, pool_ref, *refs, ps, r, dr, scale,
+                     bits):
     if bits is None:
         qc_ref, qr_ref, m_ref, l_ref, acc_ref = refs
     else:
@@ -305,7 +306,7 @@ def _mla_page_kernel(tbl_ref, pos_ref, pool_ref, *refs, ps, r, scale, bits):
         blk = pool_ref[0]               # (ps, r+dr) — the mapped page
         if bits is not None:            # one scale spans c_kv and k_rope
             blk = _dequant(blk, s_ref, bits, qc.dtype)
-        c, kr = blk[:, :r], blk[:, r:]
+        c, kr = blk[:, :r], blk[:, r:r + dr]
         nt = (((1,), (1,)), ((), ()))
         sc = jax.lax.dot_general(qc, c, nt,
                                  preferred_element_type=jnp.float32)
@@ -332,7 +333,7 @@ def mla_paged_decode_partials(pool, q_c, q_rope, tbl, pos_b, r, scale_dim, *,
     """Fused compressed-space page partials for MLA absorbed decode.
 
     Replaces the gather + inline partials in ``mla._mla_paged_decode``:
-    ``pool`` (N, ps, r+dr) shard-local latent pool, ``q_c`` (B, Sq, H, r)
+    ``pool`` (N, ps, >= r+dr) shard-local latent pool, ``q_c`` (B, Sq, H, r)
     absorbed queries, ``q_rope`` (B, Sq, H, dr), ``tbl`` (B, P) local
     table, ``pos_b`` (B,) slot positions.  The weighted sum stays in the
     COMPRESSED space — ``acc`` is (B, Sq, H, P, r) — so the caller's
@@ -342,11 +343,9 @@ def mla_paged_decode_partials(pool, q_c, q_rope, tbl, pos_b, r, scale_dim, *,
     QUANTIZED pools: pass ``scale_pool`` ((N, ps) f32) and ``bits``; the
     pool then stores packed int8 rows of width ``(r+dr) * bits // 8``,
     dequantized in VMEM before the split at ``r``."""
-    n, ps, width = pool.shape
-    if bits is not None:
-        width = width * pack_factor(bits)
+    n, ps, _ = pool.shape
     b, sq, h, _ = q_c.shape
-    dr = width - r
+    dr = q_rope.shape[-1]           # fp rows may be stored wider (zeros)
     p = tbl.shape[1]
     rows = sq * h
     in_specs = [pl.BlockSpec((1, ps, pool.shape[-1]), lambda b_, j, t, *_: (
@@ -368,7 +367,7 @@ def mla_paged_decode_partials(pool, q_c, q_rope, tbl, pos_b, r, scale_dim, *,
         out_specs=[row_spec, row_spec,
                    pl.BlockSpec((1, 1, rows, r),
                                 lambda b_, j, *_: (b_, j, 0, 0))])
-    kernel = functools.partial(_mla_page_kernel, ps=ps, r=r,
+    kernel = functools.partial(_mla_page_kernel, ps=ps, r=r, dr=dr,
                                scale=scale_dim ** -0.5, bits=bits)
     row_shape = jax.ShapeDtypeStruct((b, p, rows, 1), jnp.float32)
     m, l, acc = _call(

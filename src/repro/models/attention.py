@@ -146,12 +146,13 @@ def _pick_q_chunk(b: int, h: int, skv: int) -> int:
     return 1 << (qc.bit_length() - 1)       # round down to a power of two
 
 
-def _chunked_attention_local(q, k, v, q0, kv_valid):
+def _chunked_attention_local(q, k, v, q0, kv_valid, scale=None):
     """Exact causal attention, local arrays, query-chunked.
 
     q: (B, Sq, H, dh) local query slice whose global positions start at q0.
     k, v: (B, Skv, KV, dh) full keys/values.
     kv_valid: number of valid kv rows (int32 scalar).
+    scale: the softmax's score scale; None = 1/sqrt(dh).
     """
     b, sq, hq, dh = q.shape
     skv, kv = k.shape[1], k.shape[2]
@@ -160,7 +161,7 @@ def _chunked_attention_local(q, k, v, q0, kv_valid):
     if sq % qc:
         qc = 1 << ((sq & -sq).bit_length() - 1)   # largest pow2 dividing sq
     nc = sq // qc
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
     kpos = jnp.arange(skv, dtype=jnp.int32)
 
     def chunk(args):
@@ -187,7 +188,8 @@ def _chunked_attention_local(q, k, v, q0, kv_valid):
     return jnp.moveaxis(out, 0, 1).reshape(b, sq, hq, v.shape[-1])
 
 
-def _resume_attention_local(q, k_all, v_all, q0, kv_valid, kv_ok=None):
+def _resume_attention_local(q, k_all, v_all, q0, kv_valid, kv_ok=None,
+                            scale=None):
     """Causal attention of a RESUMED prefill chunk against the slot's full
     cached window (history rows [0, q0) plus the chunk's own rows, which
     the caller has already scattered into the cache).
@@ -203,7 +205,8 @@ def _resume_attention_local(q, k_all, v_all, q0, kv_valid, kv_ok=None):
     kv_ok: optional (B, Skv) bool residency mask (paged windows:
     :func:`~repro.models.common.page_resident_rows`) ANDed into the
     causal/validity mask — all-True on every legal dispatch, so the AND
-    is bit-preserving; see that helper's docstring.
+    is bit-preserving; see that helper's docstring.  ``scale`` as in
+    :func:`_chunked_attention_local`.
 
     Queries are processed in SCORE_BYTES_BUDGET-sized chunks (the key
     axis is never split, so every query row still sees one exact softmax
@@ -214,7 +217,7 @@ def _resume_attention_local(q, k_all, v_all, q0, kv_valid, kv_ok=None):
     b, sq, hq, dh = q.shape
     skv, kv = k_all.shape[1], k_all.shape[2]
     g = hq // kv
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
     kpos = jnp.arange(skv, dtype=jnp.int32)
 
     def chunk(qx, c0):
@@ -373,7 +376,7 @@ def _pool_spec(ndim: int) -> P:
     return P(None, ax, *([None] * (ndim - 2)))
 
 
-def _page_partials(q, kw, vw, tbl, qpos, kv_valid):
+def _page_partials(q, kw, vw, tbl, qpos, kv_valid, scale=None):
     """Per-LOGICAL-page flash-decoding partials of ``q`` against a
     gathered (B, P*ps, KV, dh) window.
 
@@ -394,32 +397,34 @@ def _page_partials(q, kw, vw, tbl, qpos, kv_valid):
 
     Queries are chunked against SCORE_BYTES_BUDGET like every other
     attention path (key axis untouched — bitwise chunk-independent).
+    ``scale`` as in :func:`_chunked_attention_local`.
     """
     b, sq, hq, dh = q.shape
     skv = kw.shape[1]
     qc = _pick_q_chunk(b, hq, skv)
     if sq <= qc:
-        return _page_partials_chunk(q, kw, vw, tbl, qpos, kv_valid)
+        return _page_partials_chunk(q, kw, vw, tbl, qpos, kv_valid, scale)
     if sq % qc:
         qc = 1 << ((sq & -sq).bit_length() - 1)   # largest pow2 dividing sq
     nc = sq // qc
     qr = jnp.moveaxis(q.reshape(b, nc, qc, hq, dh), 1, 0)
     pr = jnp.moveaxis(qpos.reshape(b, nc, qc), 1, 0)
     m, l, acc = jax.lax.map(
-        lambda a: _page_partials_chunk(a[0], kw, vw, tbl, a[1], kv_valid),
+        lambda a: _page_partials_chunk(a[0], kw, vw, tbl, a[1], kv_valid,
+                                       scale),
         (qr, pr))
     merge = lambda x: jnp.moveaxis(x, 0, 1).reshape(       # noqa: E731
         (b, sq) + x.shape[3:])
     return merge(m), merge(l), merge(acc)
 
 
-def _page_partials_chunk(q, kw, vw, tbl, qpos, kv_valid):
+def _page_partials_chunk(q, kw, vw, tbl, qpos, kv_valid, scale=None):
     b, sq, hq, dh = q.shape
     skv, kv = kw.shape[1], kw.shape[2]
     g = hq // kv
     p = tbl.shape[1]
     ps = skv // p
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
     qx = q.reshape(b, sq, kv, g, dh)
     s = jnp.einsum("bqkgd,bskd->bqkgs", (qx * scale).astype(q.dtype), kw,
                    preferred_element_type=jnp.float32)
@@ -674,15 +679,16 @@ def _batch_spec(mesh, b: int):
     return ax if b % _axes_size(mesh, axes) == 0 else None
 
 
-def sdpa(q, k, v, *, kv_valid) -> jax.Array:
+def sdpa(q, k, v, *, kv_valid, scale=None) -> jax.Array:
     """Causal SDPA for q/k/v of equal seq length (train/prefill).
 
     q: (B, S, H, dh), k/v: (B, S, KV, dh), both seq-sharded per the rules.
+    ``scale``: the softmax's score scale; None = 1/sqrt(dh).
     """
     mesh, seq_axes = _seq_axes_info()
     if not seq_axes or q.shape[1] % _axes_size(mesh, seq_axes):
         return _chunked_attention_local(
-            q, k, v, jnp.int32(0), kv_valid)
+            q, k, v, jnp.int32(0), kv_valid, scale)
 
     bspec = _batch_spec(mesh, q.shape[0])
     qkv_spec = P(bspec, make_spec((None, "seq"))[1], None, None)
@@ -695,7 +701,7 @@ def sdpa(q, k, v, *, kv_valid) -> jax.Array:
         q0 = (idx * s_loc).astype(jnp.int32)
         kf = jax.lax.all_gather(k_l, seq_axes, axis=1, tiled=True)
         vf = jax.lax.all_gather(v_l, seq_axes, axis=1, tiled=True)
-        return _chunked_attention_local(q_l, kf, vf, q0, kv_valid)
+        return _chunked_attention_local(q_l, kf, vf, q0, kv_valid, scale)
 
     return jax.shard_map(
         local_fn, mesh=mesh, in_specs=(qkv_spec, qkv_spec, qkv_spec),

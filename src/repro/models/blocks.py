@@ -3,7 +3,7 @@
 Every block has the same interface:
   specs(cfg)                                  -> ParamSpec tree
   apply(p, x, cfg, cache, mode, pos, pages,
-        offset, layer)                        -> (x', new_cache, aux_loss)
+        offset, layer)                        -> (x', new_cache, aux)
   cache_spec(cfg, batch, capacity)            -> ParamSpec tree or None
   paged_cache_spec(cfg, num_pages, page_size,
                    fmt=pageformat.FP)        -> ParamSpec tree or None
@@ -14,6 +14,9 @@ per-slot state and ignore it.  ``offset`` is the (B,) start row of a
 RESUMABLE chunk (mode='chunk'): attention families scatter/attend at
 absolute rows [offset, offset + len), recurrent families resume their
 cached state when offset > 0; None keeps the single-pass chunk path.
+``aux`` is the MoE load-balance loss in training, and in serving modes
+the expert layer's f32 (3,) pick counts (``moe.moe_ffn``); blocks
+without experts return a scalar 0, which adds to either.
 ``layer`` is the layer scan's index when ``cache`` is a paged pool: the
 cache is then the whole stacked (L, num_pages, ...) pool, and the block
 writes and reads only layer ``layer``'s pages, in place
@@ -81,12 +84,18 @@ def _attn_block_specs(cfg, ffn: str) -> dict:
     return s
 
 
-def _chunk_token_mask(x, mode, pos):
-    """(B, S) valid-token mask in chunked-prefill mode, else None."""
-    if mode != "chunk":
-        return None
+def _token_mask(x, mode, pos):
+    """(B, S) valid-token mask of a serving call, None in training: chunk
+    and verify rows past each slot's length, and decode slots at a
+    negative (inactive) position, are padding."""
     b, s = x.shape[:2]
-    return chunk_valid_mask(chunk_lengths(pos, b), s)
+    if mode == "train":
+        return None
+    if mode in ("chunk", "verify"):
+        return chunk_valid_mask(chunk_lengths(pos, b), s)
+    if mode == "decode":
+        return jnp.broadcast_to((jnp.atleast_1d(pos) >= 0)[:, None], (b, s))
+    return jnp.ones((b, s), bool)
 
 
 def _apply_attn_block(p, x, cfg, cache, mode, pos, pages, offset, layer,
@@ -100,7 +109,7 @@ def _apply_attn_block(p, x, cfg, cache, mode, pos, pages, offset, layer,
     h = apply_norm(p["ln2"], x, cfg)
     if ffn == "moe":
         y, aux = moe.moe_ffn(p["ffn"], h, cfg,
-                             token_mask=_chunk_token_mask(x, mode, pos))
+                             token_mask=_token_mask(x, mode, pos))
     else:
         y, aux = apply_mlp(p["ffn"], h, cfg), jnp.float32(0)
     x = lshard(x + y, "batch", "seq", None)
@@ -125,7 +134,7 @@ def _apply_mla_block(p, x, cfg, cache, mode, pos, pages, offset, layer,
     h = apply_norm(p["ln2"], x, cfg)
     if ffn == "moe":
         y, aux = moe.moe_ffn(p["ffn"], h, cfg,
-                             token_mask=_chunk_token_mask(x, mode, pos))
+                             token_mask=_token_mask(x, mode, pos))
     else:
         y, aux = apply_mlp(p["ffn"], h, cfg), jnp.float32(0)
     x = lshard(x + y, "batch", "seq", None)

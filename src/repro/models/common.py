@@ -18,10 +18,12 @@ leaf has been converted to a PackedWeight for serving.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.quant import QuantConfig, fake_quant_activation, fake_quant_weight
 from repro.distributed.sharding import lshard
@@ -228,10 +230,17 @@ def paged_gather(pool: jax.Array, pages: jax.Array,
     """
     lead = 0 if layer is None else 1
     n, ps = pool.shape[lead:lead + 2]
-    flat = pool.reshape((-1,) + pool.shape[lead + 2:])
     page = jnp.maximum(pages, 0)
     if layer is not None:
         page = page + layer * n
+    if pool.ndim - lead == 3:
+        # rows of one feature axis (MLA's latent): take whole pages by
+        # index.  Sliced out of the row view, XLA:TPU runs this gather as
+        # a loop of one dynamic-update-slice per page.
+        win = jnp.take(pool.reshape((-1,) + pool.shape[lead + 1:]), page,
+                       axis=0, mode="clip")
+        return win.reshape((pages.shape[0], -1) + win.shape[3:])
+    flat = pool.reshape((-1,) + pool.shape[lead + 2:])
     # whole pages of ps rows, not single rows: a page is one contiguous
     # block of the pool, and a row of a few KV heads is too small to
     # fetch alone at speed.  Slicing the row view keeps the pool's layout.
@@ -343,16 +352,59 @@ def layer_norm(x: jax.Array, w: jax.Array, b: jax.Array,
     return (h * w.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding, NeoX half-split convention.
+def yarn_get_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor 0.1 * mscale * ln(factor) + 1
+    (1 without scaling), as the published DeepSeek-V2 code computes it."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(scaling, dim: int, theta: float):
+    """(low, high) rotary dims between which YaRN blends: the dims at
+    which ``beta_fast`` and ``beta_slow`` full rotations fit in the
+    original context, floored and ceiled into [0, dim - 1]."""
+    def at(rotations):
+        return dim * math.log(scaling.original_max_position_embeddings /
+                              (rotations * 2 * math.pi)) / \
+            (2 * math.log(theta))
+    return (max(math.floor(at(scaling.beta_fast)), 0),
+            min(math.ceil(at(scaling.beta_slow)), dim - 1))
+
+
+def yarn_inv_freq(dim: int, theta: float, scaling) -> np.ndarray:
+    """(dim/2,) float32 YaRN inverse frequencies: the base frequencies
+    below the correction range, those of ``theta * factor`` above it, and
+    a linear ramp between."""
+    base = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim))
+    inter = base / np.float32(scaling.factor)
+    low, high = yarn_correction_range(scaling, dim, theta)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low) /
+                   (high - low), 0, 1)
+    return (inter * ramp + base * (1 - ramp)).astype(np.float32)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         scaling=None) -> jax.Array:
+    """Rotary embedding, NeoX half-split convention; ``scaling`` (a
+    config's ``RopeScaling``) switches to YaRN's frequencies and cos/sin
+    factor.
 
     x: (B, S, H, D), positions: (B, S) int32.
     """
     d = x.shape[-1]
     half = d // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling is None:
+        freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freq = jnp.asarray(yarn_inv_freq(d, theta, scaling))
     ang = positions[..., None].astype(jnp.float32) * freq  # (B, S, half)
     sin, cos = jnp.sin(ang)[:, :, None, :], jnp.cos(ang)[:, :, None, :]
+    if scaling is not None:
+        m = yarn_get_mscale(scaling.factor, scaling.mscale) / \
+            yarn_get_mscale(scaling.factor, scaling.mscale_all_dim)
+        if m != 1.0:
+            sin, cos = sin * m, cos * m
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)

@@ -21,6 +21,25 @@ import jax.numpy as jnp
 from repro.core.quant import QuantConfig
 
 
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rope scaling in DeepSeek-V2's form (arXiv:2309.00071; the
+    published ``DeepseekV2YarnRotaryEmbedding``): rotary frequencies blend
+    from ``theta`` to ``theta * factor`` over a linear ramp between the
+    correction dims that ``beta_fast`` and ``beta_slow`` rotations over
+    ``original_max_position_embeddings`` positions give; cos/sin take the
+    factor mscale(factor, mscale) / mscale(factor, mscale_all_dim), and
+    latent attention's softmax scale mscale(factor, mscale_all_dim)**2
+    (``repro.models.common.yarn_*``)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    type: str = "yarn"
+
+
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
@@ -40,6 +59,7 @@ class ArchConfig:
     qk_norm: bool = False
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None   # None: plain rope
     norm: str = "rms"                # rms | layer
     mlp_act: str = "silu_glu"        # silu_glu | gelu
 
@@ -48,8 +68,13 @@ class ArchConfig:
     top_k: int = 0
     n_shared_experts: int = 0
     d_ff_expert: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25        # training only: serving is dropless
     aux_loss_weight: float = 0.01
+    # the contiguous range [lo, hi) of routed experts whose weights this
+    # device holds (expert parallelism's share); None = all of them.  The
+    # router always scores all n_experts.
+    experts_held: Optional[Tuple[int, int]] = None
+    norm_topk_prob: bool = True      # renormalise the top-k gates to sum 1
 
     # MLA (deepseek)
     kv_lora_rank: int = 0
@@ -82,6 +107,14 @@ class ArchConfig:
             kind = {"moe": "attn_moe"}.get(self.family, "attn_mlp")
             object.__setattr__(self, "pattern",
                                (("scan", kind, self.n_layers),))
+        lo, hi = self.held_experts()
+        if self.n_experts and not 0 <= lo < hi <= self.n_experts:
+            raise ValueError(f"{self.name}: experts_held {self.experts_held}"
+                             f" is no range of the {self.n_experts} experts")
+
+    def held_experts(self) -> Tuple[int, int]:
+        """[lo, hi) of the routed experts held here."""
+        return self.experts_held or (0, self.n_experts)
 
     @property
     def padded_vocab(self) -> int:

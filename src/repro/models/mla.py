@@ -31,12 +31,30 @@ from repro.models.common import (ParamSpec, broadcast_offset, chunk_lengths,
                                  page_resident_rows, paged_gather,
                                  paged_gather_quant, paged_scatter,
                                  paged_scatter_quant, rms_norm, rope,
-                                 shard_local_pages, stacked_as_pool)
+                                 shard_local_pages, stacked_as_pool,
+                                 yarn_get_mscale)
 
 
 def mla_dims(cfg):
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     return dn, dr, dv
+
+
+def score_dim(cfg) -> float:
+    """The d whose 1/sqrt(d) scales the attention scores: qk_nope +
+    qk_rope, or under YaRN that over mscale(factor, mscale_all_dim)**4, so
+    that the scale takes the published model's factor mscale**2."""
+    dn, dr, _ = mla_dims(cfg)
+    ys = cfg.rope_scaling
+    if ys is None:
+        return dn + dr
+    return (dn + dr) / yarn_get_mscale(ys.factor, ys.mscale_all_dim) ** 4
+
+
+def score_scale(cfg):
+    """The expanded-form attention's score scale; None (1/sqrt(head dim),
+    the attention default) without rope scaling."""
+    return None if cfg.rope_scaling is None else score_dim(cfg) ** -0.5
 
 
 def mla_specs(cfg) -> dict:
@@ -61,6 +79,24 @@ def mla_cache_spec(cfg, batch: int, capacity: int):
     }
 
 
+def latent_width(r: int, dr: int) -> int:
+    """Stored width of a latent row in an fp page pool: r + dr, rounded
+    up to whole 128-lane tiles once it spans more than one.  A pool whose
+    rows end in a part-tile (576 = 4.5 tiles at DeepSeek-V2's widths) is
+    laid out pages-minor on the TPU, and each step then copies the whole
+    stacked pool into and out of the layer scan; the columns past r + dr
+    hold zeros and are never read."""
+    w = r + dr
+    return w if w <= 128 else -(-w // 128) * 128
+
+
+def _fit(entry, pool):
+    """``entry`` rows zero-padded to an fp pool's stored width."""
+    pad = pool.shape[-1] - entry.shape[-1]
+    return entry if pad == 0 else \
+        jnp.pad(entry, [(0, 0)] * (entry.ndim - 1) + [(0, pad)])
+
+
 def paged_mla_cache_spec(cfg, num_pages: int, page_size: int, fmt=FP):
     """Paged layout for the compressed cache: a (num_pages, page_size,
     r+dr) pool per layer, addressed through the engine's per-slot page
@@ -68,11 +104,12 @@ def paged_mla_cache_spec(cfg, num_pages: int, page_size: int, fmt=FP):
     table maps 'pages' (see attention.paged_kv_cache_spec).  Quantized
     ``fmt``: the pool stores packed int8 latent rows (one absmax scale
     per row spanning the c_kv AND k_rope halves) with a pool-shaped
-    ``ckv_scale`` leaf riding the same page axis."""
+    ``ckv_scale`` leaf riding the same page axis.  fp rows are stored
+    :func:`latent_width` wide."""
     r, dr = cfg.kv_lora_rank, cfg.qk_rope_dim
     if not fmt.quantized:
         return {
-            "ckv": ParamSpec((num_pages, page_size, r + dr),
+            "ckv": ParamSpec((num_pages, page_size, latent_width(r, dr)),
                              ("pages", None, None), init="zeros"),
         }
     return {
@@ -99,7 +136,7 @@ def _mla_window_partials(buf, qc, qr, lt, pb, r, scale_dim):
     b, w = buf.shape[:2]
     p_ = lt.shape[1]
     ps = w // p_
-    c_all, kr_all = buf[..., :r], buf[..., r:]
+    c_all, kr_all = buf[..., :r], buf[..., r:r + qr.shape[-1]]
     sc = jnp.einsum("bqhr,bsr->bqhs", qc, c_all,
                     preferred_element_type=jnp.float32)
     sc += jnp.einsum("bqhd,bsd->bqhs", qr, kr_all,
@@ -174,7 +211,7 @@ def _mla_paged_decode(q_c, q_rope, entry, cache, pages, pos_b, r,
         ctx_c, pl = jax.shard_map(
             body, mesh=mesh, in_specs=(pspec, P(), P(), P(), P(), P(), P()),
             out_specs=(P(), pspec), check_vma=False)(
-                pool, entry, q_c, q_rope, pages, pos_b, layer)
+                pool, _fit(entry, pool), q_c, q_rope, pages, pos_b, layer)
         return ctx_c, {"ckv": pl}
 
     sspec = _pool_spec(cache["ckv_scale"].ndim)
@@ -228,7 +265,7 @@ def _mla_paged_resume(p, qq, entry, cache, pages, t, ok, off_b, len_b, cfg,
 
     def expand_window(buf, w_uk, w_uv):
         w = buf.shape[1]
-        c_all, kr_all = buf[..., :r], buf[..., r:]
+        c_all, kr_all = buf[..., :r], buf[..., r:r + dr]
         k_nope_w = dense(c_all, w_uk, cfg.quant).reshape(b, w, h, dn)
         v_w = dense(c_all, w_uv, cfg.quant).reshape(b, w, h, dv)
         k_full = jnp.concatenate(
@@ -238,8 +275,8 @@ def _mla_paged_resume(p, qq, entry, cache, pages, t, ok, off_b, len_b, cfg,
 
     if mesh is None:
         if fmt is None:
-            new_cache = {"ckv": paged_scatter(pool, pages, entry, t, ok,
-                                              layer)}
+            new_cache = {"ckv": paged_scatter(pool, pages, _fit(entry, pool),
+                                              t, ok, layer)}
             buf = paged_gather(new_cache["ckv"], pages, layer)
         else:
             pl, pls = paged_scatter_quant(pool, cache["ckv_scale"], pages,
@@ -250,7 +287,8 @@ def _mla_paged_resume(p, qq, entry, cache, pages, t, ok, off_b, len_b, cfg,
         k_full, v_w = expand_window(buf, p["w_uk"], p["w_uv"])
         o = _resume_attention_local(
             qq, k_full, v_w, off_b, off_b + len_b,
-            kv_ok=page_resident_rows(pages, pool.shape[2]))
+            kv_ok=page_resident_rows(pages, pool.shape[2]),
+            scale=score_scale(cfg))
         return o, new_cache
 
     pspec = _pool_spec(pool.ndim)
@@ -265,7 +303,8 @@ def _mla_paged_resume(p, qq, entry, cache, pages, t, ok, off_b, len_b, cfg,
             k_full, v_w = expand_window(buf, w_uk, w_uv)
             qpos = q0[:, None] + \
                 jnp.arange(q_.shape[1], dtype=jnp.int32)[None]
-            m, l, acc = _page_partials(q_, k_full, v_w, lt, qpos, kvv)
+            m, l, acc = _page_partials(q_, k_full, v_w, lt, qpos, kvv,
+                                       score_scale(cfg))
             m = jax.lax.pmax(m, axes)
             l = jax.lax.psum(l, axes)
             acc = jax.lax.psum(acc, axes)
@@ -277,8 +316,8 @@ def _mla_paged_resume(p, qq, entry, cache, pages, t, ok, off_b, len_b, cfg,
             in_specs=(pspec, P(), P(), P(), P(), P(), P(), P(), P(), P(),
                       P()),
             out_specs=(P(), pspec), check_vma=False)(
-                pool, entry, qq, pages, t, ok, off_b, off_b + len_b,
-                p["w_uk"], p["w_uv"], layer)
+                pool, _fit(entry, pool), qq, pages, t, ok, off_b,
+                off_b + len_b, p["w_uk"], p["w_uv"], layer)
         return o, {"ckv": pl}
 
     sspec = _pool_spec(cache["ckv_scale"].ndim)
@@ -293,7 +332,8 @@ def _mla_paged_resume(p, qq, entry, cache, pages, t, ok, off_b, len_b, cfg,
         buf = paged_gather_quant(pl, pls, lt, fmt, entry.dtype, li)
         k_full, v_w = expand_window(buf, w_uk, w_uv)
         qpos = q0[:, None] + jnp.arange(q_.shape[1], dtype=jnp.int32)[None]
-        m, l, acc = _page_partials(q_, k_full, v_w, lt, qpos, kvv)
+        m, l, acc = _page_partials(q_, k_full, v_w, lt, qpos, kvv,
+                                   score_scale(cfg))
         m = jax.lax.pmax(m, axes)
         l = jax.lax.psum(l, axes)
         acc = jax.lax.psum(acc, axes)
@@ -323,7 +363,7 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
     h = cfg.n_heads
     r = cfg.kv_lora_rank
     dn, dr, dv = mla_dims(cfg)
-    scale_dim = dn + dr
+    scale_dim = score_dim(cfg)
 
     q = dense(x, p["w_q"], cfg.quant).reshape(b, s, h, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
@@ -340,10 +380,11 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
         positions = jnp.atleast_1d(pos)[:, None] + \
             jnp.arange(s, dtype=jnp.int32)[None, :]
         positions = jnp.broadcast_to(jnp.maximum(positions, 0), (b, s))
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
+    q_rope = rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
 
     c_kv, k_r = _compress(p, x, cfg)
-    k_rope = rope(k_r[:, :, None, :], positions, cfg.rope_theta)  # (B,S,1,dr)
+    k_rope = rope(k_r[:, :, None, :], positions, cfg.rope_theta,
+                  cfg.rope_scaling)                             # (B,S,1,dr)
 
     new_cache = None
     if mode == "chunk" and off_b is not None:
@@ -366,13 +407,14 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
             new_cache = {"ckv": contig_scatter(cache["ckv"], entry, t, ok)}
             buf = new_cache["ckv"]
             w = buf.shape[1]
-            c_all, kr_all = buf[..., :r], buf[..., r:]
+            c_all, kr_all = buf[..., :r], buf[..., r:r + dr]
             k_nope_w = dense(c_all, p["w_uk"], cfg.quant).reshape(b, w, h, dn)
             v_w = dense(c_all, p["w_uv"], cfg.quant).reshape(b, w, h, dv)
             k_full = jnp.concatenate(
                 [k_nope_w, jnp.broadcast_to(kr_all[:, :, None, :],
                                             (b, w, h, dr))], axis=-1)
-            o = _resume_attention_local(qq, k_full, v_w, off_b, off_b + len_b)
+            o = _resume_attention_local(qq, k_full, v_w, off_b, off_b + len_b,
+                                        scale=score_scale(cfg))
     elif mode == "chunk" and pages is not None and \
             cache_page_format(cache, r + dr) is not None:
         # quantized pool, fresh chunk: route through the resume path at
@@ -400,7 +442,7 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
         qq = lshard(qq, "batch", "seq", "heads", None)
         k = lshard(k, "batch", "seq", "heads", None)
         v = lshard(v, "batch", "seq", "heads", None)
-        o = sdpa(qq, k, v, kv_valid=jnp.int32(s))
+        o = sdpa(qq, k, v, kv_valid=jnp.int32(s), scale=score_scale(cfg))
         if mode == "prefill":
             entry = jnp.concatenate([c_kv, k_rope[:, :, 0, :]], axis=-1)
             cap = cache["ckv"].shape[1]
@@ -416,7 +458,8 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
                     jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
                 ok = chunk_valid_mask(chunk_lengths(pos, b), s)
                 new_cache = {"ckv": sharded_paged_scatter(
-                    cache["ckv"], pages, entry, t, ok, layer)}
+                    cache["ckv"], pages, _fit(entry, cache["ckv"]), t, ok,
+                    layer)}
             else:
                 buf = cache["ckv"]
                 cap = buf.shape[1]
@@ -450,7 +493,8 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
         if pages is not None:
             fmt = cache_page_format(cache, r + dr)
             if fmt is None:
-                pool = paged_scatter(cache["ckv"], pages, entry,
+                pool = paged_scatter(cache["ckv"], pages,
+                                     _fit(entry, cache["ckv"]),
                                      pos_b[:, None], (pos_b >= 0)[:, None],
                                      layer)
                 new_cache = {"ckv": pool}
@@ -473,7 +517,7 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, cache: Optional[dict],
             buf = buf.at[jnp.arange(b), idx].set(new[:, 0])
             buf = lshard(buf, "cache_batch", "cache_seq", None)
             new_cache = {"ckv": buf}
-        c_all, kr_all = buf[..., :r], buf[..., r:]
+        c_all, kr_all = buf[..., :r], buf[..., r:r + dr]
         # absorbed queries: q_c = q_nope @ W_UK^T per head -> (B,1,H,r)
         w_uk = p["w_uk"].reshape(r, h, dn)
         q_c = jnp.einsum("bqhd,rhd->bqhr", q_nope.astype(jnp.float32),

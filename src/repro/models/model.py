@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import lshard
-from repro.models import common
+from repro.models import common, moe
 from repro.models.blocks import BLOCKS, apply_norm, norm_specs
 from repro.models.common import ParamSpec, dense, embed_lookup, stack_specs
 from repro.models.config import ArchConfig
@@ -173,6 +173,16 @@ def _apply_stage(kinds, count, stage_p, x, cfg, stage_c, mode, pos, pages,
     pools = {k: stage_c[k] for k in pooled}
     rest = None if stage_c is None else \
         {k: c for k, c in stage_c.items() if k not in pooled}
+    # serving's expert layers read their banks in place out of the whole
+    # stack, at the layer index (``moe.moe_ffn``): a per-layer slice of
+    # ``xs`` would be copied out for the grouped-matmul kernel each layer
+    stacked = {f"b{j}" for j, kind in enumerate(kinds)
+               if mode != "train" and kind.endswith("_moe")}
+    banks = {k: {n: stage_p[k]["ffn"][n] for n in moe.BANKS}
+             for k in stacked}
+    stage_p = {k: {**v, "ffn": {n: w for n, w in v["ffn"].items()
+                                if n not in moe.BANKS}}
+               if k in stacked else v for k, v in stage_p.items()}
 
     def body(carry, xs):
         h, aux, pools = carry
@@ -181,6 +191,9 @@ def _apply_stage(kinds, count, stage_p, x, cfg, stage_c, mode, pos, pages,
         for j, kind in enumerate(kinds):
             key = f"b{j}"
             p_j = shared if kind == "shared_attn" else p_map[key]
+            if key in banks:
+                p_j = {**p_j, "ffn": {**p_j["ffn"], **banks[key],
+                                      "layer": i}}
             if key in pools:
                 h, pools[key], a = BLOCKS[kind].apply(
                     p_j, h, cfg, pools[key], mode, pos, pages, offset, i)
@@ -193,8 +206,11 @@ def _apply_stage(kinds, count, stage_p, x, cfg, stage_c, mode, pos, pages,
             aux = aux + a
         return (h, aux, pools), new_c
 
+    # serving calls of an expert layer add up its (3,) pick counts
+    moe_counts = mode != "train" and any(k.endswith("_moe") for k in kinds)
+    aux0 = jnp.zeros((3,), jnp.float32) if moe_counts else jnp.float32(0)
     (x, aux, pools), c_out = jax.lax.scan(
-        _remat(body, cfg, mode), (x, jnp.float32(0), pools),
+        _remat(body, cfg, mode), (x, aux0, pools),
         (stage_p, rest, jnp.arange(count, dtype=jnp.int32)))
     return x, {**c_out, **pools}, aux
 
@@ -204,7 +220,11 @@ def forward(params: dict, inputs: jax.Array, cfg: ArchConfig, *,
             pos: Any = 0, pages: Optional[jax.Array] = None,
             offset: Optional[Any] = None,
             ) -> Tuple[jax.Array, Optional[list], jax.Array]:
-    """Returns (logits (B, S, padded_vocab), new_cache, aux_loss).
+    """Returns (logits (B, S, padded_vocab), new_cache, aux): ``aux`` is
+    the MoE load-balance loss in training, and in a serving mode of a
+    model with experts the f32 (3,) [picks routed to experts held here,
+    all picks, held experts with any pick] summed over the layers
+    (``moe.moe_ffn``).
 
     ``pages``: optional (B, P) int32 per-slot page table when ``cache``
     uses the paged layout (see module docstring); None = contiguous.

@@ -1,23 +1,38 @@
-"""Mixture-of-Experts FFN: top-k capacity routing + explicit EP all-to-all.
+"""Mixture-of-Experts FFN: dropless serving over a share of the experts,
+and top-k capacity routing + explicit EP all-to-all for training.
 
-Routing is GShard-style top-k with a fixed per-expert capacity, but
+Serving (``token_mask`` given, every serving mode) is DROPLESS
+(`_moe_dropless`): the router scores all ``n_experts``, and the layer
+computes the part of the result that the experts held here
+(``ArchConfig.experts_held``, a contiguous range) give, for exactly the
+(token, pick) pairs routed to them.  The pairs are sorted by expert and
+the held experts run as grouped matmuls over the group sizes (megablox
+``gmm``, which reads each layer's weights in place out of the layer
+stack), so the work follows the picks, no token is dropped and none can
+displace another: a token's output does not depend on its batch-mates.
+Picks of experts held elsewhere add nothing here (expert parallelism's
+exchange is not part of a one-chip step).  On a mesh that shards the
+experts, each shard computes its own and one all-reduce adds them.
+
+Training routing is GShard-style top-k with a fixed per-expert capacity, but
 *without* the O(tokens x experts x capacity) one-hot dispatch tensors:
 assignments are ranked within their expert by a stable sort, giving each a
 (expert, capacity-slot) coordinate.
 
-Data movement runs under shard_map (`_moe_shardmap`): experts are sharded
-over the 'model' axis and each expert's capacity rows are striped over
-('pod','data'), so a token's coordinate names a unique destination device.
-Each device buckets its assignments by destination, performs ONE fused
-all-to-all over the whole mesh (payload + routing metadata), computes its
-local experts, and reverses the all-to-all to combine — the canonical
-expert-parallel schedule, with compute and comm both 1/n_devices.  (Letting
-XLA's SPMD partitioner derive this from scatter sharding constraints
-instead produced replicated multi-GB scatter expansions — see
-EXPERIMENTS.md §Perf.)
+Training's data movement runs under shard_map (`_moe_shardmap`): experts
+are sharded over the 'model' axis and each expert's capacity rows are
+striped over ('pod','data'), so a token's coordinate names a unique
+destination device. Each device buckets its assignments by destination,
+performs ONE fused all-to-all over the whole mesh (payload + routing
+metadata), computes its local experts, and reverses the all-to-all to
+combine — the canonical expert-parallel schedule, with compute and comm
+both 1/n_devices. (Letting XLA's SPMD partitioner derive this from
+scatter sharding constraints instead produced replicated multi-GB
+scatter expansions — see EXPERIMENTS.md §Perf.)
 
 On a single device (tests/examples) the same math runs as the pure-jnp
 scatter path (`_moe_dense_path`), which doubles as the shard_map oracle.
+Both training paths need every expert held here.
 
 Sub-byte quantization (the paper's technique) pays most here: expert banks
 dominate parameter bytes while each token touches only top-k of them, so
@@ -25,6 +40,7 @@ packed int4/int2 expert weights cut the dominant HBM term (§Perf).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -32,15 +48,19 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from jax.experimental.pallas.ops.tpu.megablox import gmm
+
 from repro.core.quant import fake_quant
-from repro.distributed.sharding import current_mesh, lshard, make_spec
+from repro.distributed.sharding import (current_mesh, lshard, make_spec,
+                                       mesh_axes_for)
 from repro.models.common import ParamSpec, dense
 
 
 def moe_specs(cfg) -> dict:
-    d, e, f = cfg.d_model, cfg.n_experts, cfg.d_ff_expert
+    lo, hi = cfg.held_experts()
+    d, e, f = cfg.d_model, hi - lo, cfg.d_ff_expert
     specs = {
-        "router": ParamSpec((d, e), ("embed", None), scale=0.02),
+        "router": ParamSpec((d, cfg.n_experts), ("embed", None), scale=0.02),
         "w_gate": ParamSpec((e, d, f), ("expert", "embed", "ffn"), quantize=True),
         "w_up": ParamSpec((e, d, f), ("expert", "embed", "ffn"), quantize=True),
         "w_down": ParamSpec((e, f, d), ("expert", "ffn", "embed"), quantize=True),
@@ -74,14 +94,20 @@ def _rank_in_group(ids: jax.Array) -> jax.Array:
     return jnp.zeros((a,), jnp.int32).at[order].set(ranks_sorted)
 
 
-def _expert_swiglu(buf, wg, wu, wd, quant, dtype):
-    """Batched per-expert SwiGLU with the paper's quantization emulation."""
+def _quant_experts(buf, wg, wu, wd, quant):
+    """The paper's quantization emulation of expert weights and inputs."""
     if quant is not None and quant.quantized:
         wg = fake_quant(wg, quant.w_bits, 1)
         wu = fake_quant(wu, quant.w_bits, 1)
         wd = fake_quant(wd, quant.w_bits, 1)
         if quant.mode in ("int", "qat"):
             buf = fake_quant(buf, quant.a_bits, -1)
+    return buf, wg, wu, wd
+
+
+def _expert_swiglu(buf, wg, wu, wd, quant, dtype):
+    """Batched per-expert SwiGLU with the paper's quantization emulation."""
+    buf, wg, wu, wd = _quant_experts(buf, wg, wu, wd, quant)
     g = jnp.einsum("ecd,edf->ecf", buf, wg)
     u = jnp.einsum("ecd,edf->ecf", buf, wu)
     h = jax.nn.silu(g.astype(jnp.float32)).astype(dtype) * u
@@ -105,6 +131,139 @@ def _moe_dense_path(p, xf, idx_e, idx_c, keep, gate_vals, cap, cfg):
     y_a = jnp.where(keep[:, None], y_a, 0)
     y_a = y_a * gate_vals.reshape(a, 1).astype(xf.dtype)
     return y_a.reshape(t, k, d).sum(axis=1)
+
+
+BANKS = ("w_gate", "w_up", "w_down")
+ROW_TILE = 128      # the grouped matmul's row block; dropless rows round to it
+
+
+def _block(dim: int, cap: int = 1024) -> int:
+    """The grouped matmul's block along a weight dim: the whole dim up to
+    1.5 x ``cap``, else the largest multiple of 128 at most ``cap`` that
+    divides it."""
+    if dim <= cap + cap // 2:
+        return dim
+    return next((b for b in range(cap, 127, -128) if dim % b == 0), dim)
+
+
+def _grouped(x, w, sizes, base):
+    """Rows of ``x`` sorted by group, each group g times ``w[base + g]``,
+    f32 (megablox ``gmm``; compiled for the TPU, interpreted for any
+    other platform).  ``w`` is (groups, K, N) and may hold more groups
+    than ``sizes`` (every layer's experts, ``base`` picking one layer's):
+    the kernel reads only the blocks of groups with rows, so weights are
+    read in place, once for each row tile of their group.  Rows past
+    sum(sizes) are left unwritten."""
+    _, kd, nd = w.shape
+    run = functools.partial(
+        gmm, preferred_element_type=jnp.float32,
+        tiling=(ROW_TILE, _block(kd), _block(nd)),
+        group_offset=jnp.asarray(-base, jnp.int32))
+    return jax.lax.platform_dependent(
+        x, w, sizes, tpu=functools.partial(run, interpret=False),
+        default=functools.partial(run, interpret=True))
+
+
+def _dropless_local(xf, expert_idx, gate_vals, valid, banks, layer, lo, hi,
+                    cfg):
+    """The experts [lo, hi) of ``banks``' layer ``layer`` (banks stacked
+    (L, hi - lo, ...) over the layers; ``layer`` None: one layer's
+    (hi - lo, ...)) for the (token, pick) pairs routed to them.  Returns
+    (y (T, D), sizes (hi - lo,) picks each expert got)."""
+    t, d = xf.shape
+    k = cfg.top_k
+    a = t * k
+    m = -(-a // ROW_TILE) * ROW_TILE
+    e_flat = expert_idx.reshape(a)
+    held = jnp.repeat(valid, k) & (e_flat >= lo) & (e_flat < hi)
+    group = jnp.where(held, e_flat - lo, hi - lo).astype(jnp.int32)
+    group = jnp.pad(group, (0, m - a), constant_values=hi - lo)
+    order = jnp.argsort(group, stable=True)            # pair at each row
+    sizes = jnp.zeros((hi - lo,), jnp.int32).at[group].add(1, mode="drop")
+    xs = xf[jnp.minimum(order // k, t - 1)]
+    ws, base = banks, 0
+    quant = cfg.quant is not None and cfg.quant.quantized
+    if layer is not None and quant:                # emulate on this layer
+        ws = tuple(w[layer] for w in ws)
+    elif layer is not None:                        # read the stack in place
+        ws = tuple(w.reshape((-1,) + w.shape[2:]) for w in ws)
+        base = layer * (hi - lo)
+    xs, wg, wu, wd = _quant_experts(xs, *ws, cfg.quant)
+    hm = jax.nn.silu(_grouped(xs, wg, sizes, base)) * \
+        _grouped(xs, wu, sizes, base)
+    if cfg.quant is not None and cfg.quant.mode in ("int", "qat"):
+        hm = fake_quant(hm, cfg.quant.a_bits, -1)
+    ys = _grouped(hm.astype(xf.dtype), wd, sizes, base)
+    ys = jnp.where((jnp.arange(m) < jnp.sum(sizes))[:, None], ys, 0)
+    row_of = jnp.zeros((m,), jnp.int32).at[order].set(
+        jnp.arange(m, dtype=jnp.int32))                 # row of each pair
+    y = ys[row_of[:a]].reshape(t, k, d) * gate_vals[..., None]
+    return jnp.sum(y, axis=1).astype(xf.dtype), sizes
+
+
+def _moe_dropless(banks, layer, xf, expert_idx, gate_vals, valid, cfg):
+    """Dropless expert layer over the experts held here.
+
+    xf: (T, D); expert_idx, gate_vals: (T, k) routed over all n_experts;
+    valid: (T,) bool, False for padding (no pick is counted or computed);
+    banks: the expert weights (``BANKS``), stacked over the layers when
+    ``layer`` is given.  The T*k (token, pick) pairs are sorted by held
+    expert, picks of experts not held (and padding) last; the held
+    experts run as three grouped matmuls over the group sizes on a
+    static T*k rows rounded up to ROW_TILE.  Each token's output is the
+    gate-weighted sum of its own picks' rows, read back in pick order,
+    so it depends on nothing else in the batch.
+
+    On a mesh (the kernel cannot be partitioned for it) the layer runs
+    under shard_map: where the mesh shards the 'expert' axis, each shard
+    computes the held experts it holds for every token of its batch
+    shard and the shards' sums are added (one all-reduce of (T, D)); no
+    expert weight moves.
+
+    Returns (y (T, D), picks f32 (3,): [picks routed to held experts, all
+    picks, held experts with any pick])."""
+    lo, hi = cfg.held_experts()
+    k = cfg.top_k
+    mesh, ex = mesh_axes_for("expert")
+    if mesh is None or mesh.size == 1:
+        y, sizes = _dropless_local(xf, expert_idx, gate_vals, valid, banks,
+                                   layer, lo, hi, cfg)
+        return y, jnp.stack([jnp.sum(sizes), k * jnp.sum(valid),
+                             jnp.sum(sizes > 0)]).astype(jnp.float32)
+    n_ex = math.prod(mesh.shape[a] for a in ex)
+    if (hi - lo) % n_ex:                # the banks are replicated then
+        ex, n_ex = (), 1
+    _, bt = mesh_axes_for("batch")
+    dp = tuple(a for a in bt if a not in ex)
+    if xf.shape[0] % math.prod(mesh.shape[a] for a in dp):
+        dp = ()
+    e_loc = (hi - lo) // n_ex
+    lead = (None,) if layer is not None else ()
+
+    def psum(x, axes):
+        return jax.lax.psum(x, axes) if axes else x
+
+    def local(x_l, idx_l, gate_l, valid_l, *banks_l):
+        i = 0
+        for ax in ex:
+            i = i * mesh.shape[ax] + jax.lax.axis_index(ax)
+        lo_l = lo + i * e_loc
+        # this shard's experts [lo_l, lo_l + e_loc), renumbered to start
+        # at lo so that the local range has a static width
+        y, sizes = _dropless_local(x_l, idx_l - lo_l + lo, gate_l, valid_l,
+                                   banks_l, layer, lo, lo + e_loc, cfg)
+        picks = jnp.stack([psum(jnp.sum(sizes), dp + ex),
+                           k * psum(jnp.sum(valid_l), dp),
+                           psum(jnp.sum(psum(sizes, dp) > 0), ex)])
+        return psum(y, ex), picks.astype(jnp.float32)
+
+    tok = P(dp or None)
+    bank = P(*lead, ex or None)
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(tok, tok, tok, tok) + (bank,) * len(banks),
+        out_specs=(tok, P()), check_vma=False)(
+            xf, expert_idx, gate_vals, valid, *banks)
 
 
 def _moe_shardmap(p, x, expert_idx, gate_vals, cap, cfg, mesh,
@@ -228,12 +387,18 @@ def _ep_layout(cfg, b, s, cap, mesh):
 def moe_ffn(p: dict, x: jax.Array, cfg,
             token_mask: Optional[jax.Array] = None
             ) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, D) -> (out (B, S, D), aux_loss scalar).
+    """x: (B, S, D) -> (out (B, S, D), aux).
 
-    ``token_mask``: optional (B, S) bool — False positions (chunked-prefill
-    padding) are excluded from routing entirely: their expert index is the
-    OOB sentinel so they consume NO expert capacity (they must never
-    displace a valid token's slot), and their gates are zeroed.
+    Serving passes ``token_mask``, a (B, S) bool that is False at padding
+    (chunk padding, inactive decode slots): the layer is then dropless
+    over the experts held here (`_moe_dropless`), padding is neither
+    routed nor counted, and ``aux`` is the f32 (3,) [picks routed to held
+    experts, all picks, held experts with any pick].  Serving's layer
+    scan hands over the expert banks stacked over all its layers, with
+    this layer's index as ``p["layer"]`` (``model._apply_stage``), so
+    the kernel reads them where they lie.  Without ``token_mask``
+    (training) ``aux`` is the scalar load-balance loss and routing has a
+    fixed per-expert capacity.
     """
     b, s, d = x.shape
     e, k = cfg.n_experts, cfg.top_k
@@ -244,12 +409,17 @@ def moe_ffn(p: dict, x: jax.Array, cfg,
     logits = lshard(logits, "batch", None)
     probs = jax.nn.softmax(logits, axis=-1)                    # (T, E)
     gate_vals, expert_idx = jax.lax.top_k(probs, k)            # (T, k)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
     if token_mask is not None:
-        tm = token_mask.reshape(t)
-        gate_vals = jnp.where(tm[:, None], gate_vals, 0.0)
-        expert_idx = jnp.where(tm[:, None], expert_idx, e)
+        y, aux = _moe_dropless(tuple(p[n] for n in BANKS), p.get("layer"),
+                               xf, expert_idx, gate_vals,
+                               token_mask.reshape(t), cfg)
+        return _add_shared(p, xf, y, cfg).reshape(b, s, d), aux
+    if cfg.held_experts() != (0, e):
+        raise ValueError(f"{cfg.name}: training needs every expert held "
+                         f"here, not {cfg.experts_held}")
 
     # load-balancing auxiliary loss (Switch-style).
     me = probs.mean(0)
@@ -259,10 +429,7 @@ def moe_ffn(p: dict, x: jax.Array, cfg,
 
     cap = _capacity(t, e, k, cfg.capacity_factor)
     a = t * k
-    # the EP shard_map path has no masked-dispatch support; the dense path
-    # is numerically identical, so masked (serving chunk) calls take it.
-    layout = None if token_mask is not None else \
-        _ep_layout(cfg, b, s, cap, current_mesh())
+    layout = _ep_layout(cfg, b, s, cap, current_mesh())
     if layout is not None:
         # slot assignment happens hierarchically inside the shard_map.
         y = _moe_shardmap(p, x, expert_idx.reshape(b, s, k),
@@ -276,12 +443,15 @@ def moe_ffn(p: dict, x: jax.Array, cfg,
         idx_e = jnp.where(keep, e_flat, e).astype(jnp.int32)   # OOB -> drop
         idx_c = jnp.where(keep, rank, 0).astype(jnp.int32)
         y = _moe_dense_path(p, xf, idx_e, idx_c, keep, gate_vals, cap, cfg)
+    return _add_shared(p, xf, y, cfg).reshape(b, s, d), aux
 
-    if "shared" in p:
-        sh = p["shared"]
-        gs = dense(xf, sh["w_gate"], cfg.quant)
-        us = dense(xf, sh["w_up"], cfg.quant)
-        hs = jax.nn.silu(gs.astype(jnp.float32)).astype(x.dtype) * us
-        y = y + dense(hs, sh["w_down"], cfg.quant)
 
-    return y.reshape(b, s, d), aux
+def _add_shared(p, xf, y, cfg):
+    """y plus the shared experts' SwiGLU of xf, where the layer has them."""
+    if "shared" not in p:
+        return y
+    sh = p["shared"]
+    gs = dense(xf, sh["w_gate"], cfg.quant)
+    us = dense(xf, sh["w_up"], cfg.quant)
+    hs = jax.nn.silu(gs.astype(jnp.float32)).astype(xf.dtype) * us
+    return y + dense(hs, sh["w_down"], cfg.quant)

@@ -65,6 +65,7 @@ single-pass, never-preempted, unshared path.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import time
@@ -103,6 +104,34 @@ _OVERSIZED = "oversized"            # admission verdict: host-tier context
 # measured model (benchmarks/fig12_offload.measure_offload_bandwidth)
 # is unavailable — sized so the auto prefetch depth stays modest.
 _FALLBACK_H2D_BPS = 1e9
+
+
+class _ExpertTally:
+    """A model with experts: its serving steps' pick counts by step kind
+    ([picks routed to the experts held here, all picks, held experts any
+    pick reached], over the layers, padding excluded), added up on the
+    device, and the dispatches that carried them.  Read with one device
+    transfer (``read``)."""
+
+    def __init__(self):
+        self.reset()
+
+    def reset(self) -> None:
+        self.picks: Dict[str, jax.Array] = {}
+        self.dispatches = collections.Counter()
+
+    def add(self, kind: str, picks: jax.Array) -> None:
+        self.picks[kind] = picks + self.picks[kind] \
+            if kind in self.picks else picks
+        self.dispatches[kind] += 1
+
+    def read(self) -> dict:
+        got = jax.device_get(self.picks)
+        return {"expert_picks_held": {k: int(v[0]) for k, v in got.items()},
+                "expert_picks_total": {k: int(v[1]) for k, v in got.items()},
+                "expert_groups_touched": {k: int(v[2])
+                                          for k, v in got.items()},
+                "expert_dispatches": dict(self.dispatches)}
 
 
 @dataclasses.dataclass
@@ -218,6 +247,7 @@ class ServingEngine:
         self.cfg = cfg
         self.params = params
         self.sc = serve_cfg
+        self._experts = _ExpertTally() if cfg.n_experts else None
         bsz = serve_cfg.max_batch
         cap = serve_cfg.slot_rows
         if serve_cfg.paged:
@@ -253,10 +283,11 @@ class ServingEngine:
             # jax.jit objects, so traces never leak across engines with
             # different knob settings.
             self._use_pallas = bool(serve_cfg.use_pallas_decode)
-            self._decode = jax.jit(make_paged_decode_step(cfg),
-                                   donate_argnums=1)
-            self._prefill = jax.jit(make_paged_chunked_prefill_step(cfg),
-                                    donate_argnums=1)
+            self._decode = self._tallied(jax.jit(
+                make_paged_decode_step(cfg), donate_argnums=1), "decode")
+            self._prefill = self._tallied(jax.jit(
+                make_paged_chunked_prefill_step(cfg), donate_argnums=1),
+                "prefill")
             self.alloc = PageAllocator(self.num_pages, ps, bsz,
                                        self.pages_per_slot,
                                        num_shards=self.pool_shards,
@@ -286,9 +317,10 @@ class ServingEngine:
             self._can_share = False
             self._use_pallas = False    # contiguous path has no paged kernel
             self.cache = init_cache(cfg, bsz, cap)
-            self._decode = jax.jit(make_decode_step(cfg), donate_argnums=1)
-            self._prefill = jax.jit(make_chunked_prefill_step(cfg),
-                                    donate_argnums=1)
+            self._decode = self._tallied(jax.jit(
+                make_decode_step(cfg), donate_argnums=1), "decode")
+            self._prefill = self._tallied(jax.jit(
+                make_chunked_prefill_step(cfg), donate_argnums=1), "prefill")
             self._slot_span = cap
             # whole-slot windows (one per slot), mapped once.
             self._plain_iotlb = Iotlb()
@@ -388,9 +420,33 @@ class ServingEngine:
                 dcfg = reduce_config(get_config(serve_cfg.spec_draft))
                 dparams = init_params(dcfg,
                                       jax.random.PRNGKey(serve_cfg.seed))
-            self._verify = jax.jit(make_paged_verify_step(cfg),
-                                   donate_argnums=1)
+            self._verify = self._tallied(jax.jit(
+                make_paged_verify_step(cfg), donate_argnums=1), "verify")
             self._drafter = SpecDrafter(dcfg, dparams, serve_cfg)
+
+    def _tallied(self, step, kind: str):
+        """``step``, a jitted serving step, returning (out, cache) for
+        every model: a model with experts adds each dispatch's pick counts
+        to the engine's tally, on the device (no read)."""
+        tally = self._experts
+        if tally is None:
+            return step
+
+        def call(*args):
+            out, cache, picks = step(*args)
+            tally.add(kind, picks)
+            return out, cache
+        return call
+
+    def stats(self) -> dict:
+        """A model with experts: ``expert_picks_held`` and
+        ``expert_picks_total`` (picks routed to the experts held here, and
+        all picks, padding excluded), ``expert_groups_touched`` (held
+        experts that a pick reached, summed over layers and dispatches)
+        and ``expert_dispatches``, each by step kind (``decode``,
+        ``prefill``) since ``warmup()``: one device read, here and nowhere
+        else.  Empty for a model without experts."""
+        return {} if self._experts is None else self._experts.read()
 
     def _kernel_ctx(self):
         """Context for jitted dispatches: installs the fused-Pallas-decode
@@ -730,6 +786,8 @@ class ServingEngine:
         jax.block_until_ready(lg)
         if self._drafter is not None:
             self._drafter.warmup()
+        if self._experts is not None:
+            self._experts.reset()       # warm-up dispatches count nothing
 
     def admit(self, req: Request) -> bool:
         """Single-request admission (compat shim over the batched path).
@@ -1548,9 +1606,10 @@ class ServingEngine:
         cap = n_host * ps
         cache = jax.tree.map(np.asarray, init_cache(self.cfg, 1, cap))
         if self._ov_prefill is None:
-            self._ov_prefill = jax.jit(
-                make_chunked_prefill_resume_step(self.cfg))
-            self._ov_decode = jax.jit(make_decode_step(self.cfg))
+            self._ov_prefill = self._tallied(jax.jit(
+                make_chunked_prefill_resume_step(self.cfg)), "prefill")
+            self._ov_decode = self._tallied(jax.jit(
+                make_decode_step(self.cfg)), "decode")
         self._oversized.append(_OversizedRequest(
             req=req, cache=cache, cap=cap, n_host_pages=n_host))
         self.n_oversized += 1

@@ -119,14 +119,23 @@ def make_train_step(cfg: ArchConfig, opt_cfg: AdamWConfig = AdamWConfig(),
 
 
 # ---------------------------------------------------------------------------
-# Serving steps.
+# Serving steps.  Each returns (out, cache); a model with experts adds a
+# third output, the step's int32 (3,) expert pick counts summed over the
+# layers: picks routed to the experts held here, all picks (padding
+# excluded), and held experts that any pick reached (``forward``'s aux).
 # ---------------------------------------------------------------------------
+
+def _served(cfg: ArchConfig, out, cache, aux):
+    if not cfg.n_experts:
+        return out, cache
+    return out, cache, aux.astype(jnp.int32)
+
 
 def make_prefill_step(cfg: ArchConfig):
     def prefill(params, inputs, cache):
-        logits, cache, _ = forward(params, inputs, cfg, cache=cache,
-                                   mode="prefill")
-        return logits[:, -1, :], cache
+        logits, cache, aux = forward(params, inputs, cfg, cache=cache,
+                                     mode="prefill")
+        return _served(cfg, logits[:, -1, :], cache, aux)
     return prefill
 
 
@@ -137,9 +146,9 @@ def make_decode_step(cfg: ArchConfig):
         pos: scalar int32, or — as the serving engine passes it — a (B,)
         int32 vector of per-slot positions where -1 marks an inactive slot
         (no cache write; that row's logits are garbage and ignored)."""
-        logits, cache, _ = forward(params, token, cfg, cache=cache,
-                                   mode="decode", pos=pos)
-        return logits[:, -1, :], cache
+        logits, cache, aux = forward(params, token, cfg, cache=cache,
+                                     mode="decode", pos=pos)
+        return _served(cfg, logits[:, -1, :], cache, aux)
     return decode
 
 
@@ -155,11 +164,11 @@ def make_chunked_prefill_step(cfg: ArchConfig):
     def prefill(params, cache, tokens, lengths):
         """tokens: (B, S) right-padded ids; lengths: (B,) valid counts
         (0 = slot not being admitted — its cache region is untouched)."""
-        logits, cache, _ = forward(params, tokens, cfg, cache=cache,
-                                   mode="chunk", pos=lengths)
+        logits, cache, aux = forward(params, tokens, cfg, cache=cache,
+                                     mode="chunk", pos=lengths)
         last = jnp.take_along_axis(
             logits, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)
-        return last[:, 0, :], cache
+        return _served(cfg, last[:, 0, :], cache, aux)
     return prefill
 
 
@@ -175,12 +184,12 @@ def make_chunked_prefill_resume_step(cfg: ArchConfig):
     chunk.  Returns each slot's last-valid-token logits, like the other
     prefill builders."""
     def prefill(params, cache, tokens, lengths, offsets):
-        logits, cache, _ = forward(params, tokens, cfg, cache=cache,
-                                   mode="chunk", pos=lengths,
-                                   offset=offsets)
+        logits, cache, aux = forward(params, tokens, cfg, cache=cache,
+                                     mode="chunk", pos=lengths,
+                                     offset=offsets)
         last = jnp.take_along_axis(
             logits, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)
-        return last[:, 0, :], cache
+        return _served(cfg, last[:, 0, :], cache, aux)
     return prefill
 
 
@@ -189,9 +198,9 @@ def make_paged_decode_step(cfg: ArchConfig):
     ``pages`` (B, P) argument is the engine's per-slot page table mapping
     logical cache rows to pool pages; -1 entries are unmapped."""
     def decode(params, cache, token, pos, pages):
-        logits, cache, _ = forward(params, token, cfg, cache=cache,
-                                   mode="decode", pos=pos, pages=pages)
-        return logits[:, -1, :], cache
+        logits, cache, aux = forward(params, token, cfg, cache=cache,
+                                     mode="decode", pos=pos, pages=pages)
+        return _served(cfg, logits[:, -1, :], cache, aux)
     return decode
 
 
@@ -208,12 +217,12 @@ def make_paged_chunked_prefill_step(cfg: ArchConfig):
     LAST-valid-token logits — the post-prompt prediction when this chunk
     finishes the prompt, intermediate (discarded) logits otherwise."""
     def prefill(params, cache, tokens, lengths, pages, offsets):
-        logits, cache, _ = forward(params, tokens, cfg, cache=cache,
-                                   mode="chunk", pos=lengths, pages=pages,
-                                   offset=offsets)
+        logits, cache, aux = forward(params, tokens, cfg, cache=cache,
+                                     mode="chunk", pos=lengths, pages=pages,
+                                     offset=offsets)
         last = jnp.take_along_axis(
             logits, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)
-        return last[:, 0, :], cache
+        return _served(cfg, last[:, 0, :], cache, aux)
     return prefill
 
 
@@ -233,8 +242,8 @@ def make_paged_verify_step(cfg: ArchConfig):
     in the round pass length 0 (no cache write, garbage logits ignored).
     """
     def verify(params, cache, tokens, lengths, pages, offsets):
-        logits, cache, _ = forward(params, tokens, cfg, cache=cache,
-                                   mode="verify", pos=lengths, pages=pages,
-                                   offset=offsets)
-        return logits, cache
+        logits, cache, aux = forward(params, tokens, cfg, cache=cache,
+                                     mode="verify", pos=lengths, pages=pages,
+                                     offset=offsets)
+        return _served(cfg, logits, cache, aux)
     return verify

@@ -44,6 +44,41 @@ def test_moe_ep_matches_dense_oracle():
     """)
 
 
+@pytest.mark.parametrize("rules", ["fsdp_sp", "tp"])
+def test_moe_dropless_sharded_matches_one_device(rules):
+    """The serving (dropless) expert layer on a (pod, data, model) mesh,
+    experts split over 'model': each shard computes the held experts it
+    holds and the shards' sums agree with one device, for per-layer banks
+    and for banks stacked over layers read at a layer index; the pick
+    counts are the same."""
+    run_devices(f"""
+        from repro.models import ArchConfig
+        from repro.models.moe import BANKS, moe_ffn, moe_specs
+        from repro.models.common import materialize
+        from repro.distributed.sharding import use_rules
+        from repro.launch.mesh import make_test_mesh
+        cfg = ArchConfig(name='m', family='moe', n_layers=2, d_model=64,
+                         n_heads=4, n_kv_heads=4, d_ff=0, vocab_size=100,
+                         n_experts=8, top_k=2, d_ff_expert=64,
+                         n_shared_experts=1, experts_held=(2, 6),
+                         norm_topk_prob=False, dtype=jnp.float32)
+        p = materialize(moe_specs(cfg), jax.random.PRNGKey(0), jnp.float32)
+        stacked = dict(p, layer=jnp.int32(1), **{{
+            n: jnp.stack([-p[n], p[n]]) for n in BANKS}})
+        x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 64), jnp.float32)
+        mask = jax.random.bernoulli(jax.random.PRNGKey(2), 0.7, (4, 8))
+        f = jax.jit(lambda p, x, m: moe_ffn(p, x, cfg, token_mask=m))
+        y_ref, n_ref = f(p, x, mask)
+        mesh = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
+        with use_rules(mesh, "{rules}"):
+            for q in (p, stacked):
+                y, n = f(q, x, mask)
+                assert float(jnp.abs(y_ref - y).max()) < 1e-5
+                assert (n == n_ref).all(), (n, n_ref)
+        assert 0 < int(n_ref[0]) < int(n_ref[1]) == 2 * int(mask.sum())
+    """)
+
+
 def test_sharded_forward_matches_single_device():
     run_devices("""
         from repro.configs import get_config, reduce_config

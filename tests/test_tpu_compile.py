@@ -220,3 +220,52 @@ def test_qwen_paged_pool_written_in_place(topo, one_chip, program, striped):
                          for x in jax.tree.leaves(cache))
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < 0.05 * pool_bytes, (temp, pool_bytes)
+
+
+def _sharded(spec_tree, dtype):
+    """ParamSpec tree -> ShapeDtypeStructs sharded by the installed rules."""
+    from repro.distributed.sharding import make_array_sharding
+    from repro.models.common import is_spec_tree_leaf
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype or dtype,
+            sharding=make_array_sharding(s.shape, s.axes)),
+        spec_tree, is_leaf=is_spec_tree_leaf)
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "ep4"])
+def test_deepseek_expert_banks_read_in_place(topo, chips):
+    """DeepSeek-V2-Lite's paged decode step at published widths with one
+    chip's 16 experts (three layers here): the grouped-matmul kernel reads
+    each layer's expert banks in place out of the stacked weights, so no
+    op makes a layer's bank (16 x 2048 x 1408) or a copy of the stack.
+    On four chips under ``tp`` (experts over 'model'), each chip computes
+    the experts it holds: no all-gather carries expert weights."""
+    from repro.models.model import cache_specs, param_specs
+    cfg = get_config("deepseek-v2-lite").with_(
+        experts_held=(0, 16), n_layers=3,
+        pattern=(("scan", "mla_mlp", 1), ("scan", "mla_moe", 2)))
+    mesh = Mesh(np.array(topo.devices[:chips]).reshape(1, chips),
+                ("data", "model"))
+    with use_rules(mesh, "tp"):
+        params = _sharded(param_specs(cfg), cfg.dtype)
+        cache = _sharded(cache_specs(cfg, B, 0, num_pages=N, page_size=PS),
+                         cfg.dtype)
+        i32 = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
+            s, jnp.int32, sharding=SingleDeviceSharding(topo.devices[0])
+            if chips == 1 else None)
+        compiled = jax.jit(make_paged_decode_step(cfg), donate_argnums=1
+                           ).lower(params, cache, i32(B, 1), i32(B),
+                                   i32(B, P)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    bank = 16 * 2048 * 1408
+    local = bank // chips
+    made = [line for n in (bank, 2 * bank, local, 2 * local)
+            for op, line in _ops_of_size(hlo, n)
+            if op not in ("parameter", "bitcast", "get-tuple-element",
+                          "tuple")]
+    assert not made, made
+    gathered = [line for line in hlo.splitlines() if "all-gather" in line
+                and _ops_of_size(line, bank) + _ops_of_size(line, 2 * bank)]
+    assert not gathered, gathered
