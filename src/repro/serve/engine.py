@@ -27,11 +27,14 @@ shim (submit everything, tick until idle); ``drain()`` finishes all
 outstanding work and CLOSES the engine — ``submit()`` afterwards raises.
 
 Continuous batching: every engine tick is (at most) ONE chunked-prefill
-dispatch — covering freshly admitted slots AND slots resuming a prompt
+wave — covering freshly admitted slots AND slots resuming a prompt
 longer than one chunk, via the ``offset`` argument threaded through
 ``forward`` — followed by ONE decode dispatch for the slots whose prompt
 is complete.  Prefill of the next wave therefore overlaps decode of the
-current one, and a long prompt never stalls the tick loop.
+current one, and a long prompt never stalls the tick loop.  Where every
+cache leaf is a page pool, the wave goes out in dispatches of a few
+slots each (about ``PREFILL_ROWS`` rows), sized to the slots that owe
+prompt rows rather than to the whole batch.
 
 Preemption (overcommit mode): when decode growth finds the pool empty,
 the scheduler picks the youngest resident request, the engine snapshots
@@ -104,6 +107,13 @@ _OVERSIZED = "oversized"            # admission verdict: host-tier context
 # measured model (benchmarks/fig12_offload.measure_offload_bandwidth)
 # is unavailable — sized so the auto prefetch depth stays modest.
 _FALLBACK_H2D_BPS = 1e9
+
+# rows one chunked-prefill dispatch is sized to.  Below about 240 rows a
+# bf16 matmul that streams its weights is bound by the weight read on a
+# v5e (197 TFLOP/s / 819 GB/s), so rows up to there cost almost nothing
+# and rows past them are paid for; 256 is that figure rounded to the
+# MXU's 128.
+PREFILL_ROWS = 256
 
 
 class _ExpertTally:
@@ -329,6 +339,16 @@ class ServingEngine:
                     name=f"slot{i}", virt_base=i * cap,
                     size=cap, phys_base=i * cap,
                     readable=True, writable=True))
+        # slots one prefill dispatch carries: where every cache leaf is a
+        # page pool, a wave's owing slots go out in groups of about
+        # PREFILL_ROWS rows, each with its own rows of the page table.
+        # Leaves with a batch axis (recurrent state, the contiguous cache)
+        # keep the whole batch: compacting them would gather and scatter
+        # per-slot state.
+        self._prefill_group = bsz
+        if serve_cfg.paged and all(self._pooled):
+            self._prefill_group = min(
+                max(PREFILL_ROWS // serve_cfg.max_prompt, 1), bsz)
         self.sched = Scheduler(bsz, serve_cfg.max_prompt)
         self.positions = np.zeros((bsz,), np.int32)
         self.last_token = np.zeros((bsz,), np.int32)
@@ -341,7 +361,7 @@ class ServingEngine:
         self.n_cow_copies = 0
         self.n_shared_admissions = 0
         self.n_swap_budget_denials = 0
-        self._prefilled_since_step = False   # one prefill dispatch per tick
+        self._prefilled_since_step = False   # one prefill wave per tick
         self.tick_no = 0            # the serving clock (deadline ledger)
         self._closed = False        # set by drain(): no further submits
         # host bytes one swapped slot would occupy, for the swap budget:
@@ -666,7 +686,7 @@ class ServingEngine:
 
     def admit_many(self, pending: List[Request]) -> int:
         """Admit as many pending requests as there are free slots, then
-        run ONE chunked-prefill dispatch covering the new slots' first
+        run ONE chunked-prefill wave covering the new slots' first
         chunks AND the next chunk of every slot still mid-prefill.  Pops
         admitted (and rejected) requests off ``pending``; returns the
         number admitted.  Swapped-out requests re-enter first.  A request
@@ -753,31 +773,32 @@ class ServingEngine:
         return placed
 
     def warmup(self) -> None:
-        """Compile the jitted prefill (both traces: fresh and resumed)
-        and decode steps at their serving shapes with no-op dispatches —
-        zero lengths, every slot inactive, so no cache row is written and
-        nothing is admitted.  Benchmarks call this so TTFT measures
-        serving latency, not XLA compilation."""
+        """Compile the jitted prefill (both traces: fresh and resumed, at
+        the prefill group's shape) and decode steps at their serving
+        shapes with no-op dispatches — zero lengths, every slot inactive,
+        so no cache row is written and nothing is admitted.  Benchmarks
+        call this so TTFT measures serving latency, not XLA compilation."""
         bsz, sp = self.sc.max_batch, self.sc.max_prompt
-        z_tok = jnp.zeros((bsz, sp), jnp.int32)
-        z_len = jnp.zeros((bsz,), jnp.int32)
+        g = self._prefill_group
+        z_tok = jnp.zeros((g, sp), jnp.int32)
+        z_len = jnp.zeros((g,), jnp.int32)
         one = jnp.zeros((bsz, 1), jnp.int32)
-        inactive = jnp.full((bsz,), -1, jnp.int32)
+        inactive = jnp.asarray(np.full((bsz,), -1, np.int32))
         if self.sc.paged:
+            pages = jnp.asarray(self.alloc.page_table[:g])
             with self._kernel_ctx():
                 _, self.cache = self._prefill(self.params, self.cache,
-                                              z_tok, z_len,
-                                              self._pages_dev(), None)
+                                              z_tok, z_len, pages, None)
                 _, self.cache = self._prefill(self.params, self.cache,
-                                              z_tok, z_len,
-                                              self._pages_dev(), z_len)
+                                              z_tok, z_len, pages, z_len)
                 lg, self.cache = self._decode(self.params, self.cache, one,
                                               inactive, self._pages_dev())
                 if self._drafter is not None:
                     zv = jnp.zeros((bsz, self.sc.spec_k + 1), jnp.int32)
+                    zb = jnp.asarray(np.zeros((bsz,), np.int32))
                     _, self.cache = self._verify(
-                        self.params, self.cache, zv, z_len,
-                        self._pages_dev(), z_len)
+                        self.params, self.cache, zv, zb,
+                        self._pages_dev(), zb)
         else:
             _, self.cache = self._prefill(self.params, self.cache, z_tok,
                                           z_len)
@@ -799,9 +820,11 @@ class ServingEngine:
 
     # -- resumable chunked prefill ------------------------------------------
     def _prefill_tick(self) -> None:
-        """ONE chunked-prefill dispatch for every slot owing prompt rows:
+        """Chunked-prefill dispatches for every slot owing prompt rows:
         fresh admissions fill [0, chunk), resumed slots [done, done+chunk).
-        Slots whose prompt completes this tick sample their first token."""
+        The owing slots go out in groups of ``_prefill_group``, in slot
+        order; slots whose prompt completes this tick sample their first
+        token, read back once for the whole wave."""
         work = self.sched.prefill_plan()
         if self.tiered and work:
             # residency gate: a resumed chunk attends the WHOLE cached
@@ -815,23 +838,39 @@ class ServingEngine:
             return
         self._prefilled_since_step = True
         # trace invariance: fresh admissions (offset 0) and resumed chunks
-        # dispatch as SEPARATE waves.  The all-fresh trace (offsets=None,
+        # dispatch as SEPARATE groups.  The all-fresh trace (offsets=None,
         # single-pass chunk kernel) and the resume trace (full-window
-        # gather) sum in different orders, so a mixed wave would let the
+        # gather) sum in different orders, so a mixed group would let the
         # schedule — admissions staggered by tiered page pressure — shift
         # a fresh slot's logits by ~1e-7.  Splitting pins each chunk's
-        # trace to its own offset, keeping logits bitwise
-        # schedule-invariant (the tiered-vs-resident contract).
-        for group in ([w for w in work if w[1] == 0],
-                      [w for w in work if w[1] > 0]):
-            if group:
-                self._prefill_dispatch(group)
+        # trace to its own offset, and every group has the same (group,
+        # max_prompt) shape, keeping logits bitwise schedule-invariant
+        # (the tiered-vs-resident contract).
+        g = self._prefill_group
+        groups = []
+        for same in ([w for w in work if w[1] == 0],
+                     [w for w in work if w[1] > 0]):
+            groups += [same[i:i + g] for i in range(0, len(same), g)]
+        pending = []
+        for i, group in enumerate(groups):
+            self._prefill_dispatch(group, pending, i == len(groups) - 1)
 
-    def _prefill_dispatch(self, work) -> None:
-        """Issue one batched prefill step for ``work`` (same-trace chunks)."""
+    def _prefill_dispatch(self, work, pending: list, land: bool) -> None:
+        """Issue one prefill step for ``work`` (same-trace chunks of at
+        most ``_prefill_group`` slots) and add what it samples to
+        ``pending``; with ``land``, read every pending group's first
+        tokens in one transfer and emit them.
+
+        Where the group is smaller than the batch, row i of the dispatch
+        is ``work[i]``'s slot, with that slot's row of the page table;
+        rows past the work have length 0 and no pages, and write nothing.
+        Otherwise row i is slot i, as in decode."""
         bsz, sp, ps = self.sc.max_batch, self.sc.max_prompt, self.sc.page_size
+        g = self._prefill_group
+        compact = g < bsz
+        rows = list(range(len(work))) if compact else [w[0] for w in work]
         with span("serve.prefill", rows=sum(len(w[2]) for w in work),
-                  padded_rows=bsz * sp,
+                  padded_rows=g * sp,
                   resumed=int(any(w[1] for w in work))):
             self.sched.mark_dispatch([w[0] for w in work], self.tick_no)
             if self.sc.paged:
@@ -850,24 +889,30 @@ class ServingEngine:
                             self.alloc.check_write(
                                 slot, j * ps, ps, strict=self.sc.strict_iotlb)
                     self._apply_copies(copies)
-            toks_np = np.zeros((bsz, sp), np.int32)
-            lens_np = np.zeros((bsz,), np.int32)
-            offs_np = np.zeros((bsz,), np.int32)
-            for slot, off, toks in work:
-                toks_np[slot, :len(toks)] = toks
-                lens_np[slot] = len(toks)
-                offs_np[slot] = off
+            toks_np = np.zeros((g, sp), np.int32)
+            lens_np = np.zeros((g,), np.int32)
+            offs_np = np.zeros((g,), np.int32)
+            for row, (slot, off, toks) in zip(rows, work):
+                toks_np[row, :len(toks)] = toks
+                lens_np[row] = len(toks)
+                offs_np[row] = off
             toks, lens = jnp.asarray(toks_np), jnp.asarray(lens_np)
             if self.sc.paged:
-                # all-fresh waves (the common case) pass offsets=None — a
+                # all-fresh groups (the common case) pass offsets=None — a
                 # separate trace of the same jitted step that keeps the
                 # single-pass chunk kernel instead of the full-window
                 # gather.
                 offs = jnp.asarray(offs_np) if offs_np.any() else None
+                if compact:
+                    table = np.full((g, self.pages_per_slot), -1, np.int32)
+                    table[:len(work)] = self.alloc.page_table[
+                        [w[0] for w in work]]
+                    pages = jnp.asarray(table)
+                else:
+                    pages = self._pages_dev()
                 with self._kernel_ctx():
                     logits, self.cache = self._prefill(
-                        self.params, self.cache, toks, lens,
-                        self._pages_dev(), offs)
+                        self.params, self.cache, toks, lens, pages, offs)
             else:
                 logits, self.cache = self._prefill(self.params, self.cache,
                                                    toks, lens)
@@ -881,24 +926,32 @@ class ServingEngine:
             finishes = any(
                 off + len(toks) >= len(self.sched.slots[slot].req.prompt)
                 for slot, off, toks in work)
-            with span("serve.sync"):
-                firsts = (np.asarray(self._sample(logits)) if finishes
-                          else None)
-                lg_np = (np.asarray(logits) if self.sc.record_logits
-                         else None)
-            for slot, off, chunk_toks in work:
+            pending.append((work, rows,
+                            self._sample(logits) if finishes else None,
+                            logits if self.sc.record_logits else None))
+            if land:
+                self._prefill_land(pending)
+
+    def _prefill_land(self, pending: list) -> None:
+        """Read the wave's sampled first tokens (and logits, when
+        recorded) in one transfer; emit each finished prompt's token."""
+        with span("serve.sync"):
+            got = jax.device_get([(firsts, lg)
+                                  for _, _, firsts, lg in pending])
+        for (work, rows, _, _), (firsts, lg_np) in zip(pending, got):
+            for row, (slot, off, chunk_toks) in zip(rows, work):
                 meta = self.sched.slots[slot]
                 meta.prefill_done = off + len(chunk_toks)
                 if not meta.prefilled:
                     continue        # more chunks to come; logits discarded
                 req = meta.req
-                first = int(firsts[slot])
+                first = int(firsts[row])
                 self.positions[slot] = len(req.prompt)
                 self.last_token[slot] = first
                 req.out_tokens.append(first)    # the post-prompt prediction
                 self.sched.note_first_token(req, self.tick_no)
                 if lg_np is not None:
-                    req.logits.append(lg_np[slot].copy())
+                    req.logits.append(lg_np[row].copy())
                 if first == self.sc.eos_id or \
                         len(req.out_tokens) >= self.sc.max_new_tokens:
                     self._finish(slot)
@@ -1799,8 +1852,8 @@ class ServingEngine:
     def step(self):
         """One engine tick: advance any unfinished prefill by one chunk
         (unless this tick's admission wave already did), then one decode
-        step for every prompt-complete slot — at most ONE prefill and ONE
-        decode dispatch per tick."""
+        step for every prompt-complete slot — at most ONE prefill wave and
+        ONE decode dispatch per tick."""
         t0 = time.perf_counter()
         if self.tiered:
             with span("serve.tier"):
@@ -1923,7 +1976,7 @@ class ServingEngine:
     def tick(self) -> None:
         """One externally-drivable engine step: advance the serving
         clock, drain pending admissions into free slots (at most ONE
-        chunked-prefill dispatch, covering fresh and resumed prompts),
+        chunked-prefill wave, covering fresh and resumed prompts),
         then one decode dispatch for the prompt-complete slots.  Safe to
         call when idle (no-op dispatches are skipped)."""
         self.tick_no += 1

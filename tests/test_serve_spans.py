@@ -18,6 +18,7 @@ from jax.profiler import ProfileData, TraceAnnotation
 
 from repro.models import ArchConfig, init_params
 from repro.serve import Request, ServeConfig, ServingEngine
+from repro.serve import engine as serve_engine
 
 CFG = ArchConfig(name="spans", family="dense", n_layers=2, d_model=64,
                  n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=100,
@@ -82,7 +83,10 @@ def params():
 def traced(params, tmp_path_factory):
     """(tokens, dispatches, spans): spans are (name, start, end, args) of
     every ``serve.*`` host event, in start order, parents first."""
-    d = tmp_path_factory.mktemp("trace")
+    return _trace(params, tmp_path_factory.mktemp("trace"))
+
+
+def _trace(params, d):
     record = []
     tokens = _serve(params, d, record)
     path = sorted(glob.glob(f"{d}/**/*.xplane.pb", recursive=True))[-1]
@@ -137,6 +141,21 @@ def test_ticks_hold_their_phases_in_order(traced):
 
 def test_args_are_what_the_engine_dispatched(traced):
     _, record, spans = traced
+    _assert_args_dispatched(record, spans)
+
+
+def test_padded_rows_is_the_compacted_dispatch(params, tmp_path,
+                                               monkeypatch):
+    """An engine whose prefill dispatches carry one slot each (the row
+    budget cut to one chunk) reports each dispatch's own shape."""
+    monkeypatch.setattr(serve_engine, "PREFILL_ROWS", SERVE.max_prompt)
+    _, record, spans = _trace(params, tmp_path)
+    shapes = {toks.shape for kind, toks, *_ in record if kind == "prefill"}
+    assert shapes == {(1, SERVE.max_prompt)}
+    _assert_args_dispatched(record, spans)
+
+
+def _assert_args_dispatched(record, spans):
     prefills = [p[3] for p in spans if p[0] == "serve.prefill"]
     decodes = [p[3] for p in spans if p[0] == "serve.decode"]
     got_p = [r for r in record if r[0] == "prefill"]

@@ -1,6 +1,7 @@
 """Serving engine vs teacher-forced oracle + IOTLB containment."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core.iotlb import Iotlb, IotlbFault, Window
@@ -151,6 +152,117 @@ def test_moe_chunk_prefill_padding_invariant_at_tight_capacity():
         return {r.rid: r.out_tokens for r in eng.run(reqs())}
 
     assert go(16) == go(64)
+
+
+# -- prefill dispatches sized to the slots that owe rows ---------------------
+
+# every cache leaf a page pool, and 4 slots of 128-row chunks are 512 rows:
+# the engine dispatches prefill in groups of PREFILL_ROWS // 128 = 2 slots.
+COMPACT = dict(max_batch=4, max_prompt=128, max_seq=288, page_size=16,
+               max_new_tokens=3, record_logits=True)
+
+
+def _recorded(eng):
+    """Wrap ``eng._prefill``: each dispatch appends (tokens shape, lengths,
+    resumed, page table passed, the engine's page table then) to the
+    returned list."""
+    calls, orig = [], eng._prefill
+
+    def rec(params, cache, toks, lens, *paged):
+        pages, offs = paged or (None, None)
+        calls.append((toks.shape, np.asarray(lens).tolist(),
+                      offs is not None,
+                      None if pages is None else np.asarray(pages),
+                      None if eng.alloc is None
+                      else eng.alloc.page_table.copy()))
+        return orig(params, cache, toks, lens, *paged)
+    eng._prefill = rec
+    return calls
+
+
+def _first_logits(params, cfg, prompt):
+    lg, _, _ = forward(params, jnp.asarray(prompt, jnp.int32)[None, :], cfg,
+                       mode="train")
+    return np.asarray(lg[0, -1])
+
+
+@pytest.mark.parametrize("family", ["dense", "mla", "moe"])
+def test_prefill_wave_goes_out_in_groups_of_its_owing_slots(family):
+    """A wave of four fresh slots is two dispatches of (2, max_prompt);
+    the next tick's resumed chunks of slots 1 and 3 (not adjacent) are
+    one, carrying those two slots' rows of the page table.  Tokens match
+    the teacher-forced oracle, and each request's first-token logits are
+    its own prompt's, not a neighbour row's."""
+    cfg = FAMILY_CFGS[family]
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, params, ServeConfig(**COMPACT))
+    assert eng._prefill_group == 2
+    calls = _recorded(eng)
+    lens = {0: 3, 1: 150, 2: 5, 3: 140}
+    prompts = {rid: [1 + (7 * rid + 3 * k) % 97 for k in range(n)]
+               for rid, n in lens.items()}
+    out = eng.run([Request(rid, p) for rid, p in prompts.items()])
+    assert [c[:3] for c in calls] == [
+        ((2, 128), [3, 128], False), ((2, 128), [5, 128], False),
+        ((2, 128), [22, 12], True)]
+    for (_, _, _, pages, table), slots in zip(calls, ([0, 1], [2, 3],
+                                                      [1, 3])):
+        np.testing.assert_array_equal(pages, table[slots])
+    assert len(out) == 4
+    for r in out:
+        assert r.done and not r.failed
+        assert r.out_tokens == _oracle(params, cfg, r.prompt, 3), r.rid
+        np.testing.assert_allclose(
+            r.logits[0], _first_logits(params, cfg, r.prompt),
+            rtol=1e-4, atol=1e-4, err_msg=f"rid {r.rid}")
+
+
+@pytest.mark.parametrize("family", ["dense", "mla", "moe"])
+def test_prefill_group_logits_bitwise_alone_or_in_a_wave(family):
+    """Every dispatch has the fixed (group, max_prompt) shape, so a
+    request's first-token logits are bitwise the same whether it is
+    admitted alone (row 0 of the only dispatch) or in a wave of three
+    (row 1 of the first dispatch, or row 0 of the second, padded)."""
+    cfg = FAMILY_CFGS[family]
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, params, ServeConfig(**COMPACT))
+    calls = _recorded(eng)
+    prompts = [[4, 9, 2, 7, 1], [8, 3, 3, 5, 6, 2, 9], [6, 1, 7]]
+    alone = {rid: eng.run([Request(rid, prompts[rid])])[0]
+             for rid in (1, 2)}
+    assert [c[1] for c in calls] == [[7, 0], [3, 0]]
+    del calls[:]
+    wave = {r.rid: r for r in eng.run(
+        [Request(rid, p) for rid, p in enumerate(prompts)])}
+    assert [c[:2] for c in calls] == [((2, 128), [5, 7]),
+                                      ((2, 128), [3, 0])]
+    for rid in (1, 2):
+        assert wave[rid].out_tokens == alone[rid].out_tokens, rid
+        np.testing.assert_array_equal(wave[rid].logits[0],
+                                      alone[rid].logits[0], err_msg=str(rid))
+    assert wave[0].out_tokens == _oracle(params, cfg, prompts[0], 3)
+
+
+@pytest.mark.parametrize("family, paged", [("ssm", True), ("dense", False)],
+                         ids=["ssm-paged", "dense-contiguous"])
+def test_prefill_keeps_whole_batch_with_per_slot_state(family, paged):
+    """Recurrent state and the contiguous cache have a batch axis, so
+    their prefill still dispatches (max_batch, max_prompt), slot i in
+    row i."""
+    cfg = FAMILY_CFGS[family]
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    sc = dict(COMPACT, paged=paged)
+    if not paged:
+        del sc["max_seq"], sc["page_size"]     # paged geometry only
+    eng = ServingEngine(cfg, params, ServeConfig(**sc))
+    assert eng._prefill_group == 4
+    calls = _recorded(eng)
+    reqs = [Request(0, [5, 7, 11]), Request(1, [3, 1, 4, 1, 5, 9]),
+            Request(2, [2, 7])]
+    out = eng.run(reqs)
+    assert [c[:2] for c in calls] == [((4, 128), [3, 6, 2, 0])]
+    for r in out:
+        assert r.out_tokens == _oracle(params, cfg, r.prompt, 3), r.rid
 
 
 def test_empty_prompt_rejected_cleanly():

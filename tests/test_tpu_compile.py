@@ -5,7 +5,7 @@ The TPU compiler is installed beside the CPU backend, so a described
 path at its real widths: the fused paged-decode kernel (qwen2.5-3b GQA
 and deepseek-v2-lite MLA pools), the weight-only w4 matmul kernel, and
 the whole 36-layer qwen2.5-3b paged decode step from abstract shapes, and
-that its decode and resumed-prefill steps write a donated paged pool in
+that its decode and prefill steps write a donated paged pool in
 place (no pool-sized copy, update or allocation).
 Mosaic refuses here what the Pallas interpreter accepts (block shapes not
 tiled by (8, 128), dots it cannot lower, too much VMEM), so these tests
@@ -32,12 +32,15 @@ from repro.kernels import paged_flash_decode as K
 from repro.kernels.mpq_matmul import wo_matmul_kernel
 from repro.models import abstract_params
 from repro.models.model import abstract_paged_cache
+from repro.serve.engine import PREFILL_ROWS
 from repro.train.step import (make_paged_chunked_prefill_step,
                               make_paged_decode_step)
 
 # serving shapes: 8 slots, 64 logical pages of 16 rows (1024-row window),
-# a 1024-page pool.
+# a 1024-page pool; 128-row prefill chunks go out PREFILL_GROUP slots at a
+# time, as the engine sizes them.
 B, P, N, PS = 8, 64, 1024, 16
+PREFILL_GROUP = min(max(PREFILL_ROWS // 128, 1), B)
 V5E_HBM_BYTES = 16 * 10**9
 
 
@@ -184,16 +187,20 @@ def _ops_of_size(hlo: str, n_elements: int):
     return ops
 
 
-@pytest.mark.parametrize("program", ["decode", "resumed_prefill"])
+@pytest.mark.parametrize("program", ["decode", "resumed_prefill",
+                                     "fresh_prefill_group",
+                                     "resumed_prefill_group"])
 @pytest.mark.parametrize("striped", [False, True],
                          ids=["replicated", "fsdp_sp"])
 def test_qwen_paged_pool_written_in_place(topo, one_chip, program, striped):
     """The layer scan carries the stacked paged pool and each layer
     scatters only its new rows into it, so with the cache donated (as
     the engine jits its steps) the full-width qwen2.5-3b decode and
-    resumed-prefill steps hold no copy, dynamic-update-slice or
-    allocated buffer the size of a pool leaf, and decode needs
-    (almost) no temporaries beside it."""
+    prefill steps hold no copy, dynamic-update-slice or allocated buffer
+    the size of a pool leaf, and decode needs (almost) no temporaries
+    beside it.  The prefill steps compile at the whole batch and at the
+    group of slots the engine dispatches prefill in (``_group``: fresh,
+    with no offsets, and resumed)."""
     cfg = get_config("qwen2.5-3b")
     params = _shapes(abstract_params(cfg), one_chip)
     cache = _shapes(abstract_paged_cache(cfg, B, N, PS), one_chip)
@@ -204,7 +211,9 @@ def test_qwen_paged_pool_written_in_place(topo, one_chip, program, striped):
         args = (params, cache, i32(B, 1), i32(B), i32(B, P))
     else:
         step = make_paged_chunked_prefill_step(cfg)
-        args = (params, cache, i32(B, 128), i32(B), i32(B, P), i32(B))
+        g = PREFILL_GROUP if program.endswith("_group") else B
+        offs = None if program.startswith("fresh") else i32(g)
+        args = (params, cache, i32(g, 128), i32(g), i32(g, P), offs)
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
     with use_rules(mesh, "fsdp_sp") if striped else contextlib.nullcontext():
         compiled = jax.jit(step, donate_argnums=1).lower(*args).compile()
